@@ -521,7 +521,7 @@ def cmd_netinfo(args) -> int:
     RPC endpoint in --endpoints (comma-separated; defaults to the single
     --rpc.laddr) and print one JSON document — per-node per-peer/
     per-channel accounting plus a fleet rollup (total wire bytes by
-    channel, stall time, tunnel/link estimates). The single-pane answer
+    channel, stall time, link estimates). The single-pane answer
     to 'where do this net's wire bytes go'."""
     import urllib.request
 

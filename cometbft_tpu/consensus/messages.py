@@ -1,6 +1,6 @@
 """Consensus message types flowing through the state-machine queue.
 
-Reference: consensus/reactor.go:1576-1592 message taxonomy; the subset the
+Reference: consensus/reactor.go:1576-1592 message classification; the subset the
 state machine consumes (Proposal/BlockPart/Vote) plus the gossip-control
 messages the reactor exchanges (NewRoundStep, HasVote, VoteSetMaj23, ...).
 """

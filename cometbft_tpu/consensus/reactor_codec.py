@@ -1,7 +1,7 @@
 """Wire codec for consensus reactor messages.
 
 Reference: proto/tendermint/consensus/types.proto + consensus/reactor.go
-message taxonomy (reactor.go:1576-1592). Each channel carries a Message
+message classification (reactor.go:1576-1592). Each channel carries a Message
 envelope with a oneof keyed by field number:
 
   1 NewRoundStep  2 NewValidBlock  3 Proposal  4 ProposalPOL  5 BlockPart

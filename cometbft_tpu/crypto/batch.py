@@ -15,7 +15,7 @@ from cometbft_tpu.crypto import ed25519
 from cometbft_tpu.libs.prefixrows import PrefixedMsg
 
 _BACKEND = "auto"
-_tpu_available: Optional[bool] = None
+_device: Optional[dict] = None
 
 # key type -> backend name -> factory
 _REGISTRY: dict[str, dict[str, Callable[[], crypto.BatchVerifier]]] = {}
@@ -37,16 +37,26 @@ def get_backend() -> str:
     return _BACKEND
 
 
-def _device_present() -> bool:
-    global _tpu_available
-    if _tpu_available is None:
-        try:
-            import jax
+def device_info(probe: bool = True) -> dict | None:
+    """platform / device_kind / count as JAX reports them, probed once per
+    process. probe=False only reads what an earlier call cached (health
+    snapshots must not be what first touches the device: a backend="cpu"
+    node never claims a chip)."""
+    global _device
+    if _device is None and probe:
+        import jax
 
-            _tpu_available = any(d.platform != "cpu" for d in jax.devices())
-        except Exception:  # noqa: BLE001 - no jax / no device: fall back
-            _tpu_available = False
-    return _tpu_available
+        devs = jax.devices()
+        _device = {"platform": devs[0].platform,
+                   "kind": devs[0].device_kind, "count": len(devs)}
+    return _device
+
+
+def _device_present() -> bool:
+    """Is the device the "tpu" backend is written for attached? Only a
+    TPU counts, and an import or runtime error surfaces: "jax is broken"
+    must not read as "no chip, use the CPU"."""
+    return device_info()["platform"] == "tpu"
 
 
 def resolve_backend() -> str:

@@ -278,7 +278,7 @@ def should_corrupt(site: str) -> bool:
 
 def corrupt_mask(site: str, payload):
     """Flip lane 0 of a fetched integrity payload when the site is armed
-    with `corrupt` — simulates single-lane tunnel corruption, which the
+    with `corrupt` — simulates single-lane link corruption, which the
     mask-echo check must detect (the echo half is left intact)."""
     if _take(site, want_corrupt=True) is None:
         return payload

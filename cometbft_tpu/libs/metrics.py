@@ -55,6 +55,12 @@ class _Metric:
         with self._lock:
             return self._values.get(key, 0.0)
 
+    def total(self) -> float:
+        """Sum over every label combination (a labelled counter read as
+        one number: "did ANY scheme fall back?")."""
+        with self._lock:
+            return sum(self._values.values())
+
     def _set(self, key: tuple, v: float) -> None:
         with self._lock:
             self._values[key] = v
@@ -466,7 +472,7 @@ class CryptoMetrics:
             "crypto", "device_lanes", "Signature lanes dispatched", labels=("kind",))
         self.device_seconds = reg.counter(
             "crypto", "device_seconds", "Estimated device-busy seconds")
-        # transfer-integrity plane: a tunnel-attached device must EARN the
+        # transfer-integrity plane: a link-attached device must EARN the
         # in-process-memory trust the reference assumes (validation.go:235)
         self.transfer_checksum_mismatch = reg.counter(
             "crypto", "transfer_checksum_mismatch",
@@ -503,7 +509,7 @@ class CryptoMetrics:
             "Signature lanes verified on the CPU ladder after a device "
             "failure", labels=("scheme",))
         # staging plane (ops/hashvec + reduced-fetch protocol): how often
-        # the happy path keeps the mask off the tunnel, and how the
+        # the happy path keeps the mask off the link, and how the
         # decompressed-pubkey cache is doing
         self.verify_fetches = reg.counter(
             "crypto", "verify_fetches",

@@ -30,7 +30,7 @@ def _transient(e: BaseException) -> bool:
     """Worth retrying? Timeouts, connection resets, and 5xx server
     errors are one flaky hop; 4xx, malformed bodies, and RPC-level
     errors are the provider's answer and retrying cannot change it.
-    The chaos taxonomy maps the same way (libs/chaos.py): transient and
+    The chaos classification maps the same way (libs/chaos.py): transient and
     timeout retry, permanent does not."""
     from cometbft_tpu.libs import chaos as _chaos
 

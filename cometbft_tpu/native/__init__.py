@@ -23,6 +23,14 @@ import tempfile
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _failed: set[str] = set()
 _loaded: dict[str, ctypes.CDLL] = {}
+# lib -> "built" (cc ran in this process) | "reused" (a fresh-enough .so
+# was already in the package directory) | "failed: <why>" — the smoke
+# prints it, so a run that leaned on a stray .so or lost its cc says so
+_status: dict[str, str] = {}
+
+
+def status() -> dict[str, str]:
+    return dict(_status)
 
 
 def load(name: str, cflags_ladder: tuple = (("-O2",),)) -> ctypes.CDLL | None:
@@ -51,8 +59,10 @@ def load(name: str, cflags_ladder: tuple = (("-O2",),)) -> ctypes.CDLL | None:
             repr(cflags_ladder).encode()).hexdigest()[:8]
     so = os.path.join(_DIR, f"_{name}{suffix}.so")
     try:
+        _status[name] = "reused"
         if (not os.path.exists(so)
                 or os.path.getmtime(so) < os.path.getmtime(src)):
+            _status[name] = "built"
             fd, tmp = tempfile.mkstemp(suffix=".so", dir=_DIR)
             os.close(fd)
             try:
@@ -73,8 +83,9 @@ def load(name: str, cflags_ladder: tuple = (("-O2",),)) -> ctypes.CDLL | None:
                 if os.path.exists(tmp):
                     os.unlink(tmp)
         lib = ctypes.CDLL(so)
-    except Exception:  # noqa: BLE001 - no cc / sandboxed fs: fall back
+    except Exception as exc:  # noqa: BLE001 - no cc / sandboxed fs: fall back
         _failed.add(name)
+        _status[name] = f"failed: {type(exc).__name__}: {exc}"
         return None
     _loaded[name] = lib
     return lib

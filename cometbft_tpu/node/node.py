@@ -98,6 +98,44 @@ def init_files(home: str, chain_id: str = "", moniker: str = "node") -> Config:
     return cfg
 
 
+def configure_device_plane(crypto_cfg, logger: cmtlog.Logger) -> dict:
+    """Everything a process does to its verify device before the first
+    batch, in one callable (Node.__init__ and chip_smoke.py share it):
+    apply config.crypto (backend, supervision, scheduler, mesh, wire
+    knobs — BASELINE: --crypto.backend; ops/dispatch.py), arm the
+    persistent compilation cache on device backends (a restart loads the
+    verify executables instead of re-tracing them; on a mesh EVERY chip
+    instantiates its own), and log one line naming the configured and
+    resolved backend and the device JAX reports. Returns that record.
+
+    backend="tpu" without a TPU stays legal — the e2e device
+    perturbations run the device path as XLA on the host CPU — but it is
+    logged at error level: the node is NOT on the accelerator it was
+    configured for."""
+    crypto_batch.configure(crypto_cfg)
+    record = {"configured_backend": crypto_cfg.backend,
+              "compile_cache": None, "platform": None, "kind": None,
+              "count": None}
+    if crypto_cfg.backend != "cpu":
+        from cometbft_tpu.ops import compile_cache
+
+        try:
+            record["compile_cache"] = compile_cache.arm()
+        except Exception as exc:  # noqa: BLE001 - boot goes on, uncached
+            logger.error("compile cache could not be armed; every "
+                         "restart pays cold device compiles", err=str(exc))
+        record.update(crypto_batch.device_info())
+    record["resolved_backend"] = crypto_batch.resolve_backend()
+    fields = {k: str(v) for k, v in record.items()}
+    if crypto_cfg.backend == "tpu" and record["platform"] != "tpu":
+        logger.error("crypto.backend=tpu but JAX reports no TPU: the "
+                     "device verify path is running on the host CPU",
+                     **fields)
+    else:
+        logger.info("verify device plane", **fields)
+    return record
+
+
 class Node(BaseService):
     """node/node.go:234 Node: owns every subsystem."""
 
@@ -147,27 +185,10 @@ class Node(BaseService):
                 slow_ms=inst.height_slow_ms,
                 postmortems=inst.postmortem_captures)
 
-        # crypto backend selection + device-fault supervision knobs
-        # (BASELINE: --crypto.backend flag; ops/dispatch.py supervisor)
-        crypto_batch.configure(config.crypto)
-
-        # device backends: arm the persistent XLA compilation cache so a
-        # node (re)start loads compiled verify executables instead of
-        # re-tracing them — on a multi-chip mesh EVERY chip instantiates
-        # its own executable, and paying a cold compile per chip inside
-        # live consensus rounds would eat the liveness budget
-        if config.crypto.backend != "cpu":
-            try:
-                import jax
-
-                repo_root = os.path.dirname(os.path.dirname(
-                    os.path.dirname(os.path.abspath(__file__))))
-                jax.config.update("jax_compilation_cache_dir",
-                                  os.path.join(repo_root, ".jax_cache"))
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 2)
-            except Exception:  # noqa: BLE001 - cache is an optimization
-                pass
+        # crypto backend selection, device-fault supervision knobs,
+        # compile cache, and the one boot line that says which device
+        # the verify path will actually run on
+        configure_device_plane(config.crypto, self.logger)
 
         # network-fault schedule (p2p/netchaos.py; CBFT_NET_CHAOS overlays)
         if config.p2p.chaos:

@@ -352,7 +352,7 @@ def verify_batch_async(pubs, msgs, sigs, cache=None,
             flags_dev = jnp.asarray(flags)
             jax.block_until_ready((block_dev, flags_dev))
             nbytes = block.nbytes + flags.nbytes
-            _linkmodel.tunnel().observe_transfer(
+            _linkmodel.link().observe_transfer(
                 nbytes, _time.perf_counter() - t0)
             sp.add_bytes(tx=nbytes)
         try:
@@ -518,7 +518,7 @@ def _aggregate_device(pubs, msgs, sigs, presummed_sig: bool = False) -> bool:
         block_dev = jnp.asarray(block)
         flags_dev = jnp.asarray(flags)
         jax.block_until_ready((block_dev, flags_dev))
-        _linkmodel.tunnel().observe_transfer(
+        _linkmodel.link().observe_transfer(
             block.nbytes, _time.perf_counter() - t0)
         sp.add_bytes(tx=block.nbytes + flags.nbytes)
     try:
@@ -648,7 +648,7 @@ def mesh_shard_verify(chip_device, pubs, msgs, sigs):
         block_dev = jax.device_put(block, chip_device)
         flags_dev = jax.device_put(flags, chip_device)
         jax.block_until_ready((block_dev, flags_dev))
-        _linkmodel.tunnel().observe_transfer(
+        _linkmodel.link().observe_transfer(
             block.nbytes + flags.nbytes, _time.perf_counter() - t0)
         with KERNEL_DISPATCH_LOCK:
             _header, payload = _verify_device(
@@ -659,7 +659,7 @@ def mesh_shard_verify(chip_device, pubs, msgs, sigs):
     # (ed25519_kernel.decode_payload): checksum + mask/echo complement,
     # one fresh-transfer retry, then the shard FAILS so the mesh
     # redispatches it across surviving fault domains — a flipped bit in
-    # the tunnel must never become an accepted signature
+    # the link must never become an accepted signature
     for _attempt in range(2):
         payload_np = _round()
         mask = payload_np[:b]

@@ -295,6 +295,12 @@ def verify_batch_async(
     from cometbft_tpu.ops import ed25519_kernel as EK
     from cometbft_tpu.ops.dispatch import KERNEL_DISPATCH_LOCK
 
+    # the scheduler and the mixed verifier pass no cache: without this
+    # default the dispatch closure died on None.stage(), the supervisor
+    # recorded a failure, and every sr25519 batch was verified by the
+    # host oracle — right verdicts, wrong rung (found by chip_smoke's
+    # rung accounting)
+    cache = cache or _default_cache
     rows = (list(pubs), list(msgs), list(sigs))
     info = (srm.verify, "sr25519", None)
     sup = D.supervisor("device")
@@ -359,7 +365,7 @@ def verify_batch_async(
             dev_block = jnp.asarray(block)
             jax.block_until_ready(dev_block)
             nbytes = block.nbytes
-            _linkmodel.tunnel().observe_transfer(
+            _linkmodel.link().observe_transfer(
                 nbytes, _time.perf_counter() - t0)
             sp.add_bytes(tx=nbytes)
         _residency.record_send(path, staging_tx + nbytes, sigs=n)

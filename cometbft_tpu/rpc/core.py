@@ -277,7 +277,7 @@ class Environment:
         per-channel network accounting rollup — bytes/msgs/packets both
         directions per channel per peer, send-queue depth + high-water,
         send-routine stall split, ping RTT EWMAs — plus the live link
-        models (the host<->device tunnel estimate the kernels feed, and
+        models (the host<->device link estimate the kernels feed, and
         the aggregate p2p RTT view) and the armed net-chaos schedule.
         `cometbft netinfo` renders this across a fleet; the e2e runner
         snapshots it per node into the run report."""
@@ -309,7 +309,7 @@ class Environment:
             **wire,
             "gossip": acct() if acct is not None else None,
             "discovery": book.stats() if book is not None else None,
-            "tunnel": linkmodel.tunnel().snapshot(),
+            "link": linkmodel.link().snapshot(),
             "p2p_link": linkmodel.p2p().snapshot(),
             "net_chaos": netchaos.snapshot(),
         }

@@ -918,12 +918,12 @@ class VerifyScheduler:
         try:
             from cometbft_tpu.libs import linkmodel
 
-            tun = linkmodel.tunnel()
-            out = tun.snapshot()
+            lnk = linkmodel.link()
+            out = lnk.snapshot()
             bps = self.planning_bytes_per_sig()
             out["planning_bytes_per_sig"] = round(bps, 2)
             # current wire cost of one maximally-coalesced flush
-            est = tun.transfer_seconds(int(bps * self.max_lanes))
+            est = lnk.transfer_seconds(int(bps * self.max_lanes))
             out["full_flush_wire_ms_at_measured_bytes_per_sig"] = (
                 round(est * 1e3, 2) if est is not None else None)
             return out

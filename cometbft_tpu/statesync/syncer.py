@@ -3,7 +3,7 @@
 SyncAny loop: pick the best offered snapshot, anchor its app hash in
 light-client-verified headers, OfferSnapshot to the app, fetch chunks in
 parallel, apply them in order, then verify the restored app (Info) against
-the trusted app hash. Error taxonomy mirrors the reference:
+the trusted app hash. Error classification mirrors the reference:
 
   ErrAbort          — app said abort: give up state sync entirely
   ErrRetrySnapshot  — refetch every chunk of the same snapshot

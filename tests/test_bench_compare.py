@@ -1,7 +1,8 @@
 """Bench regression sentinel tests (ISSUE 8): direction-aware thresholds,
 snapshot-shape handling (driver records with parsed=null tails), and the
-injected-regression self-test that turns the BENCH_r*.json trajectory
-into an enforced contract. perf-marked (tier-1-safe, selectable via
+injected-regression self-test over synthetic driver snapshots of the two
+shapes a driver has shipped (with `parsed`; `parsed: null` with a
+front-truncated tail). perf-marked (tier-1-safe, selectable via
 `pytest -m perf` as the fast perf smoke)."""
 
 from __future__ import annotations
@@ -37,6 +38,26 @@ def _record(**overrides) -> dict:
     for k, v in overrides.items():
         rec["detail"][k] = v
     return rec
+
+
+def _driver_snapshot(tmp_path, parsed: bool) -> str:
+    """A driver snapshot written into tmp_path, in one of the two shapes
+    drivers have shipped: {n, cmd, rc, tail, parsed} with the parsed
+    record, or parsed=null and a tail whose FRONT was truncated away
+    mid-token (the tail keeps the end of the bench's one JSON line)."""
+    rec = _record(sr25519_device_compute_ms=1.99,
+                  blocksync_blocks_per_s=25.1)
+    rec["value"] = 804844.9
+    rec["detail"]["device_compute_ms_per_batch"] = 12.72
+    line = json.dumps(rec)
+    progress = "[bench +  123.2s] bench_blocksync\n"
+    doc = {"n": 4 if parsed else 5, "cmd": "python bench.py", "rc": 0,
+           "tail": progress + line if parsed
+           else line[line.index('"device_compute_ms_per_batch"') + 9:],
+           "parsed": rec if parsed else None}
+    path = tmp_path / ("with_parsed.json" if parsed else "null_parsed.json")
+    path.write_text(json.dumps(doc))
+    return str(path)
 
 
 class TestFlatten:
@@ -99,7 +120,7 @@ class TestDirectionAwareCompare:
         assert row["direction"] == bc.HIGHER
         assert "promoted from wire-bound" in row["why"]
         assert bc.compare(worse, old)["verdict"] == "pass"
-        # within the wide threshold: tunnel RTT wiggle still tolerated
+        # within the wide threshold: link RTT wiggle still tolerated
         v2 = bc.compare(old, _record(stream_sigs_per_s=60_000.0))  # -40%
         assert v2["metrics"]["stream_sigs_per_s"]["verdict"] == "pass"
 
@@ -501,15 +522,15 @@ class TestAbsoluteWireBounds:
 
 
 class TestSnapshotShapes:
-    def test_driver_record_with_parsed(self):
-        rec = bc.load_snapshot(os.path.join(REPO, "BENCH_r04.json"))
+    def test_driver_record_with_parsed(self, tmp_path):
+        rec = bc.load_snapshot(_driver_snapshot(tmp_path, parsed=True))
         assert rec["value"] == 804844.9
         assert bc.flatten(rec)["device_compute_ms_per_batch"] == 12.72
 
-    def test_driver_record_with_null_parsed_recovers_tail(self):
-        """BENCH_r05.json ships parsed=null and a front-truncated tail;
-        the sentinel must still recover comparable metrics from it."""
-        rec = bc.load_snapshot(os.path.join(REPO, "BENCH_r05.json"))
+    def test_driver_record_with_null_parsed_recovers_tail(self, tmp_path):
+        """A driver snapshot can ship parsed=null and a front-truncated
+        tail; the sentinel must still recover comparable metrics."""
+        rec = bc.load_snapshot(_driver_snapshot(tmp_path, parsed=False))
         flat = bc.flatten(rec)
         assert flat["sr25519_device_compute_ms"] == 1.99
         assert flat["blocksync_blocks_per_s"] == 25.1
@@ -540,10 +561,10 @@ class TestSentinelSelfTest:
         assert res["identical_verdict"] == "pass"
         assert res["improvement_verdict"] == "pass"
 
-    def test_injected_regression_flagged_on_real_snapshots(self):
-        for name in ("BENCH_r04.json", "BENCH_r05.json"):
-            res = bc.self_test(os.path.join(REPO, name), pct=30.0)
-            assert res["ok"], (name, res)
+    def test_injected_regression_flagged_on_driver_snapshots(self, tmp_path):
+        for parsed in (True, False):
+            res = bc.self_test(_driver_snapshot(tmp_path, parsed), pct=30.0)
+            assert res["ok"], (parsed, res)
             assert res["injected_metric"] in res["regression_flagged"]
 
     def test_injection_is_direction_aware(self):
@@ -560,10 +581,10 @@ class TestSentinelSelfTest:
 
 @pytest.mark.perf
 class TestEntryPoints:
-    def test_module_cli_self_test(self):
+    def test_module_cli_self_test(self, tmp_path):
         out = subprocess.run(
             [sys.executable, "-m", "tools.bench_compare", "--self-test",
-             os.path.join(REPO, "BENCH_r04.json")],
+             _driver_snapshot(tmp_path, parsed=True)],
             capture_output=True, text=True, cwd=REPO, timeout=60)
         assert out.returncode == 0, out.stdout + out.stderr
         assert json.loads(out.stdout)["ok"] is True

@@ -281,9 +281,9 @@ def _device_env():
     from cometbft_tpu.crypto import batch as crypto_batch
     from cometbft_tpu.ops import dispatch as D
 
-    jax.config.update("jax_compilation_cache_dir",
-                      str(__import__("pathlib").Path(__file__).parent.parent
-                          / ".jax_cache"))
+    from cometbft_tpu.ops import compile_cache
+
+    compile_cache.arm()
     D.configure(watchdog_timeout=900.0)
     prev = crypto_batch.get_backend()
     crypto_batch.set_backend("tpu")
@@ -441,7 +441,7 @@ def test_mesh_shard_validates_transfer_integrity(monkeypatch):
     contract as the single-chip resolver (ed25519_kernel.decode_payload):
     checksum bit + mask/echo complement validated, one fresh-transfer
     retry, then the shard FAILS (DeviceOpFailed -> mesh redispatch) — a
-    flipped bit in the tunnel never becomes an accepted signature.
+    flipped bit in the link never becomes an accepted signature.
     Device pipeline stubbed: the contract is pure host logic."""
     from cometbft_tpu.ops import bls_kernel as K
     from cometbft_tpu.ops import dispatch as D

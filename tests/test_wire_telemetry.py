@@ -420,16 +420,16 @@ class TestLinkModel:
             lm.observe_transfer(500_000, 0.01 + 1.0)   # drops to 0.5 MB/s
         assert abs(lm.bandwidth_bps() - 500_000) / 500_000 < 0.25
 
-    def test_tunnel_exposed_in_crypto_health(self):
+    def test_link_exposed_in_crypto_health(self):
         from cometbft_tpu.ops import dispatch
 
-        linkmodel.tunnel().observe_transfer(1_000_000, 0.1)
-        linkmodel.tunnel().observe_rtt(0.05)
+        linkmodel.link().observe_transfer(1_000_000, 0.1)
+        linkmodel.link().observe_rtt(0.05)
         snap = dispatch.health_snapshot()
-        assert "tunnel" in snap
-        assert snap["tunnel"]["bytes_observed"] == 1_000_000
-        assert snap["tunnel"]["rtt_ms"] == 50.0
-        assert "converged" in snap["tunnel"]
+        assert "link" in snap
+        assert snap["link"]["bytes_observed"] == 1_000_000
+        assert snap["link"]["rtt_ms"] == 50.0
+        assert "converged" in snap["link"]
         # the scheduler's health view reads the same link live
         from cometbft_tpu import sched
 
@@ -502,10 +502,10 @@ class TestNetTelemetryRoute:
                 # rollups + link models + chaos snapshot present
                 assert tel["channels"]["0x1"]["send_bytes"] == ch["send_bytes"]
                 assert tel["totals"]["send_bytes"] >= ch["send_bytes"]
-                for key in ("tunnel", "p2p_link", "net_chaos",
+                for key in ("link", "p2p_link", "net_chaos",
                             "peer_scores"):
                     assert key in tel
-                assert "bandwidth_bytes_per_s" in tel["tunnel"]
+                assert "bandwidth_bytes_per_s" in tel["link"]
             finally:
                 await s1.stop()
                 await s2.stop()
