@@ -49,11 +49,17 @@ PHASE_A_SHAPES = (("hub-150", 150, 0), ("committee-10240", 10240, 0),
                   ("mixed-5120+5120", 5120, 5120))
 MESH_SHAPES = PHASE_A_SHAPES[1:]
 NET_VALIDATORS = 4
+NET_CHAIN_ID = CHAIN_ID + "-net"
 NET_HEIGHTS = 5
 NET_TXS = 6
 NET_DEADLINE_S = 180.0
 REPEATS = 5
 WARMUP_WATCHDOG_S = 1800.0
+MESH_CHIPS = 4
+# programs one new challenge-derive geometry builds at most: the derive
+# program, the slice that cuts R and s out of its flat block and the
+# integrity program over that block (their shapes follow its length)
+PROGRAMS_PER_DERIVE_GEOMETRY = 3
 # env switches that exist to take the device OFF the path
 REFUSED_ENV = ("CBFT_NO_PALLAS", "CBFT_CHAOS")
 
@@ -80,7 +86,7 @@ def refuse_off_device_env(environ=os.environ) -> None:
             "take the device off the verify path")
 
 
-def probe_device(want_count: int | None = None) -> dict:
+def probe_device(want_count: int) -> dict:
     """Device first: a TPU or nothing. Never continues on the CPU."""
     import jax
     import jaxlib
@@ -103,9 +109,8 @@ def probe_device(want_count: int | None = None) -> dict:
         f"native cores={native.status()}")
     require(device["platform"] == "tpu",
             f"JAX reports platform {device['platform']!r}, not a TPU")
-    if want_count is not None:
-        require(device["count"] == want_count,
-                f"need {want_count} chip(s), JAX reports {device['count']}")
+    require(device["count"] == want_count,
+            f"need {want_count} chip(s), JAX reports {device['count']}")
     return device
 
 
@@ -133,12 +138,13 @@ def boot_device_plane() -> None:
 # ---------------------------------------------------------------- workload
 
 
-def make_commit(n_ed: int, n_sr: int, seed: int, height: int = 7):
+def make_commit(n_ed: int, n_sr: int, seed: int, chain_id: str = CHAIN_ID,
+                nanos: list[int] | None = None):
     """A seeded validator set (n_ed ed25519 + n_sr sr25519 keys, equal
     power) and a full commit for it: every validator signs the canonical
     precommit bytes, with millisecond-grained timestamps inside one
-    second as a live round produces them. Returns (vals, block_id,
-    commit)."""
+    second as a live round produces them (or the given nanosecond field
+    per validator). Returns (vals, block_id, commit)."""
     from cometbft_tpu.crypto import ed25519, sr25519
     from cometbft_tpu.types.basic import BlockID, BlockIDFlag, PartSetHeader
     from cometbft_tpu.types.commit import Commit, CommitSig
@@ -155,17 +161,35 @@ def make_commit(n_ed: int, n_sr: int, seed: int, height: int = 7):
     block_id = BlockID(
         hash=rng.randbytes(32),
         part_set_header=PartSetHeader(total=1, hash=rng.randbytes(32)))
+    if nanos is None:
+        nanos = [rng.randrange(1000) * 1_000_000 for _ in privs]
     sigs = [CommitSig(
         block_id_flag=BlockIDFlag.COMMIT, validator_address=v.address,
-        timestamp=cmttime.Timestamp(1_790_000_000,
-                                    rng.randrange(1000) * 1_000_000))
-        for v in vals.validators]
-    commit = Commit(height=height, round_=0, block_id=block_id,
-                    signatures=sigs)
+        timestamp=cmttime.Timestamp(1_790_000_000, ns))
+        for v, ns in zip(vals.validators, nanos)]
+    commit = Commit(height=7, round_=0, block_id=block_id, signatures=sigs)
     for i, v in enumerate(vals.validators):
         sigs[i].signature = by_addr[v.address].sign(
-            commit.vote_sign_bytes(CHAIN_ID, i))
+            commit.vote_sign_bytes(chain_id, i))
     return vals, block_id, commit
+
+
+def vote_geometry_nanos(n: int):
+    """The nanosecond fields of n votes, once for every challenge-derive
+    geometry a full flush of for-block votes at round 0 can ask for.
+    ops.challenge.plan_batch compiles against (prefix length, varying
+    suffix bytes, common trailing bytes); for votes of one height and
+    round the suffix is the timestamp plus the chain-id trailer, so the
+    geometry follows two things the clock decides: how many varint bytes
+    the millisecond-grained nanos field takes (none at 0 ms, 3 at 1-2 ms,
+    4 up to 268 ms, 5 above) and which of them is the last one to differ
+    between the votes (none when the stamps are identical)."""
+    yield [0] * n
+    for length in (3, 4, 5):
+        top = 128 ** (length - 1)
+        yield [top] * n
+        for last_differing in range(length):
+            yield [top + (i % 3) * 128 ** last_differing for i in range(n)]
 
 
 def fresh(commit, corrupt_idx: int | None = None):
@@ -185,12 +209,12 @@ def fresh(commit, corrupt_idx: int | None = None):
                   block_id=commit.block_id, signatures=sigs)
 
 
-def commit_rows(vals, commit):
+def commit_rows(vals, commit, chain_id: str = CHAIN_ID):
     """(pubkeys, sign-bytes, sigs) for every signature of the commit, in
     commit order — the very rows verify_commit batches, sign-bytes still
     factored (shared prefix + per-lane suffix) as validation._commit_rows
     hands them to the verifier."""
-    rows = commit.vote_sign_bytes_all(CHAIN_ID)
+    rows = commit.vote_sign_bytes_all(chain_id)
     return ([v.pub_key for v in vals.validators],
             rows.rows_for(list(range(len(commit.signatures)))),
             [cs.signature for cs in commit.signatures])
@@ -253,6 +277,13 @@ class Accounting:
             self._on_duration)
         jax.monitoring.register_event_listener(self._on_event)
 
+    def close(self) -> None:
+        """Stop listening (a process that outlives its Accounting)."""
+        import jax.monitoring
+
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
+        jax.monitoring.unregister_event_listener(self._on_event)
+
     def _on_duration(self, event: str, _secs: float, **_kw) -> None:
         if event == "/jax/core/compile/backend_compile_duration":
             self._compiles += 1
@@ -302,6 +333,9 @@ class Accounting:
         h = sched.get().health()
         self._base["sched_batches"] = h["batches"]
         self._base["sched_class_rows"] = dict(h["class_rows"])
+        self._base["mesh_shards"] = {
+            i: c["shards_total"] for i, c in
+            (dispatch.health_snapshot()["mesh"].get("chips") or {}).items()}
 
     def snapshot(self) -> dict:
         from cometbft_tpu.ops import dispatch, ed25519_kernel
@@ -311,6 +345,7 @@ class Accounting:
                     for k, v in self._cumulative().items()}
         vs = health["verify_sched"]
         base_rows = self._base.get("sched_class_rows", {})
+        base_shards = self._base.get("mesh_shards", {})
         mesh = health["mesh"]
         return {
             "configured_backend": health["configured_backend"],
@@ -328,6 +363,9 @@ class Accounting:
                      for p in ("indexed", "delta", "full")},
             "bytes_per_sig": health["staging"]["wire"].get(
                 "steady_state_bytes_per_sig"),
+            "table_devices": {
+                name: t["devices"] for name, t in
+                health["staging"]["wire"]["tables"].items()},
             "fetch": health["staging"]["fetch"],
             "link": health["link"],
             "dispatched_shapes": ed25519_kernel.dispatched_shapes(),
@@ -344,22 +382,27 @@ class Accounting:
                 "redispatched_batches", "fallbacks")} | {
                 "chips": {i: {"successes": c["successes"],
                               "failures": c["failures"],
-                              "shards": c["shards_total"]}
+                              "shards": (c["shards_total"]
+                                         - base_shards.get(i, 0)),
+                              "shard_lanes": c["shard_lanes"],
+                              "array_devices": c["array_devices"]}
                           for i, c in (mesh.get("chips") or {}).items()}},
         }
 
 
 def check_rungs(snap: dict, *, aligned_ed: int, aligned_sr: int,
                 warmed: set[int], want_challenge: bool = True,
-                want_indexed: bool = True, allow_compiles: bool = False,
                 mesh_chips: int = 0) -> list[str]:
     """THE assertion this script exists for: which rung served? Returns
     the list of violations (empty = every batch of the phase ran on the
     rung it should). aligned_ed / aligned_sr: how many 128-aligned device
     batches the phase dispatched per scheme on the single-chip plane —
-    each must show as one pallas.<scheme> success. mesh_chips > 0 checks
-    the mesh plane instead (its shards run the XLA ladder; pallas
-    successes are then expected to be what aligned_* say, normally 0)."""
+    each must show as one pallas.<scheme> success. want_challenge: the
+    phase's batches are wide enough that the device-challenge planner
+    must have taken some (whatever it took must have been derived on the
+    device either way). mesh_chips > 0 checks the mesh plane instead (its
+    shards run the XLA ladder; pallas successes are then expected to be
+    what aligned_* say, normally 0)."""
     bad: list[str] = []
 
     def need(cond: bool, what: str) -> None:
@@ -397,20 +440,31 @@ def check_rungs(snap: dict, *, aligned_ed: int, aligned_sr: int,
     need(snap["sched"]["chaos_fallbacks"] == 0,
          f"scheduler chaos_fallbacks = {snap['sched']['chaos_fallbacks']}")
     ch = snap["challenge"]
+    plans = ch.get("plans", 0)
+    derived = sups.get("ed25519.challenge", {}).get("successes", 0)
     if want_challenge:
-        need(ch.get("lanes_device", 0) > 0,
-             "no challenge lane was derived on the device")
+        need(plans > 0, "no batch cleared the device-challenge planner")
+    need(derived == plans,
+         f"{plans} batches planned a device challenge, {derived} derive "
+         "programs ran on the device")
+    need((ch.get("lanes_device", 0) > 0) == (plans > 0),
+         f"challenge plane: {plans} plans but lanes_device = "
+         f"{ch.get('lanes_device', 0)}")
     for k in ("plan_upload_failed", "plan_breaker_open", "derive_failed",
               "batch_host_fallback", "enc_not_resident"):
         need(ch.get(k, 0) == 0, f"challenge plane: {k} = {ch.get(k)}")
-    if want_indexed:
-        need(snap["wire"]["indexed"] > 0, "no indexed (resident-table) send")
+    need(snap["wire"]["indexed"] > 0, "no indexed (resident-table) send")
     need(set(snap["dispatched_shapes"]) <= warmed,
          f"dispatched shapes {snap['dispatched_shapes']} outside the "
          f"warmed set {sorted(warmed)}: a shape compiled inside a phase")
-    if not allow_compiles:
-        need(snap["compiles"] == 0,
-             f"{snap['compiles']} backend compile(s) inside the phase")
+    # nothing compiles inside a phase: the warm-up ran every program. The
+    # one thing a live net can still ask for is a derive geometry outside
+    # the warmed family (a nil vote, a round above 0); it is counted by
+    # the challenge plane itself and bounds what may have been built.
+    unwarmed = ch.get("derive_programs", 0)
+    need(snap["compiles"] <= PROGRAMS_PER_DERIVE_GEOMETRY * unwarmed,
+         f"{snap['compiles']} program(s) built inside the phase with "
+         f"{unwarmed} challenge-derive geometries outside the warmed set")
     mesh = snap["mesh"]
     if mesh_chips:
         need(mesh.get("active") is True, "mesh not active")
@@ -424,6 +478,22 @@ def check_rungs(snap: dict, *, aligned_ed: int, aligned_sr: int,
         for i, chip in mesh["chips"].items():
             need(chip["successes"] > 0 and chip["failures"] == 0,
                  f"mesh chip {i}: {chip}")
+        # nothing put everything on the first device: each chip's shard
+        # arrays on its own device, and so its resident tables
+        placed = [tuple(c["array_devices"]) for c in mesh["chips"].values()]
+        need(all(len(p) == 1 for p in placed)
+             and len(set(placed)) == mesh_chips,
+             f"shard arrays live on {placed}, want one distinct device "
+             "per chip")
+        tables: dict[str, set[str]] = {}
+        for name, devs in snap["table_devices"].items():
+            scheme, _, put_key = name.partition("/")
+            if put_key.startswith("dev"):
+                tables.setdefault(scheme, set()).update(devs or ())
+        for scheme, devs in tables.items():
+            need(len(devs) == mesh_chips,
+                 f"{scheme} resident tables live on {sorted(devs)}, want "
+                 f"{mesh_chips} distinct devices")
     return bad
 
 
@@ -448,10 +518,10 @@ def assert_rungs(label: str, acct: Accounting, **expect) -> dict:
 # ------------------------------------------------------------------ phases
 
 
-def _verify(vals, block_id, commit) -> None:
+def _verify(vals, block_id, commit, chain_id: str = CHAIN_ID) -> None:
     from cometbft_tpu.types import validation
 
-    validation.verify_commit(CHAIN_ID, vals, block_id, commit.height, commit)
+    validation.verify_commit(chain_id, vals, block_id, commit.height, commit)
 
 
 def _expect_bad_signature(vals, block_id, commit, idx: int) -> None:
@@ -469,11 +539,12 @@ def _expect_bad_signature(vals, block_id, commit, idx: int) -> None:
 
 def warm_up(workloads: list, net_validators: int, seed: int) -> float:
     """Compile every program the phases will run, by running exactly what
-    they run (a clean and a corrupted verify per shape, plus a small
-    commit at the net's bucket), with the watchdog raised for the warm-up
-    only: a cold XLA ladder rung is about a minute, and a compile that
-    outlasts the configured watchdog would be recorded as a device
-    failure and served by the host oracle. Timed as set-up."""
+    they run (a clean and a corrupted verify per shape, plus commits of
+    the net's size in every challenge-derive geometry its vote flushes
+    can take), with the watchdog raised for the warm-up only: a cold XLA
+    ladder rung is about a minute, and a compile that outlasts the
+    configured watchdog would be recorded as a device failure and served
+    by the host oracle. Timed as set-up."""
     from cometbft_tpu.ops import dispatch
 
     configured = dispatch.watchdog_timeout()
@@ -482,14 +553,19 @@ def warm_up(workloads: list, net_validators: int, seed: int) -> float:
     try:
         if net_validators:
             t1 = time.perf_counter()
-            vals, bid, commit = make_commit(net_validators, 0, seed + 99)
-            _verify(vals, bid, commit)
+            geometries = 0
+            for nanos in vote_geometry_nanos(net_validators):
+                vals, bid, commit = make_commit(
+                    net_validators, 0, seed + 99, NET_CHAIN_ID, nanos)
+                _verify(vals, bid, commit, NET_CHAIN_ID)
+                geometries += 1
             # a 2-vote flush stays under the device-challenge planner's
             # lane floor and rides the host-challenge program instead
-            pubs, msgs, sigs = commit_rows(vals, commit)
+            pubs, msgs, sigs = commit_rows(vals, commit, NET_CHAIN_ID)
             verifier_mask(pubs[:2], msgs[:2], sigs[:2])
-            say(f"[warm-up] {net_validators}-validator commit "
-                f"(net bucket): {time.perf_counter() - t1:.1f} s")
+            say(f"[warm-up] {net_validators}-validator commits (net bucket, "
+                f"{geometries} vote-timestamp geometries): "
+                f"{time.perf_counter() - t1:.1f} s")
         for name, vals, bid, commit, bad_idx in workloads:
             t1 = time.perf_counter()
             _verify(vals, bid, fresh(commit))
@@ -569,6 +645,11 @@ def phase_a(workloads: list, acct: Accounting, warmed: set[int],
             f"{json.dumps(link, separators=(',', ':'))}; corrupted lane "
             f"{bad_idx} pinpointed; masks == host oracle "
             f"({n} lanes, oracle {oracle_s:.1f} s)")
+        if mesh_chips:
+            chips = acct.snapshot()["mesh"]["chips"].values()
+            say(f"[{label}] {name}: {sum(c['shards'] for c in chips)} "
+                f"shards for {batches} verifies, dispatched at lane shapes "
+                f"{sorted({b for c in chips for b in c['shard_lanes']})}")
         assert_rungs(
             f"{label} {name}", acct, warmed=warmed, mesh_chips=mesh_chips,
             # mesh shards stage host-side challenges (K.stage_batch)
@@ -593,7 +674,7 @@ def phase_b(acct: Accounting, warmed: set[int], n_vals: int, heights: int,
     async def run():
         cfg = test_consensus_config()
         cfg.batch_vote_verification = True
-        net = await make_net(n_vals, config=cfg, chain_id=CHAIN_ID + "-net")
+        net = await make_net(n_vals, config=cfg, chain_id=NET_CHAIN_ID)
         t0 = time.perf_counter()
         await net.start()
         try:
@@ -634,24 +715,24 @@ def phase_b(acct: Accounting, warmed: set[int], n_vals: int, heights: int,
         f"height; all {len(txs)} txs found in committed blocks; scheduler "
         f"batches {snap['sched']['batches']}, consensus-class rows {rows}, "
         f"device batches {dev_batches}, device lanes "
-        f"{snap['counters']['device_lanes']}, {snap['compiles']} compile(s) "
-        "inside the phase (challenge-derive geometry follows the votes' "
-        "timestamp lengths; lane counts stay on the warmed buckets)")
+        f"{snap['counters']['device_lanes']}; challenge plane "
+        f"{json.dumps(snap['challenge'], separators=(',', ':'))}; "
+        f"{snap['compiles']} program(s) built inside the phase, "
+        f"{snap['challenge'].get('derive_programs', 0)} challenge-derive "
+        "geometries outside the warmed family")
     require(dev_batches >= h,
             f"only {dev_batches} device batches for {h} heights: vote "
             "flushes stayed under VoteSet.flush_pending's 2-vote batching "
             "threshold and were verified singly on the host — the phase "
             "has shown nothing")
     require(rows >= 2 * h, f"only {rows} consensus-class rows in {h} heights")
-    # votes' timestamps pick the derive geometry, so small derive programs
-    # may compile here; the LANE shapes must not, and every other count
-    # holds as in phase A. No batch here is 128-aligned: Pallas stays 0.
-    # Device-derived challenge lanes are printed, not required: the
-    # planner's 4-lane floor admits a 4-validator commit only when all
-    # four timestamps encode to one length, which is the clock's to decide
-    # (phase A asserts the challenge plane at every width).
+    # No batch here is 128-aligned: Pallas stays 0. A flush rides the
+    # device-challenge plane only when it holds all four votes and their
+    # timestamps encode to one length, which is the clock's to decide —
+    # so plans are not required here (phase A requires them at every
+    # width), but every plan made must have been derived on the device.
     assert_rungs("phase B", acct, aligned_ed=0, aligned_sr=0, warmed=warmed,
-                 allow_compiles=True, want_challenge=False)
+                 want_challenge=False)
     return {"heights": h, "seconds": wall, "ms_per_height": wall / h * 1e3,
             "consensus_rows": rows, "device_batches": dev_batches}
 
@@ -699,69 +780,49 @@ def run_one_chip() -> dict:
     return device
 
 
-def mesh_tables_devices() -> dict:
-    """Which device each chip's resident validator table lives on."""
-    from cometbft_tpu.ops import residency
-
-    out = {}
-    with residency._reg_lock:
-        tables = dict(residency._tables)
-    for (scheme, put_key), table in tables.items():
-        if table._dev is not None:
-            out[f"{scheme}/{put_key or 'default'}"] = sorted(
-                str(d) for d in table._dev[0].devices())
-    return out
-
-
-def mesh_phases(workloads: list, chips: int, acct: Accounting,
-                repeats: int) -> None:
-    """The commits through VerifyMesh over `chips` devices (default
-    config: mesh active, class_aware), then the same commits on one chip
-    of this process as the comparison."""
-    from cometbft_tpu.parallel import mesh as verify_mesh
-
+def mesh_phase(workloads: list, acct: Accounting, repeats: int) -> dict:
+    """The commits through VerifyMesh over MESH_CHIPS devices (default
+    config: mesh active, class_aware)."""
     say("[mesh] shard program: the XLA ladder (ed25519_kernel."
-        "_verify_kernel_ok / sr25519_kernel._verify_kernel_ok) at <= "
-        f"{verify_mesh.MAX_SHARD_ROWS} lanes per shard — NOT the Pallas "
-        "kernel and not through PallasGate; pallas.* successes of 0 are "
-        "expected in the mesh part")
+        "_verify_kernel_ok / sr25519_kernel._verify_kernel_ok), one "
+        "executable per chip and lane shape — NOT the Pallas kernel and "
+        "not through PallasGate; pallas.* successes of 0 are expected in "
+        "the mesh part")
     set_up = warm_up(workloads, 0, SEED)
     say(f"[mesh warm-up] {set_up:.1f} s of set-up (every chip instantiates "
         "its own executable per shard shape); " + acct.cache_report())
     # mesh shards never enter ed25519_kernel's shape log: nothing warmed
-    mesh_readings = phase_a(workloads, acct, set(), repeats,
-                            mesh_chips=chips, label="mesh")
-    placed = mesh_tables_devices()
-    say("[mesh] resident tables by device: "
-        + json.dumps(placed, separators=(",", ":"), sort_keys=True))
-    for _name, vals, *_ in workloads:
-        for scheme in {v.pub_key.type_() for v in vals.validators}:
-            devs = {d for k, ds in placed.items()
-                    if k.startswith(scheme + "/dev") for d in ds}
-            require(len(devs) == chips,
-                    f"{scheme} shard tables live on {sorted(devs)}, not on "
-                    f"{chips} distinct devices")
-    # comparison: the same commits on one chip of this process
+    return phase_a(workloads, acct, set(), repeats, mesh_chips=MESH_CHIPS,
+                   label="mesh")
+
+
+def one_chip_comparison(workloads: list, acct: Accounting, repeats: int,
+                        mesh_readings: dict) -> None:
+    """The same commits on one chip of this process."""
+    from cometbft_tpu.parallel import mesh as verify_mesh
+
     verify_mesh.configure(enabled=False)
     acct.mark()
-    one_set_up = warm_up(workloads, 0, SEED)
-    say(f"[one-chip warm-up] {one_set_up:.1f} s of set-up")
+    set_up = warm_up(workloads, 0, SEED)
+    say(f"[one-chip warm-up] {set_up:.1f} s of set-up")
     one_readings = phase_a(workloads, acct, bucket_set(workloads, 0),
                            repeats, label="one chip")
     for name in mesh_readings:
         say(f"[mesh vs one chip] {name}: smoke readings "
-            f"{mesh_readings[name]['wall_ms_median']:.3f} ms on {chips} "
-            f"chips (XLA shards), "
+            f"{mesh_readings[name]['wall_ms_median']:.3f} ms on "
+            f"{MESH_CHIPS} chips (XLA shards), "
             f"{one_readings[name]['wall_ms_median']:.3f} ms on one chip; "
             "verdicts identical (both equal the host oracle lane for lane)")
 
 
-def run_mesh(chips: int = 4) -> dict:
+def run_mesh() -> dict:
     """Four chips, and no other phase."""
-    device = probe_device(want_count=chips)
+    device = probe_device(want_count=MESH_CHIPS)
     boot_device_plane()
-    mesh_phases(build_workloads(MESH_SHAPES, SEED + 1), chips, Accounting(),
-                REPEATS)
+    acct = Accounting()
+    workloads = build_workloads(MESH_SHAPES, SEED + 1)
+    one_chip_comparison(workloads, acct, REPEATS,
+                        mesh_phase(workloads, acct, REPEATS))
     return device
 
 

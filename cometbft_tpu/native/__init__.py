@@ -21,7 +21,6 @@ import subprocess
 import tempfile
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_failed: set[str] = set()
 _loaded: dict[str, ctypes.CDLL] = {}
 # lib -> "built" (cc ran in this process) | "reused" (a fresh-enough .so
 # was already in the package directory) | "failed: <why>" — the smoke
@@ -48,7 +47,7 @@ def load(name: str, cflags_ladder: tuple = (("-O2",),)) -> ctypes.CDLL | None:
     different name and rebuilds, or falls back to pure Python."""
     if name in _loaded:
         return _loaded[name]
-    if name in _failed:
+    if _status.get(name, "").startswith("failed"):
         return None
     src = os.path.join(_DIR, f"{name}.c")
     suffix = ""
@@ -84,7 +83,6 @@ def load(name: str, cflags_ladder: tuple = (("-O2",),)) -> ctypes.CDLL | None:
                     os.unlink(tmp)
         lib = ctypes.CDLL(so)
     except Exception as exc:  # noqa: BLE001 - no cc / sandboxed fs: fall back
-        _failed.add(name)
         _status[name] = f"failed: {type(exc).__name__}: {exc}"
         return None
     _loaded[name] = lib

@@ -753,6 +753,7 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int,
     import jax
     import jax.numpy as jnp
 
+    count("derive_programs")  # lru miss: a program for a new geometry
     tot = 64 + plen + var + tlen
     nb = (tot + 17 + 127) // 128
     padlen = nb * 128 - tot

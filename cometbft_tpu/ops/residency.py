@@ -511,6 +511,9 @@ class KeyTable:
                 pinned_sets=len(self._pinned_sets),
                 pinned_rows=len(self._pin_count),
                 free_rows=len(self._free),
+                # where the resident arrays live (None until built)
+                devices=(sorted(str(d) for d in self._dev[0].devices())
+                         if self._dev is not None else None),
             )
 
 

@@ -191,7 +191,7 @@ class _Chip:
     and the load counters placement reads."""
 
     __slots__ = ("index", "device", "name", "inflight_lanes", "lanes_total",
-                 "shards_total")
+                 "shards_total", "shard_lanes", "array_devices")
 
     def __init__(self, index: int, device):
         self.index = index
@@ -200,6 +200,11 @@ class _Chip:
         self.inflight_lanes = 0
         self.lanes_total = 0
         self.shards_total = 0
+        # what actually ran here: the lane shapes of this chip's shards
+        # and the devices their staged arrays (words and pubkey
+        # coordinates) were committed to
+        self.shard_lanes: set[int] = set()
+        self.array_devices: set[str] = set()
 
     @property
     def supervisor(self):
@@ -508,7 +513,9 @@ class VerifyMesh:
                     a_dev = tuple(
                         jax.device_put(a, chip.device) for a in host_arrs)
                     nbytes += sum(a.nbytes for a in host_arrs)
-                jax.block_until_ready((rwd, swd, kwd) + tuple(a_dev))
+                staged = (rwd, swd, kwd) + tuple(a_dev)
+                jax.block_until_ready(staged)
+                placed = {str(d) for a in staged for d in a.devices()}
                 _linkmodel.link().observe_transfer(
                     nbytes, _time.perf_counter() - t0)
                 sp.add_bytes(tx=nbytes)
@@ -538,6 +545,8 @@ class VerifyMesh:
         with self._lock:
             chip.lanes_total += b
             chip.shards_total += 1
+            chip.shard_lanes.add(b)
+            chip.array_devices |= placed
         eligible = pre_ok & ok_a
         return mask[:n] & eligible, eligible
 
@@ -738,6 +747,8 @@ class VerifyMesh:
                 "inflight_lanes": chip.inflight_lanes,
                 "lanes_total": chip.lanes_total,
                 "shards_total": chip.shards_total,
+                "shard_lanes": sorted(chip.shard_lanes),
+                "array_devices": sorted(chip.array_devices),
                 "failures": sup.failures,
                 "successes": sup.successes,
             }

@@ -52,6 +52,7 @@ def _clean_snapshot() -> dict:
                       "lanes_host_fallback": 4000},
         "wire": {"indexed": 6, "delta": 0, "full": 0},
         "bytes_per_sig": 80.0, "fetch": {}, "link": {},
+        "table_devices": {"ed25519": ["TPU_0"], "sr25519": ["TPU_0"]},
         "dispatched_shapes": [6144], "compiles": 0,
         "sched": {"batches": 3, "class_rows": {"consensus": 30720},
                   "chaos_fallbacks": 0},
@@ -120,6 +121,20 @@ def _program_built_in_phase(s):
     s["compiles"] = 1
 
 
+def _more_built_than_new_derive_geometries_explain(s):
+    s["challenge"]["derive_programs"] = 1
+    s["compiles"] = chip_smoke.PROGRAMS_PER_DERIVE_GEOMETRY + 1
+
+
+def _planned_but_not_derived_on_device(s):
+    s["supervisors"]["ed25519.challenge"] = _sup(2)
+
+
+def _no_plan_at_full_width(s):
+    s["challenge"] = {}
+    s["supervisors"]["ed25519.challenge"] = _sup()
+
+
 def _on_the_cpu(s):
     s["device"] = {"platform": "cpu", "kind": "cpu", "count": 1}
 
@@ -132,12 +147,27 @@ def _sched_degraded(s):
     _xla_served, _pallas_never_ran, _cpu_served, _breaker_open, _retried,
     _oracle_disagreed, _checksum_mismatch, _host_challenge, _derive_failed,
     _no_indexed_send, _compiled_in_phase, _program_built_in_phase,
+    _more_built_than_new_derive_geometries_explain,
+    _planned_but_not_derived_on_device, _no_plan_at_full_width,
     _on_the_cpu, _sched_degraded,
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_rung_accounting_fails_when_a_lower_rung_served(spoil):
     snap = _clean_snapshot()
     spoil(snap)
     assert chip_smoke.check_rungs(snap, **EXPECT) != []
+
+
+def test_rung_accounting_of_a_vote_flush_phase():
+    """Phase B's reading of the same rule: narrow flushes need not plan a
+    device challenge, and a derive geometry outside the warmed family (a
+    nil vote, a round above 0) may build its few programs — no more."""
+    snap = _clean_snapshot()
+    _no_plan_at_full_width(snap)
+    assert chip_smoke.check_rungs(snap, **EXPECT, want_challenge=False) == []
+    snap = _clean_snapshot()
+    snap["challenge"]["derive_programs"] = 1
+    snap["compiles"] = chip_smoke.PROGRAMS_PER_DERIVE_GEOMETRY
+    assert chip_smoke.check_rungs(snap, **EXPECT) == []
 
 
 def _mesh_snapshot() -> dict:
@@ -149,7 +179,12 @@ def _mesh_snapshot() -> dict:
                  "readmissions": 0, "redispatched_batches": 0,
                  "fallbacks": 0,
                  "chips": {str(i): {"successes": 4, "failures": 0,
-                                    "shards": 4} for i in range(4)}}
+                                    "shards": 4, "shard_lanes": [2048],
+                                    "array_devices": [f"TPU_{i}"]}
+                           for i in range(4)}}
+    s["table_devices"] = {f"{scheme}/dev{i}": [f"TPU_{i}"]
+                          for scheme in ("ed25519", "sr25519")
+                          for i in range(4)}
     return s
 
 
@@ -161,7 +196,16 @@ def test_mesh_accounting_passes_four_live_chips():
 
 
 def _chip_idle(s):
-    s["mesh"]["chips"]["3"] = {"successes": 0, "failures": 0, "shards": 0}
+    s["mesh"]["chips"]["3"].update(successes=0, shards=0)
+
+
+def _everything_on_the_first_device(s):
+    for chip in s["mesh"]["chips"].values():
+        chip["array_devices"] = ["TPU_0"]
+
+
+def _tables_on_the_first_device(s):
+    s["table_devices"] = dict.fromkeys(s["table_devices"], ["TPU_0"])
 
 
 def _chip_evicted(s):
@@ -179,6 +223,7 @@ def _redispatched(s):
 
 @pytest.mark.parametrize("spoil", [
     _chip_idle, _chip_evicted, _mesh_fell_back, _redispatched,
+    _everything_on_the_first_device, _tables_on_the_first_device,
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_mesh_accounting_fails_on_a_missing_chip(spoil):
     snap = _mesh_snapshot()
@@ -266,7 +311,9 @@ def device_plane(monkeypatch):
     monkeypatch.setattr(
         crypto_batch, "_device",
         {"platform": "tpu", "kind": "rehearsal", "count": 1})
-    yield chip_smoke.Accounting()
+    acct = chip_smoke.Accounting()
+    yield acct
+    acct.close()
     clean()
     crypto_batch.set_backend(prev)
     dispatch.configure(watchdog_timeout=120.0)
@@ -286,6 +333,45 @@ def test_phases_rehearse_on_cpu_at_8_and_16_validators(device_plane):
     assert out["heights"] >= 3 and out["device_batches"] >= 3
 
 
+@pytest.mark.slow  # ~4 min cold: every virtual chip builds its own programs
+def test_mesh_phase_rehearses_on_four_virtual_devices(device_plane):
+    """Guide 2.2: the four-chip path on four forced host devices, steered
+    onto the branch an accelerator mesh takes (per-chip resident tables)
+    — the shards' arrays and tables must land on four distinct devices.
+    Run it before a `chiprun --chips 4` call: -m slow -k four_virtual."""
+    from cometbft_tpu.parallel import mesh as verify_mesh
+
+    acct = device_plane
+    mesh = verify_mesh.VerifyMesh(devices=jax.devices()[:4])
+    mesh._device_cache = True
+    verify_mesh._set_for_testing(mesh)
+    workloads = chip_smoke.build_workloads(
+        (("ed-32", 32, 0), ("mixed-32+32", 32, 32)), seed=6)
+    # a consensus-class batch this small is pinned to one chip
+    with sched.work_class("sync"):
+        readings = chip_smoke.mesh_phase(workloads, acct, repeats=1)
+    assert set(readings) == {"ed-32", "mixed-32+32"}
+    chips = acct.snapshot()["mesh"]["chips"]
+    assert [c["shard_lanes"] for c in chips.values()] == [[8]] * 4
+    assert len({tuple(c["array_devices"]) for c in chips.values()}) == 4
+
+
+def test_vote_geometry_family_is_what_the_planner_sees(device_plane):
+    """Every entry of the warm-up's timestamp family plans its own
+    derive geometry, all on the net's prefix length."""
+    geometries = set()
+    for nanos in chip_smoke.vote_geometry_nanos(4):
+        vals, _bid, commit = chip_smoke.make_commit(
+            4, 0, 7, chip_smoke.NET_CHAIN_ID, nanos)
+        _pubs, msgs, _sigs = chip_smoke.commit_rows(
+            vals, commit, chip_smoke.NET_CHAIN_ID)
+        plan = challenge.plan_batch(msgs, [True] * 4)
+        assert plan is not None and plan.n_eligible == 4
+        geometries.add((plan.plen, plan.var, plan.tlen))
+    assert len(geometries) == 16
+    assert len({plen for plen, _var, _tlen in geometries}) == 1
+
+
 def test_rung_accounting_catches_a_live_pallas_fault(device_plane,
                                                      monkeypatch):
     """pallas.trace=permanent on an aligned batch: PallasGate swallows the
@@ -303,9 +389,8 @@ def test_rung_accounting_catches_a_live_pallas_fault(device_plane,
     snap = acct.snapshot()
     pallas = snap["supervisors"]["pallas.ed25519"]
     assert pallas["successes"] == 0 and pallas["failures"] == 1
-    bad = chip_smoke.check_rungs(
-        snap, aligned_ed=1, aligned_sr=0, warmed={8},
-        allow_compiles=True)
+    bad = chip_smoke.check_rungs(snap, aligned_ed=1, aligned_sr=0,
+                                 warmed={8})
     assert any("pallas.ed25519" in b for b in bad)
 
 
