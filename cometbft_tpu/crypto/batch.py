@@ -12,6 +12,7 @@ from typing import Callable, Optional
 
 from cometbft_tpu import crypto
 from cometbft_tpu.crypto import ed25519
+from cometbft_tpu.libs import trace
 from cometbft_tpu.libs.prefixrows import PrefixedMsg
 
 _BACKEND = "auto"
@@ -238,8 +239,9 @@ class MixedBatchVerifier(crypto.BatchVerifier):
             masks = {kt: m for kt, m in zip(thunks, resolved)}
         else:
             masks = {kt: sub.verify()[1] for kt, sub in self._subs.items()}
-        out = [bool(masks[kt][i]) for kt, i in self._route]
-        return all(out), out
+        with trace.span("commit.verdict", cat="collect"):
+            out = [bool(masks[kt][i]) for kt, i in self._route]
+            return all(out), out
 
     def count(self) -> int:
         return len(self._route)
@@ -284,8 +286,9 @@ class ScheduledBatchVerifier(crypto.BatchVerifier):
         from cometbft_tpu import sched
 
         mask = sched.get().verify_now(self._rows, self._klass)
-        out = [bool(x) for x in mask]
-        return all(out), out
+        with trace.span("commit.verdict", cat="collect"):
+            out = [bool(x) for x in mask]
+            return all(out), out
 
     def count(self) -> int:
         return len(self._rows)

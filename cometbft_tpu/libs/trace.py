@@ -36,8 +36,18 @@ Design constraints, in priority order:
                        log lines correlate by trace/span id (libs/log.py
                        stamps them automatically).
 
-Stage categories (the attribution model):
+Stage categories (the attribution model), from the entry down:
 
+  node       a call's root (`commit.verify`, `commit.stage_verify`,
+             `commit.prefetch`, `commit.resolve`): its SELF time is what
+             no finer span below covers — the unattributed host time of
+             the commit path
+  signbytes  CanonicalVote sign-bytes encoding (`commit.sign_bytes`; on
+             the serial path, attr `serial`, one span around the loop
+             that encodes and verifies a signature at a time) and the
+             sr25519 Merlin/STROBE transcripts (`sr25519.transcript`)
+  collect    a commit turned into rows (`commit.rows`) and a mask turned
+             back into the answer (`commit.verdict`)
   queue      submit->dispatch wait in the verify scheduler
   stage      host staging: structural checks, hashing, packing
   transfer   host->device bytes (staged words, pubkey coordinate tables)
@@ -46,17 +56,18 @@ Stage categories (the attribution model):
   compute    device dispatch / host-oracle verification
   fetch      device->host result bytes (reduced-fetch headers, payloads)
   resolve    mask decode, integrity checks, host re-checks, slicing
+  gc         a full (generation 2) collection's pause (`gc.full`), child
+             of whatever span was open on the thread it stopped
 
-Overlap model (double-buffered dispatch): with two in-flight slots per
-fault domain (ops/dispatch.DoubleBuffer) batch N's host->device transfer
-runs WHILE batch N-1's kernel computes on another pool thread. Summing
-both wall intervals would double-count the overlapped nanoseconds — the
-transfer wasn't pipeline cost, it was hidden behind compute. So a
-finishing transfer span bills only the part of its self time that did
-NOT intersect device-busy (compute/challenge) intervals on OTHER
-threads; the intersected part accumulates separately and is surfaced as
-`h2d_overlap_us` / `h2d_overlap_fraction` = overlap/(transfer+overlap)
-— the measured did-the-double-buffer-actually-overlap number.
+One clock with the device trace: while the tracer is on, a span entered
+as a context manager also enters a `jax.profiler.TraceAnnotation` of its
+name on its own thread, so a profiler trace taken meanwhile holds the
+program's spans beside the runtime's events and the device's idle gaps
+(benchmarks/reduce.py names each gap by the innermost host event over
+it). `begin()` timelines, `event()` instants and a `finish()` without
+`__exit__` enter none: a TraceMe is bound to its thread's stack. Without
+JAX there is no annotation and no error. Device-side overlap of transfer
+and compute is read from that trace, not inferred from host spans.
 
 Span parenting uses a contextvars.ContextVar, so nesting is correct per
 thread AND per asyncio task with no explicit plumbing; `wrap_ctx()` hands
@@ -67,6 +78,7 @@ pools) so device-side spans stay in their batch's tree.
 from __future__ import annotations
 
 import contextvars
+import gc
 import itertools
 import json
 import threading
@@ -77,37 +89,8 @@ from typing import Any, Callable, Optional
 # Stage categories counted by the attribution model. Spans with any other
 # cat ("sched", "consensus", "sync", "mempool", "device", ...) appear in
 # the trace but never in stage shares — they are containers, not stages.
-STAGES = ("queue", "stage", "transfer", "challenge", "compute", "fetch",
-          "resolve")
-
-# device-busy categories for the h2d overlap model: a transfer span's
-# nanoseconds that intersect one of these on ANOTHER thread bill as
-# overlap, not transfer
-_BUSY_CATS = ("challenge", "compute")
-
-# finished busy intervals kept for the overlap window: must cover every
-# transfer that could have overlapped a compute that already finished —
-# a handful of in-flight batches, so a small ring is plenty
-_BUSY_KEEP = 64
-
-
-def _union_overlap_ns(t0: int, t1: int, intervals) -> int:
-    """|[t0, t1] ∩ union(intervals)| in ns (intervals may overlap each
-    other; they are clipped, merged, then summed)."""
-    clipped = sorted((max(t0, a), min(t1, b)) for a, b in intervals
-                     if b > t0 and a < t1)
-    total = 0
-    cur_a = cur_b = None
-    for a, b in clipped:
-        if cur_b is None or a > cur_b:
-            if cur_b is not None:
-                total += cur_b - cur_a
-            cur_a, cur_b = a, b
-        elif b > cur_b:
-            cur_b = b
-    if cur_b is not None:
-        total += cur_b - cur_a
-    return total
+STAGES = ("node", "signbytes", "collect", "queue", "stage", "transfer",
+          "challenge", "compute", "fetch", "resolve", "gc")
 
 _enabled = False  # module-global fast path: read before anything else
 
@@ -122,7 +105,7 @@ class Span:
 
     __slots__ = ("id", "parent", "trace_id", "name", "cat", "t0", "t1",
                  "tid", "attrs", "bytes_tx", "bytes_rx", "_covered",
-                 "_token", "_done")
+                 "_token", "_done", "_ann")
 
     def __init__(self, id_: int, parent: Optional["Span"], name: str,
                  cat: str, attrs: dict, t0: int):
@@ -140,6 +123,7 @@ class Span:
         self._covered = 0  # ns of stage-categorized descendant time
         self._token = None
         self._done = False
+        self._ann = None  # the profiler annotation entered with the span
 
     # ------------------------------------------------------------- attrs
 
@@ -160,10 +144,19 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current.set(self)
+        ann = _annotation
+        if ann is not None:
+            # the same interval on the profiler's clock, on this thread:
+            # left in __exit__ only (a TraceMe is bound to its thread)
+            self._ann = ann(self.name)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.finish()
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(*exc)
         return False
 
     def finish(self) -> None:
@@ -234,12 +227,17 @@ class Tracer:
         self._attr_rows = 0
         self._attr_tx = 0
         self._attr_rx = 0
-        # h2d overlap model state: live device-busy spans (id -> (tid,
-        # t0)), recently finished busy intervals (tid, t0, t1), and the
-        # overlap accumulator (transfer ns hidden behind compute)
-        self._open_busy: dict[int, tuple[int, int]] = {}
-        self._done_busy: deque = deque(maxlen=_BUSY_KEEP)
-        self._attr_overlap = 0
+        # collector pauses: the gc hook stamps them here lock-free (it
+        # runs wherever an allocation triggers a collection, also inside
+        # _finish under self._lock); the next _finish or attribution()
+        # folds them in
+        self._gc_pending: deque = deque()
+        self._gc_open: Optional[tuple[int, int]] = None  # (generation, t0)
+        self._gc_counts = [0, 0, 0]
+        # thread -> the span whose _finish is running there: it has left
+        # the context stack already, and a pause inside its bookkeeping
+        # is still its own
+        self._finishing: dict[int, Span] = {}
 
     # ------------------------------------------------------------- spans
 
@@ -249,15 +247,22 @@ class Tracer:
         # the new span instead of leaking it into the parent's uncovered
         # gap (per-batch coverage is an acceptance number)
         t0 = self._clock()
-        s = Span(next(self._ids), _current.get(), name, cat, attrs, t0)
-        if cat in _BUSY_CATS:
-            # register live device-busy work for the overlap model; only
-            # busy cats pay the lock here, the common span stays lock-free
-            with self._lock:
-                self._open_busy[s.id] = (s.tid, t0)
-        return s
+        return Span(next(self._ids), _current.get(), name, cat, attrs, t0)
 
     def _finish(self, span: Span) -> None:
+        tid = threading.get_ident()
+        self._finishing[tid] = span
+        try:
+            self._account_finish(span)
+        finally:
+            del self._finishing[tid]
+
+    def _account_finish(self, span: Span) -> None:
+        if self._gc_pending:
+            # before this span's own coverage is read: a pause that
+            # stopped it or a descendant must not count twice
+            with self._lock:
+                self._fold_gc()
         # ring write FIRST, before t1 is read: the Span object itself
         # goes into the ring (rendered to a dict lazily by snapshot()),
         # so the bulk of finish bookkeeping is timed INSIDE the span
@@ -266,9 +271,7 @@ class Tracer:
         # erode it. The counter bump is atomic under the GIL; a torn
         # read during snapshot() costs at most one stale slot, never a
         # crash — the price of keeping the hot path lock-free.
-        pos = next(self._ctr)
-        self._buf[pos % self.capacity] = span
-        self._pos = pos + 1
+        self._ring_put(span)
         counted = span.cat in STAGES
         instant = span.attrs.get("instant", False)
         parent = span.parent
@@ -290,26 +293,7 @@ class Tracer:
             with self._lock:
                 span.t1 = self._clock()
                 dur = 0 if instant else max(0, span.t1 - span.t0)
-                self_ns = max(0, dur - span._covered)
-                if span.cat in _BUSY_CATS:
-                    self._open_busy.pop(span.id, None)
-                    if dur:
-                        self._done_busy.append((span.tid, span.t0, span.t1))
-                elif span.cat == "transfer" and dur:
-                    # overlapped h2d bills as overlap, not transfer: the
-                    # busy set is live spans (busy through our t1) plus
-                    # recently finished intervals, other threads only
-                    ivals = [(b0, span.t1)
-                             for (btid, b0) in self._open_busy.values()
-                             if btid != span.tid]
-                    ivals.extend(
-                        (b0, b1) for (btid, b0, b1) in self._done_busy
-                        if btid != span.tid)
-                    ov = min(self_ns,
-                             _union_overlap_ns(span.t0, span.t1, ivals))
-                    self._attr_overlap += ov
-                    self_ns -= ov
-                self._attr_ns[span.cat] += self_ns
+                self._attr_ns[span.cat] += max(0, dur - span._covered)
                 self._attr_rows += rows
                 self._attr_tx += span.bytes_tx
                 self._attr_rx += span.bytes_rx
@@ -336,6 +320,58 @@ class Tracer:
             budget_ms = span.attrs.get("slow_ms", self.slow_ms)
             if dur >= budget_ms * 1e6:
                 self._capture_slow(span)
+
+    def _ring_put(self, span: Span) -> None:
+        pos = next(self._ctr)
+        self._buf[pos % self.capacity] = span
+        self._pos = pos + 1
+
+    # ---------------------------------------------------- collector pauses
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """The gc.callbacks hook. Takes no lock and makes no span: it may
+        run under self._lock, which is not re-entrant. Collections never
+        nest and run with the GIL held, so the one open slot is enough."""
+        if phase == "start":
+            self._gc_open = (info["generation"], self._clock())
+            return
+        opened, self._gc_open = self._gc_open, None
+        if opened is None:
+            return  # hooked between a collection's start and its stop
+        gen, t0 = opened
+        self._gc_counts[gen] += 1
+        if gen == 2:
+            tid = threading.get_ident()
+            self._gc_pending.append((t0, self._clock(), tid,
+                                     self._finishing.get(tid)
+                                     or _current.get()))
+
+    def _fold_gc(self) -> None:
+        """Turn the pauses the hook stamped into `gc.full` spans (under
+        self._lock), each the child of the innermost span it lies in: it
+        covers its time at the nearest ancestor still running, or comes
+        off the stage of the first one that finished with the pause
+        inside it."""
+        while self._gc_pending:
+            t0, t1, tid, parent = self._gc_pending.popleft()
+            while parent is not None and parent.t1 and t0 >= parent.t1:
+                # stamped in that span's _finish, after its end was read
+                parent = parent.parent
+            span = Span(next(self._ids), parent, "gc.full", "gc",
+                        {"generation": 2}, t0)
+            span.tid, span.t1, span._done = tid, t1, True
+            self._ring_put(span)
+            dur = max(0, t1 - t0)
+            self._attr_ns["gc"] += dur
+            while parent is not None:
+                if not parent.t1:
+                    parent._covered += dur
+                    break
+                if parent.cat in STAGES:
+                    self._attr_ns[parent.cat] = max(
+                        0, self._attr_ns[parent.cat] - dur)
+                    break
+                parent = parent.parent
 
     def _render(self, span: Span) -> dict:
         dur = 0 if span.attrs.get("instant") \
@@ -386,10 +422,13 @@ class Tracer:
 
     def attribution(self) -> dict:
         with self._lock:
+            self._fold_gc()
             ns = dict(self._attr_ns)
             rows, tx, rx = self._attr_rows, self._attr_tx, self._attr_rx
-            overlap = self._attr_overlap
-        return _attribution_dict(ns, rows, tx, rx, overlap)
+            gen0, gen1, gen2 = self._gc_counts
+        out = _attribution_dict(ns, rows, tx, rx)
+        out["gc_collections"] = {"gen0": gen0, "gen1": gen1, "gen2": gen2}
+        return out
 
     def reset_attribution(self) -> None:
         with self._lock:
@@ -397,9 +436,8 @@ class Tracer:
             self._attr_rows = 0
             self._attr_tx = 0
             self._attr_rx = 0
-            self._attr_overlap = 0
-            self._open_busy.clear()
-            self._done_busy.clear()
+            self._gc_pending.clear()
+            self._gc_counts = [0, 0, 0]
 
     # ----------------------------------------------------------- reading
 
@@ -428,6 +466,35 @@ class Tracer:
 
 _T: Optional[Tracer] = None
 _cfg_lock = threading.Lock()
+
+# jax.profiler.TraceAnnotation while the tracer is on and JAX imports,
+# else None: Span.__enter__ reads it once
+_annotation: Any = None
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    t = _T
+    if t is not None:
+        t._on_gc(phase, info)
+
+
+def _switch_hooks(on: bool) -> None:
+    """The tracer's two hooks into the process, held only while it is on
+    (under _cfg_lock): the profiler annotation class and the collector's
+    callback."""
+    global _annotation
+    if _gc_hook in gc.callbacks:
+        gc.callbacks.remove(_gc_hook)
+    _annotation = None
+    if not on:
+        return
+    try:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    except Exception:  # noqa: BLE001 - no JAX: spans without annotations
+        pass
+    gc.callbacks.append(_gc_hook)
 
 
 # ------------------------------------------------------------- public API
@@ -555,6 +622,7 @@ def configure(enabled: bool | None = None, capacity: int | None = None,
             _T.slow_ms = slow_ms
         if enabled is not None:
             _enabled = enabled
+            _switch_hooks(enabled)
 
 
 def reset() -> None:
@@ -563,6 +631,7 @@ def reset() -> None:
     with _cfg_lock:
         _enabled = False
         _T = None
+        _switch_hooks(False)
 
 
 def snapshot() -> list[dict]:
@@ -607,17 +676,12 @@ def reset_attribution() -> None:
 # --------------------------------------------------------- the model
 
 
-def _attribution_dict(ns: dict, rows: int, tx: int, rx: int,
-                      overlap_ns: int = 0) -> dict:
+def _attribution_dict(ns: dict, rows: int, tx: int, rx: int) -> dict:
     total = sum(ns.get(s, 0) for s in STAGES)
     shares = {
         s: (round(ns.get(s, 0) / total, 4) if total else 0.0)
         for s in STAGES
     }
-    # overlap is transfer time hidden behind compute on another thread:
-    # already excluded from the transfer bill (and from total — it was
-    # not pipeline cost), reported as the did-we-overlap fraction
-    h2d = ns.get("transfer", 0) + overlap_ns
     return {
         "stage_us": {s: round(ns.get(s, 0) / 1e3, 1) for s in STAGES},
         "stage_share": shares,
@@ -627,10 +691,6 @@ def _attribution_dict(ns: dict, rows: int, tx: int, rx: int,
         "wire_rx_bytes": rx,
         "bytes_per_sig_tx": round(tx / rows, 2) if rows else None,
         "bytes_per_sig_rx": round(rx / rows, 2) if rows else None,
-        "h2d_overlap_us": round(overlap_ns / 1e3, 1),
-        # 6 decimals: a real-but-thin overlap (host-heavy boxes dilute the
-        # denominator with pubkey-staging wall time) must not read as 0.0
-        "h2d_overlap_fraction": round(overlap_ns / h2d, 6) if h2d else 0.0,
     }
 
 
@@ -643,30 +703,16 @@ def attribution_of(spans: list[dict]) -> dict:
     fails if the share math drifts."""
     by_id = {r["id"]: r for r in spans}
     covered: dict[int, int] = {}
-    # the offline overlap model sees every busy interval up front
-    busy_by_tid: dict[int, list[tuple[int, int]]] = {}
-    for r in spans:
-        if r["cat"] in _BUSY_CATS and r["dur_ns"]:
-            busy_by_tid.setdefault(r["tid"], []).append(
-                (r["t0_ns"], r["t0_ns"] + r["dur_ns"]))
     # children finish before parents, so a single pass over spans sorted
     # by END time ascending propagates coverage bottom-up
     order = sorted(spans, key=lambda r: r["t0_ns"] + r["dur_ns"])
     ns = {s: 0 for s in STAGES}
-    rows = tx = rx = overlap = 0
+    rows = tx = rx = 0
     for r in order:
         counted = r["cat"] in STAGES
         cov = covered.get(r["id"], 0)
         if counted:
-            self_ns = max(0, r["dur_ns"] - cov)
-            if r["cat"] == "transfer" and r["dur_ns"]:
-                ivals = [iv for tid, lst in busy_by_tid.items()
-                         if tid != r["tid"] for iv in lst]
-                ov = min(self_ns, _union_overlap_ns(
-                    r["t0_ns"], r["t0_ns"] + r["dur_ns"], ivals))
-                overlap += ov
-                self_ns -= ov
-            ns[r["cat"]] += self_ns
+            ns[r["cat"]] += max(0, r["dur_ns"] - cov)
             n = r["attrs"].get("sig_rows", 0)
             rows += n if isinstance(n, int) else 0
             tx += r.get("bytes_tx", 0)
@@ -675,7 +721,7 @@ def attribution_of(spans: list[dict]) -> dict:
         if pid is not None and pid in by_id:
             covered[pid] = covered.get(pid, 0) + (
                 r["dur_ns"] if counted else cov)
-    return _attribution_dict(ns, rows, tx, rx, overlap)
+    return _attribution_dict(ns, rows, tx, rx)
 
 
 # ----------------------------------------------------------- exporters

@@ -185,7 +185,8 @@ def _compress_pairs(whi, wlo):
             ah, al = _add64(ah, al, *s1)
             return wh.at[t].set(ah), wl.at[t].set(al)
 
-        wh, wl = jax.lax.fori_loop(16, 80, _sched, (wh, wl))
+        with jax.named_scope("sha512_schedule"):
+            wh, wl = jax.lax.fori_loop(16, 80, _sched, (wh, wl))
 
         def _round(t, st):
             (ah, al, bh, bl, ch, cl, dh, dl,
@@ -208,7 +209,8 @@ def _compress_pairs(whi, wlo):
             return (nah, nal, ah, al, bh, bl, ch, cl,
                     neh, nel, eh, el, fh, fl, gh, gl)
 
-        st = jax.lax.fori_loop(0, 80, _round, tuple(state))
+        with jax.named_scope("sha512_rounds"):
+            st = jax.lax.fori_loop(0, 80, _round, tuple(state))
         nxt = []
         for i in range(8):
             sh, sl = _add64(state[2 * i], state[2 * i + 1],
@@ -329,11 +331,11 @@ def _digest_fn(nb: int):
     import jax
     import jax.numpy as jnp
 
-    def f(buf):
+    def sha512_digest(buf):
         st = _compress_pairs(*_pairs_from_be_bytes(buf))
         return jnp.stack(st, axis=1)  # (N, 16): h0hi, h0lo, ...
 
-    return jax.jit(f)
+    return jax.jit(sha512_digest)
 
 
 def sha512_rows_device(rows: np.ndarray) -> np.ndarray:
@@ -352,13 +354,14 @@ def _reduce_fn():
     import jax
     import jax.numpy as jnp
 
-    def f(w):  # (N, 16) uint32 LE digest words
+    def reduce_mod_l(w):  # (N, 16) uint32 LE digest words
         limbs = []
         for i in range(16):
             limbs += [w[:, i] & 0xFFFF, w[:, i] >> 16]
-        return jnp.transpose(_limbs_to_words(_barrett_mod_l(limbs)))
+        with jax.named_scope("barrett_mod_l"):
+            return jnp.transpose(_limbs_to_words(_barrett_mod_l(limbs)))
 
-    return jax.jit(f)
+    return jax.jit(reduce_mod_l)
 
 
 def reduce512_mod_l_device(digests: np.ndarray) -> np.ndarray:
@@ -398,7 +401,7 @@ def _tab_scatter_fn(db: int):
     import jax
     import jax.numpy as jnp
 
-    def f(tab, idx, vals):
+    def prefix_table_scatter(tab, idx, vals):
         new = tab.at[idx].set(vals)
         w = (jnp.arange(vals.size, dtype=jnp.uint32) * _CHK_MULT
              + jnp.uint32(1))
@@ -407,7 +410,7 @@ def _tab_scatter_fn(db: int):
         chk = chk + jnp.sum(idx.astype(jnp.uint32), dtype=jnp.uint32)
         return new, chk
 
-    return jax.jit(f)
+    return jax.jit(prefix_table_scatter)
 
 
 def _pow2(n: int) -> int:
@@ -763,7 +766,7 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int,
                                  dtype=np.uint8)
     sw = stream_words(bucket, var)
 
-    def f(flat, aw, ptab, *fk):
+    def derive_challenge(flat, aw, ptab, *fk):
         stream = flat[16 * bucket:16 * bucket + sw]
         sb = jnp.stack([(stream >> (8 * k)) & 0xFF for k in range(4)],
                        axis=-1).reshape(-1).astype(jnp.uint8)
@@ -790,7 +793,8 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int,
                                           (bucket, padlen)))
         msg = jnp.concatenate(parts, axis=1)  # (bucket, nb*128)
         st = _compress_pairs(*_pairs_from_be_bytes(msg))
-        kw = _limbs_to_words(_barrett_mod_l(_state_to_limbs(st)))
+        with jax.named_scope("barrett_mod_l"):
+            kw = _limbs_to_words(_barrett_mod_l(_state_to_limbs(st)))
         kw = kw * use_dev
         if fb:
             fkw, fidx = fk
@@ -798,5 +802,5 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int,
         return flat, kw
 
     if donate:
-        return jax.jit(f, donate_argnums=(0,))
-    return jax.jit(f)
+        return jax.jit(derive_challenge, donate_argnums=(0,))
+    return jax.jit(derive_challenge)

@@ -97,9 +97,10 @@ def bucket_size(n: int) -> int:
 @jax.jit
 def _decompress_kernel(words: jnp.ndarray):
     """(8, B) uint32 packed encodings -> (ok, X, Y, Z, T) each (20, B)."""
-    y = U.words_to_y_limbs(words)
-    sign = U.words_sign(words)
-    ok, p = curve.decompress_zip215(y, sign)
+    with jax.named_scope("decompress"):
+        y = U.words_to_y_limbs(words)
+        sign = U.words_sign(words)
+        ok, p = curve.decompress_zip215(y, sign)
     return ok, p.x, p.y, p.z, p.t
 
 
@@ -250,11 +251,12 @@ _BAD_MAGIC = np.uint32(~0x600DFA57 & 0xFFFFFFFF)
 def _integrity_parts_expr(mask, allok, rw, sw, kw, expected):
     """-> ((2,) uint32 reduced-fetch header, (2B+1,) bool full payload
     [mask, ~mask (echo), staging-checksum ok])."""
-    chk = _device_checksum_expr((rw, sw, kw))
-    ok = chk == expected.astype(jnp.uint32)
-    payload = jnp.concatenate([mask, ~mask, ok[None]])
-    tok = chk ^ jnp.where(allok & ok, OK_MAGIC, _BAD_MAGIC)
-    return jnp.stack([tok, ~tok]), payload
+    with jax.named_scope("integrity"):
+        chk = _device_checksum_expr((rw, sw, kw))
+        ok = chk == expected.astype(jnp.uint32)
+        payload = jnp.concatenate([mask, ~mask, ok[None]])
+        tok = chk ^ jnp.where(allok & ok, OK_MAGIC, _BAD_MAGIC)
+        return jnp.stack([tok, ~tok]), payload
 
 
 # NOT donated: the header/payload outputs are tiny (2 words + 2B+1
@@ -272,11 +274,12 @@ def _integrity_parts_arrs_expr(mask, allok, expected, *arrs):
     sets: the device-challenge wire is a flat block (+ optional fallback-k
     scatter arrays), not three fixed r/s/k planes, and the checksummed set
     differs per degradation rung. Same header/payload contract."""
-    chk = _device_checksum_expr(arrs)
-    ok = chk == expected.astype(jnp.uint32)
-    payload = jnp.concatenate([mask, ~mask, ok[None]])
-    tok = chk ^ jnp.where(allok & ok, OK_MAGIC, _BAD_MAGIC)
-    return jnp.stack([tok, ~tok]), payload
+    with jax.named_scope("integrity"):
+        chk = _device_checksum_expr(arrs)
+        ok = chk == expected.astype(jnp.uint32)
+        payload = jnp.concatenate([mask, ~mask, ok[None]])
+        tok = chk ^ jnp.where(allok & ok, OK_MAGIC, _BAD_MAGIC)
+        return jnp.stack([tok, ~tok]), payload
 
 
 _integrity_parts_arrs = jax.jit(_integrity_parts_arrs_expr)

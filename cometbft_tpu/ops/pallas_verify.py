@@ -208,6 +208,7 @@ def _verify_pallas_bench(
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ),
         interpret=interpret,
+        name=f"verify_ladder_{scheme}",
     )(*_const_args(), ax, ay, az, at, r_words, s_dig, k_dig)
     return mask[0] != 0, allok[0, 0] != 0
 

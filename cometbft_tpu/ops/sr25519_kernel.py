@@ -101,7 +101,8 @@ def ristretto_decode_device(w: jnp.ndarray) -> tuple[jnp.ndarray, curve.Point]:
 
 @jax.jit
 def _decompress_kernel(words: jnp.ndarray):
-    ok, p = ristretto_decode_device(words)
+    with jax.named_scope("decompress"):
+        ok, p = ristretto_decode_device(words)
     return ok, p.x, p.y, p.z, p.t
 
 
@@ -223,8 +224,9 @@ def stage_rows_sr(
     # still shared inside srm)
     from cometbft_tpu.libs.prefixrows import as_bytes
 
-    k_rows = srm.batch_challenge_words_rows(
-        safe_pubs, r_rows, [as_bytes(m) for m in msgs])
+    with _trace.span("sr25519.transcript", cat="signbytes", rows=n):
+        k_rows = srm.batch_challenge_words_rows(
+            safe_pubs, r_rows, [as_bytes(m) for m in msgs])
     k_rows[~pre_ok] = 0
 
     if out is None:

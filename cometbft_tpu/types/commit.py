@@ -12,6 +12,7 @@ from dataclasses import dataclass, field
 
 from cometbft_tpu import crypto
 from cometbft_tpu.crypto import merkle
+from cometbft_tpu.libs import trace
 from cometbft_tpu.types.basic import BlockID, BlockIDFlag, SignedMsgType
 from cometbft_tpu.types.vote import Vote
 from cometbft_tpu.utils import cmttime
@@ -144,17 +145,22 @@ class Commit:
         copies (the reduced-send protocol's host half) — per-row Writer
         construction was the dominant host cost of blocksync staging,
         and the prefix copies were most of what remained."""
+        if self._sign_rows is None:
+            self._sign_rows = {}
+        rows = self._sign_rows.get(chain_id)
+        with trace.span("commit.sign_bytes", cat="signbytes",
+                        cached=rows is not None):
+            if rows is None:
+                rows = self._build_sign_rows(chain_id)
+        return rows
+
+    def _build_sign_rows(self, chain_id: str):
         from collections import Counter
 
         from cometbft_tpu.libs.prefixrows import SharedPrefixRows
         from cometbft_tpu.types import canonical
         from cometbft_tpu.utils.protobuf import encode_uvarint
 
-        if self._sign_rows is None:
-            self._sign_rows = {}
-        cached = self._sign_rows.get(chain_id)
-        if cached is not None:
-            return cached
         w = pb.Writer()
         w.uvarint(1, int(SignedMsgType.PRECOMMIT))
         w.sfixed64(2, self.height)

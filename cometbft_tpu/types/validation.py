@@ -21,6 +21,7 @@ from dataclasses import dataclass
 from typing import Callable
 
 from cometbft_tpu.crypto import batch as crypto_batch
+from cometbft_tpu.libs import trace
 from cometbft_tpu.types.basic import BlockID, BlockIDFlag
 from cometbft_tpu.types.commit import Commit, CommitSig
 from cometbft_tpu.types.validator import ValidatorSet
@@ -45,6 +46,16 @@ class ErrNotEnoughVotingPowerSigned(Exception):
 
 class ErrInvalidCommitSignature(Exception):
     pass
+
+
+def _root_span(name: str, commit: Commit | None, path: str):
+    """The root span of one call into this module (libs/trace.py, cat
+    `node`): whatever no finer span below it covers is its SELF time, the
+    unattributed host time of the commit path."""
+    if commit is None:
+        return trace.span(name, cat="node", path=path)
+    return trace.span(name, cat="node", path=path, height=commit.height,
+                      sigs=len(commit.signatures))
 
 
 def _verify_basic(vals: ValidatorSet, commit: Commit, height: int, block_id: BlockID) -> None:
@@ -85,52 +96,53 @@ def _commit_rows(
     (types/validation.go:153-257 loop body): select signatures, tally power,
     enforce the threshold. Returns (pubkeys, sign_bytes, sigs, commit_idxs);
     raises ErrNotEnoughVotingPowerSigned below threshold."""
-    seen_vals: dict[int, int] = {}
-    pubs: list = []
-    sigs: list[bytes] = []
-    idxs: list[int] = []
-    tallied = 0
-    sign_rows = commit.vote_sign_bytes_all(chain_id)
-    # epoch-keyed device residency (reduced-send protocol): announce the
-    # active validator set so the kernels' resident key tables pin its
-    # rows and churn ships only deltas (ops/residency.py; never raises)
-    try:
-        from cometbft_tpu.ops import residency as _residency
+    with trace.span("commit.rows", cat="collect"):
+        seen_vals: dict[int, int] = {}
+        pubs: list = []
+        sigs: list[bytes] = []
+        idxs: list[int] = []
+        tallied = 0
+        sign_rows = commit.vote_sign_bytes_all(chain_id)
+        # epoch-keyed device residency (reduced-send protocol): announce the
+        # active validator set so the kernels' resident key tables pin its
+        # rows and churn ships only deltas (ops/residency.py; never raises)
+        try:
+            from cometbft_tpu.ops import residency as _residency
 
-        _residency.announce_validator_set(vals)
-    except Exception:  # noqa: BLE001 - residency is an optimization layer
-        pass
-    for idx, cs in enumerate(commit.signatures):
-        if ignore_sig(cs):
-            continue
-        if lookup_by_index:
-            val = vals.validators[idx]
-        else:
-            val_idx, val = vals.get_by_address(cs.validator_address)
-            if val is None:
+            _residency.announce_validator_set(vals)
+        except Exception:  # noqa: BLE001 - residency is an optimization layer
+            pass
+        for idx, cs in enumerate(commit.signatures):
+            if ignore_sig(cs):
                 continue
-            if val_idx in seen_vals:
-                raise ValueError(
-                    f"double vote from {val.address.hex()} ({seen_vals[val_idx]} and {idx})"
-                )
-            seen_vals[val_idx] = idx
-        pubs.append(val.pub_key)
-        sigs.append(cs.signature)
-        idxs.append(idx)
-        if count_sig(cs):
-            tallied += val.voting_power
-        if not count_all_signatures and tallied > voting_power_needed:
-            break
-    if tallied <= voting_power_needed:
-        raise ErrNotEnoughVotingPowerSigned(got=tallied, needed=voting_power_needed)
-    # factored (shared-prefix) rows when the builder supports them: the
-    # staging fast path reassembles whole runs with one prefix broadcast
-    # instead of N per-row copies (libs/prefixrows.py)
-    if hasattr(sign_rows, "rows_for"):
-        msgs = sign_rows.rows_for(idxs)
-    else:
-        msgs = [sign_rows[i] for i in idxs]
-    return pubs, msgs, sigs, idxs
+            if lookup_by_index:
+                val = vals.validators[idx]
+            else:
+                val_idx, val = vals.get_by_address(cs.validator_address)
+                if val is None:
+                    continue
+                if val_idx in seen_vals:
+                    raise ValueError(
+                        f"double vote from {val.address.hex()} ({seen_vals[val_idx]} and {idx})"
+                    )
+                seen_vals[val_idx] = idx
+            pubs.append(val.pub_key)
+            sigs.append(cs.signature)
+            idxs.append(idx)
+            if count_sig(cs):
+                tallied += val.voting_power
+            if not count_all_signatures and tallied > voting_power_needed:
+                break
+        if tallied <= voting_power_needed:
+            raise ErrNotEnoughVotingPowerSigned(got=tallied, needed=voting_power_needed)
+        # factored (shared-prefix) rows when the builder supports them: the
+        # staging fast path reassembles whole runs with one prefix broadcast
+        # instead of N per-row copies (libs/prefixrows.py)
+        if hasattr(sign_rows, "rows_for"):
+            msgs = sign_rows.rows_for(idxs)
+        else:
+            msgs = [sign_rows[i] for i in idxs]
+        return pubs, msgs, sigs, idxs
 
 
 def _bls_aggregate_ok(pubs, msgs, sigs) -> bool | None:
@@ -187,12 +199,13 @@ def _bls_aggregate_agg_ok(pubs, msgs, agg_sig) -> bool | None:
 
 
 def _raise_first_bad(commit: Commit, idxs: list[int], mask) -> None:
-    for i, sig_ok in enumerate(mask):
-        if not sig_ok:
-            idx = idxs[i]
-            raise ErrInvalidCommitSignature(
-                f"wrong signature (#{idx}): {commit.signatures[idx].signature.hex()}"
-            )
+    with trace.span("commit.verdict", cat="collect"):
+        for i, sig_ok in enumerate(mask):
+            if not sig_ok:
+                idx = idxs[i]
+                raise ErrInvalidCommitSignature(
+                    f"wrong signature (#{idx}): {commit.signatures[idx].signature.hex()}"
+                )
 
 
 def _verify_commit_batch(
@@ -218,8 +231,9 @@ def _verify_commit_batch(
     # (BASELINE config 5 mega-commits mix ed25519 + sr25519 validators)
     bv = crypto_batch.create_mixed_batch_verifier()
     try:
-        for pub, msg, sig in zip(pubs, msgs, sigs):
-            bv.add(pub, msg, sig)
+        with trace.span("commit.rows", cat="collect"):
+            for pub, msg, sig in zip(pubs, msgs, sigs):
+                bv.add(pub, msg, sig)
     except Exception as e:  # noqa: BLE001 - unbatchable key type in the set
         from cometbft_tpu.libs import log as _log
 
@@ -249,69 +263,96 @@ def _verify_commit_single(
     """types/validation.go:266-330."""
     seen_vals: dict[int, int] = {}
     tallied = 0
-    for idx, cs in enumerate(commit.signatures):
-        if ignore_sig(cs):
-            continue
-        if lookup_by_index:
-            val = vals.validators[idx]
-        else:
-            val_idx, val = vals.get_by_address(cs.validator_address)
-            if val is None:
+    # one span around the loop, never one a signature: on this path the
+    # sign-bytes are encoded per index between the host verifications
+    with trace.span("commit.sign_bytes", cat="signbytes", serial=True):
+        for idx, cs in enumerate(commit.signatures):
+            if ignore_sig(cs):
                 continue
-            if val_idx in seen_vals:
-                raise ValueError(
-                    f"double vote from {val.address.hex()} ({seen_vals[val_idx]} and {idx})"
+            if lookup_by_index:
+                val = vals.validators[idx]
+            else:
+                val_idx, val = vals.get_by_address(cs.validator_address)
+                if val is None:
+                    continue
+                if val_idx in seen_vals:
+                    raise ValueError(
+                        f"double vote from {val.address.hex()} ({seen_vals[val_idx]} and {idx})"
+                    )
+                seen_vals[val_idx] = idx
+            sign_bytes = commit.vote_sign_bytes(chain_id, idx)
+            if not val.pub_key.verify_signature(sign_bytes, cs.signature):
+                raise ErrInvalidCommitSignature(
+                    f"wrong signature (#{idx}): {cs.signature.hex()}"
                 )
-            seen_vals[val_idx] = idx
-        sign_bytes = commit.vote_sign_bytes(chain_id, idx)
-        if not val.pub_key.verify_signature(sign_bytes, cs.signature):
-            raise ErrInvalidCommitSignature(
-                f"wrong signature (#{idx}): {cs.signature.hex()}"
-            )
-        if count_sig(cs):
-            tallied += val.voting_power
-        if not count_all_signatures and tallied > voting_power_needed:
-            return
+            if count_sig(cs):
+                tallied += val.voting_power
+            if not count_all_signatures and tallied > voting_power_needed:
+                return
     if tallied <= voting_power_needed:
         raise ErrNotEnoughVotingPowerSigned(got=tallied, needed=voting_power_needed)
+
+
+def _verify_commit_rows(
+    chain_id: str,
+    vals: ValidatorSet,
+    commit: Commit,
+    voting_power_needed: int,
+    ignore_sig: Callable[[CommitSig], bool],
+    count_sig: Callable[[CommitSig], bool],
+    count_all_signatures: bool,
+    lookup_by_index: bool,
+) -> None:
+    """Batched where the set's keys allow it, else serial."""
+    verify = (_verify_commit_batch if _should_batch_verify(vals, commit)
+              else _verify_commit_single)
+    verify(chain_id, vals, commit, voting_power_needed,
+           ignore_sig, count_sig, count_all_signatures, lookup_by_index)
 
 
 def verify_commit(
     chain_id: str, vals: ValidatorSet, block_id: BlockID, height: int, commit: Commit
 ) -> None:
     """+2/3 signed; checks ALL signatures (types/validation.go:26-57)."""
-    _verify_basic(vals, commit, height, block_id)
-    needed = vals.total_voting_power() * 2 // 3
-
-    def ignore(c: CommitSig) -> bool:
-        return c.block_id_flag == BlockIDFlag.ABSENT
-
-    def count(c: CommitSig) -> bool:
-        return c.block_id_flag == BlockIDFlag.COMMIT
-
-    if _should_batch_verify(vals, commit):
-        _verify_commit_batch(chain_id, vals, commit, needed, ignore, count, True, True)
-    else:
-        _verify_commit_single(chain_id, vals, commit, needed, ignore, count, True, True)
+    with _root_span("commit.verify", commit, "full"):
+        _verify_basic(vals, commit, height, block_id)
+        needed = vals.total_voting_power() * 2 // 3
+        _verify_commit_rows(
+            chain_id, vals, commit, needed,
+            ignore_sig=lambda c: c.block_id_flag == BlockIDFlag.ABSENT,
+            count_sig=lambda c: c.block_id_flag == BlockIDFlag.COMMIT,
+            count_all_signatures=True,
+            lookup_by_index=True,
+        )
 
 
 def verify_commit_light(
     chain_id: str, vals: ValidatorSet, block_id: BlockID, height: int, commit: Commit
 ) -> None:
     """+2/3 signed; stops early (types/validation.go:60-92)."""
-    _verify_basic(vals, commit, height, block_id)
-    needed = vals.total_voting_power() * 2 // 3
+    with _root_span("commit.verify", commit, "light"):
+        _verify_basic(vals, commit, height, block_id)
+        needed = vals.total_voting_power() * 2 // 3
+        _verify_commit_rows(
+            chain_id, vals, commit, needed,
+            ignore_sig=lambda c: c.block_id_flag != BlockIDFlag.COMMIT,
+            count_sig=lambda c: True,
+            count_all_signatures=False,
+            lookup_by_index=True,
+        )
 
-    def ignore(c: CommitSig) -> bool:
-        return c.block_id_flag != BlockIDFlag.COMMIT
 
-    def count(c: CommitSig) -> bool:
-        return True
-
-    if _should_batch_verify(vals, commit):
-        _verify_commit_batch(chain_id, vals, commit, needed, ignore, count, False, True)
-    else:
-        _verify_commit_single(chain_id, vals, commit, needed, ignore, count, False, True)
+def _trusting_needed(vals: ValidatorSet, commit: Commit,
+                     trust_level: Fraction) -> int:
+    """The argument checks and the power threshold of the trusting path
+    (types/validation.go:95-110)."""
+    if vals is None:
+        raise ValueError("nil validator set")
+    if trust_level.denominator == 0:
+        raise ValueError("trustLevel has zero Denominator")
+    if commit is None:
+        raise ValueError("nil commit")
+    return vals.total_voting_power() * trust_level.numerator // trust_level.denominator
 
 
 def verify_commit_light_trusting(
@@ -319,24 +360,15 @@ def verify_commit_light_trusting(
 ) -> None:
     """trustLevel of the (possibly different) valset signed
     (types/validation.go:95-131)."""
-    if vals is None:
-        raise ValueError("nil validator set")
-    if trust_level.denominator == 0:
-        raise ValueError("trustLevel has zero Denominator")
-    if commit is None:
-        raise ValueError("nil commit")
-    needed = vals.total_voting_power() * trust_level.numerator // trust_level.denominator
-
-    def ignore(c: CommitSig) -> bool:
-        return c.block_id_flag != BlockIDFlag.COMMIT
-
-    def count(c: CommitSig) -> bool:
-        return True
-
-    if _should_batch_verify(vals, commit):
-        _verify_commit_batch(chain_id, vals, commit, needed, ignore, count, False, False)
-    else:
-        _verify_commit_single(chain_id, vals, commit, needed, ignore, count, False, False)
+    with _root_span("commit.verify", commit, "trusting"):
+        needed = _trusting_needed(vals, commit, trust_level)
+        _verify_commit_rows(
+            chain_id, vals, commit, needed,
+            ignore_sig=lambda c: c.block_id_flag != BlockIDFlag.COMMIT,
+            count_sig=lambda c: True,
+            count_all_signatures=False,
+            lookup_by_index=False,
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -382,6 +414,10 @@ class StagedCommitVerification:
         again after a window prefetch)."""
         if self._passed:
             return
+        with _root_span("commit.resolve", self.commit, "finish"):
+            self._finish(mask)
+
+    def _finish(self, mask) -> None:
         if mask is None:
             mask = self._mask
         if mask is None and self._bls_rows is not None:
@@ -446,16 +482,17 @@ def stage_verify_commit(
     COMMIT flags tallied, types/validation.go:26-57) staged asynchronously.
     Structural checks + the voting-power threshold run here, synchronously;
     signature validity is deferred to .finish()."""
-    _verify_basic(vals, commit, height, block_id)
-    needed = vals.total_voting_power() * 2 // 3
-    rows = _commit_rows(
-        chain_id, vals, commit, needed,
-        ignore_sig=lambda c: c.block_id_flag == BlockIDFlag.ABSENT,
-        count_sig=lambda c: c.block_id_flag == BlockIDFlag.COMMIT,
-        count_all_signatures=True,
-        lookup_by_index=True,
-    )
-    return _stage_rows(commit, rows)
+    with _root_span("commit.stage_verify", commit, "full"):
+        _verify_basic(vals, commit, height, block_id)
+        needed = vals.total_voting_power() * 2 // 3
+        rows = _commit_rows(
+            chain_id, vals, commit, needed,
+            ignore_sig=lambda c: c.block_id_flag == BlockIDFlag.ABSENT,
+            count_sig=lambda c: c.block_id_flag == BlockIDFlag.COMMIT,
+            count_all_signatures=True,
+            lookup_by_index=True,
+        )
+        return _stage_rows(commit, rows)
 
 
 def stage_verify_commit_light(
@@ -464,16 +501,17 @@ def stage_verify_commit_light(
     """verify_commit_light staged: the light client's +2/3-of-new-set check
     (types/validation.go:60-92), deferred so a bisection hop's two checks
     resolve with ONE device fetch."""
-    _verify_basic(vals, commit, height, block_id)
-    needed = vals.total_voting_power() * 2 // 3
-    rows = _commit_rows(
-        chain_id, vals, commit, needed,
-        ignore_sig=lambda c: c.block_id_flag != BlockIDFlag.COMMIT,
-        count_sig=lambda c: True,
-        count_all_signatures=False,
-        lookup_by_index=True,
-    )
-    return _stage_rows(commit, rows)
+    with _root_span("commit.stage_verify", commit, "light"):
+        _verify_basic(vals, commit, height, block_id)
+        needed = vals.total_voting_power() * 2 // 3
+        rows = _commit_rows(
+            chain_id, vals, commit, needed,
+            ignore_sig=lambda c: c.block_id_flag != BlockIDFlag.COMMIT,
+            count_sig=lambda c: True,
+            count_all_signatures=False,
+            lookup_by_index=True,
+        )
+        return _stage_rows(commit, rows)
 
 
 def stage_verify_commit_light_trusting(
@@ -482,21 +520,16 @@ def stage_verify_commit_light_trusting(
     """verify_commit_light_trusting staged (types/validation.go:95-131).
     The voting-power threshold (raising ErrNotEnoughVotingPowerSigned)
     runs here synchronously; signature validity at finish()."""
-    if vals is None:
-        raise ValueError("nil validator set")
-    if trust_level.denominator == 0:
-        raise ValueError("trustLevel has zero Denominator")
-    if commit is None:
-        raise ValueError("nil commit")
-    needed = vals.total_voting_power() * trust_level.numerator // trust_level.denominator
-    rows = _commit_rows(
-        chain_id, vals, commit, needed,
-        ignore_sig=lambda c: c.block_id_flag != BlockIDFlag.COMMIT,
-        count_sig=lambda c: True,
-        count_all_signatures=False,
-        lookup_by_index=False,
-    )
-    return _stage_rows(commit, rows)
+    with _root_span("commit.stage_verify", commit, "trusting"):
+        needed = _trusting_needed(vals, commit, trust_level)
+        rows = _commit_rows(
+            chain_id, vals, commit, needed,
+            ignore_sig=lambda c: c.block_id_flag != BlockIDFlag.COMMIT,
+            count_sig=lambda c: True,
+            count_all_signatures=False,
+            lookup_by_index=False,
+        )
+        return _stage_rows(commit, rows)
 
 
 def prefetch_staged(staged: list[StagedCommitVerification],
@@ -519,9 +552,16 @@ def prefetch_staged(staged: list[StagedCommitVerification],
     queued mempool-admission work rides the same batch as filler."""
     from cometbft_tpu import sched
 
-    if sched.enabled():
-        _prefetch_via_scheduler(staged, klass or sched.SYNC)
-        return
+    with trace.span("commit.prefetch", cat="node", commits=len(staged)):
+        if sched.enabled():
+            _prefetch_via_scheduler(staged, klass or sched.SYNC)
+        else:
+            _prefetch_direct(staged)
+
+
+def _prefetch_direct(staged: list[StagedCommitVerification]) -> None:
+    """prefetch_staged with the scheduler off: the window's ed25519 rows
+    as chunks of one device batch each, all resolved with one fetch."""
     from cometbft_tpu.ops import ed25519_kernel
 
     rows = [s for s in staged
@@ -587,24 +627,25 @@ def _prefetch_via_scheduler(staged: list[StagedCommitVerification],
            if s.device_thunk is not None and s._mask is None and not s._passed]
     todo: list[StagedCommitVerification] = []
     rowlists: list[list] = []
-    for s in staged:
-        if s._passed or s._mask is not None or s.device_thunk is not None:
-            continue
-        if getattr(s, "_bls_rows", None) is not None:
-            continue  # aggregate-verified at finish(), one check total
-        if s._ed_rows is not None:
-            from cometbft_tpu.crypto import ed25519 as _ed
+    with trace.span("commit.rows", cat="collect"):
+        for s in staged:
+            if s._passed or s._mask is not None or s.device_thunk is not None:
+                continue
+            if getattr(s, "_bls_rows", None) is not None:
+                continue  # aggregate-verified at finish(), one check total
+            if s._ed_rows is not None:
+                from cometbft_tpu.crypto import ed25519 as _ed
 
-            pubs_b, msgs, sigs = s._ed_rows
-            rows = [(_ed.PubKey(p), m, g)
-                    for p, m, g in zip(pubs_b, msgs, sigs)]
-        elif s._cpu_rows is not None:
-            pubs, msgs, sigs = s._cpu_rows
-            rows = list(zip(pubs, msgs, sigs))
-        else:
-            continue
-        todo.append(s)
-        rowlists.append(rows)
+                pubs_b, msgs, sigs = s._ed_rows
+                rows = [(_ed.PubKey(p), m, g)
+                        for p, m, g in zip(pubs_b, msgs, sigs)]
+            elif s._cpu_rows is not None:
+                pubs, msgs, sigs = s._cpu_rows
+                rows = list(zip(pubs, msgs, sigs))
+            else:
+                continue
+            todo.append(s)
+            rowlists.append(rows)
     if rowlists:
         masks = sched.get().verify_many(rowlists, klass)
         for s, mask in zip(todo, masks):
@@ -618,6 +659,7 @@ def _prefetch_via_scheduler(staged: list[StagedCommitVerification],
 def resolve_staged(staged: list[StagedCommitVerification]) -> None:
     """Finish a window of staged verifications with one device fetch.
     Raises on the first bad commit, in window order."""
-    prefetch_staged(staged)
-    for s in staged:
-        s.finish()
+    with trace.span("commit.resolve", cat="node", commits=len(staged)):
+        prefetch_staged(staged)
+        for s in staged:
+            s.finish()
