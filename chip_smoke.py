@@ -57,9 +57,9 @@ REPEATS = 5
 WARMUP_WATCHDOG_S = 1800.0
 MESH_CHIPS = 4
 # programs one new challenge-derive geometry builds at most: the derive
-# program, the slice that cuts R and s out of its flat block and the
-# integrity program over that block (their shapes follow its length)
-PROGRAMS_PER_DERIVE_GEOMETRY = 3
+# program alone (it slices R and s out of its flat block and checksums
+# it, so no later program's shape follows the block's length)
+PROGRAMS_PER_DERIVE_GEOMETRY = 1
 # env switches that exist to take the device OFF the path
 REFUSED_ENV = ("CBFT_NO_PALLAS", "CBFT_CHAOS")
 

@@ -4,14 +4,22 @@ actually runs on.
 Two links bound what this framework can do, and both are measured live,
 never assumed:
 
-  the device link     every h2d staging transfer and d2h result fetch
-                      crosses the host<->accelerator link.
-                      The kernels report every measured transfer span here
-                      (ops/ed25519_kernel.py, ops/sr25519_kernel.py), so
-                      `link()` converges on the REAL link within a few
-                      windows of traffic — crypto_health exposes it, the
-                      scheduler reads it, and the reduced-send work will
-                      be graded against it.
+  the device link     h2d staging transfers and d2h result fetches
+                      cross the host<->accelerator link. The kernels
+                      report here the transfers whose wall time is wire
+                      time and that the host awaits anyway: the payload
+                      pull after a batch's header has been read
+                      (ops/ed25519_kernel.py thunk and resolve_batches),
+                      key-table delta rows and coordinate-table uploads
+                      (ops/residency.py, PubKeyCache), the block uploads
+                      of sr25519, BLS and the mesh's shards. An ed25519
+                      batch's own upload rides its first program
+                      un-awaited and is NOT sampled: a caller does not
+                      pay a round trip for a gauge. So `link()`
+                      converges with table churn and unhappy batches,
+                      not with every batch — crypto_health exposes it
+                      and the scheduler's health view reads it; nothing
+                      routes or plans by it.
   peer links          MConnection ping RTTs and flowrate throughput feed
                       per-peer models (owned by the MConnection) plus the
                       process-wide `p2p()` aggregate that net_telemetry

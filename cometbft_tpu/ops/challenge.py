@@ -507,6 +507,7 @@ class PrefixTable:
             for _ in range(2):  # one retry on checksum mismatch
                 new, chk = fn(base, idx, vals)
                 _residency.record_send("delta", vals.nbytes + idx.nbytes)
+                _residency.count_trip(programs=1, waits=1)  # int(chk) blocks
                 if int(chk) == want:
                     self._tab = new
                     self._dirty.clear()
@@ -730,31 +731,44 @@ def _words_to_bytes(w):
 
 
 @functools.lru_cache(maxsize=32)
-def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int,
-              donate: bool):
-    """Compiled derive program for one batch geometry. Signature:
+def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int):
+    """Compiled derive program for one batch geometry: the first of the
+    two programs of a batch's trip (ed25519_kernel.verify_batch_async),
+    and the one its upload rides in on. Signature:
 
-      run(flat, aw, ptab[, fkw, fidx]) -> (flat, kw)
+      run(flat, idx, tx, ty, tz, tt, te, ptab[, fkw, fidx])
+          -> (rw, sw, kw, chk, ax, ay, az, at)
 
     flat   (block_words,) uint32 — the staged wire block (R words, s
-           words, descriptor stream). Returned unchanged as output 0 so
-           TPU donation aliases the h2d buffer straight through to the
-           verify dispatch (donate=False on CPU, where jit donation is
-           unsupported and warns).
-      aw   (8, bucket) uint32 — resident pubkey-encoding words for the
-           batch's lanes (the residency enc plane; device-resident, not
-           this batch's wire).
+           words, descriptor stream), handed over as the HOST array: the
+           call uploads it, un-awaited.
+     idx   (bucket,) uint16 — the lanes' rows in the resident key table
+           (residency.KeyTable.index), host array too.
+    tx..tt (20, cap) int32 resident A-coordinate planes, te (8, cap)
+           uint32 the resident pubkey-encoding words: the table's device
+           snapshot. The lanes' rows are gathered here: encodings into
+           the challenge preimage, coordinates out as ax..at.
     ptab   (TABLE_ROWS, PREFIX_CAP) uint8 — the Plan's table snapshot.
      fkw   (8, fb) uint32 host-computed challenge words for fallback
            lanes, fidx (fb,) int32 their lane indices (padded with a
            repeated real index — the scatter is idempotent). fb == 0
            omits both.
 
+    Everything whose shape follows the geometry ends HERE, so that the
+    verify program behind it (the ladder: a minute of tracing and
+    compiling a shape) is built per bucket and not per geometry: rw, sw
+    are the block's (8, bucket) R and s planes, and chk is the
+    position-weighted checksum of all this call uploaded (flat, fkw,
+    fidx), which the verify program holds against the host's value
+    (ed25519_kernel._integrity_parts_chk_expr).
+
     kw is zero for padding/fallback/ineligible lanes before the fkw
     scatter: padded lanes carry identity R / s=0 / k=0, which the verify
     grid accepts — preserving the all-ok happy-path header."""
     import jax
     import jax.numpy as jnp
+
+    from cometbft_tpu.ops import ed25519_kernel as EK
 
     count("derive_programs")  # lru miss: a program for a new geometry
     tot = 64 + plen + var + tlen
@@ -766,7 +780,10 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int,
                                  dtype=np.uint8)
     sw = stream_words(bucket, var)
 
-    def derive_challenge(flat, aw, ptab, *fk):
+    def derive_challenge(flat, idx, tx, ty, tz, tt, te, ptab, *fk):
+        rows = idx.astype(jnp.int32)
+        a_dev = tuple(jnp.take(c, rows, axis=1) for c in (tx, ty, tz, tt))
+        aw = jnp.take(te, rows, axis=1)
         stream = flat[16 * bucket:16 * bucket + sw]
         sb = jnp.stack([(stream >> (8 * k)) & 0xFF for k in range(4)],
                        axis=-1).reshape(-1).astype(jnp.uint8)
@@ -775,8 +792,8 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int,
         desc = dlo | (dhi << 8)
         use_dev = (desc >> 15).astype(jnp.uint32)
         pid = (desc & 0x7FFF).astype(jnp.int32)
-        parts = [_words_to_bytes(flat[:8 * bucket].reshape(8, bucket)),
-                 _words_to_bytes(aw)]
+        rw = flat[:8 * bucket].reshape(8, bucket)
+        parts = [_words_to_bytes(rw), _words_to_bytes(aw)]
         if plen or tlen:
             row = ptab[pid]  # (bucket, PREFIX_CAP) gather off the snapshot
         if plen:
@@ -799,8 +816,9 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int,
         if fb:
             fkw, fidx = fk
             kw = kw.at[:, fidx].set(fkw)
-        return flat, kw
+        s_w = flat[8 * bucket:16 * bucket].reshape(8, bucket)
+        with jax.named_scope("integrity"):
+            chk = EK._device_checksum_expr((flat,) + fk)
+        return (rw, s_w, kw, chk) + a_dev
 
-    if donate:
-        return jax.jit(derive_challenge, donate_argnums=(0,))
     return jax.jit(derive_challenge)

@@ -562,7 +562,11 @@ def health_snapshot() -> dict:
         # mesh / reduced-send PRs are judged against
         "attribution": _trace.attribution(),
         # live host<->device link model (libs/linkmodel.py): EWMA
-        # bandwidth/RTT fed by the kernels' measured h2d/d2h transfers
+        # bandwidth/RTT fed by the transfers that are awaited anyway:
+        # post-header payload pulls, key-table and coordinate uploads,
+        # sr25519 / BLS / mesh block uploads. An ed25519 batch's own
+        # upload is un-awaited and feeds nothing (staging.trip counts the
+        # waits that remain)
         "link": _linkmodel.link().snapshot(),
     }
     try:
@@ -581,6 +585,10 @@ def health_snapshot() -> dict:
             # wire accounting + steady-state bytes/sig + per-replica
             # validator-table counters
             "wire": _residency.stats(),
+            # the trip of a batch to the device and back: batches,
+            # compiled programs called, places the host blocked on the
+            # device (a happy resident ed25519 batch: 2 programs, 1 wait)
+            "trip": _residency.trip_stats(),
             "pubkey_cache": _ek.cache_stats(),
             "staging_pool": _limbs.POOL.stats(),
             # the dispatch-side half of the double-buffer contract:

@@ -30,6 +30,7 @@ verify as valid and are sliced off.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import threading
 import time as _time
@@ -43,6 +44,7 @@ from cometbft_tpu.libs import linkmodel as _linkmodel
 from cometbft_tpu.libs import trace as _trace
 from cometbft_tpu.ops import curve
 from cometbft_tpu.ops import limbs as L
+from cometbft_tpu.ops import residency as _residency
 from cometbft_tpu.ops import unpack as U
 
 MIN_BUCKET = 8
@@ -153,23 +155,6 @@ def _pallas_available() -> bool:
     return _use_pallas
 
 
-_donate_staging: bool | None = None
-
-
-def _donate_ok() -> bool:
-    """Donate the staged wire block through the challenge-derive program
-    only on TPU: the identity pass-through output aliases the h2d buffer
-    straight into the verify dispatch. CPU jit donation is unsupported
-    (XLA warns and copies on every batch)."""
-    global _donate_staging
-    if _donate_staging is None:
-        try:
-            _donate_staging = jax.devices()[0].platform == "tpu"
-        except Exception:  # noqa: BLE001
-            _donate_staging = False
-    return _donate_staging
-
-
 # Serializes jit dispatch (and therefore tracing) across ALL curve kernels
 # and threads — see ops/dispatch.py for why the Pallas constant swap makes
 # this mandatory.
@@ -248,15 +233,31 @@ OK_MAGIC = np.uint32(0x600DFA57)
 _BAD_MAGIC = np.uint32(~0x600DFA57 & 0xFFFFFFFF)
 
 
-def _integrity_parts_expr(mask, allok, rw, sw, kw, expected):
+def _integrity_parts_chk_expr(mask, allok, chk, expected):
     """-> ((2,) uint32 reduced-fetch header, (2B+1,) bool full payload
-    [mask, ~mask (echo), staging-checksum ok])."""
+    [mask, ~mask (echo), staging-checksum ok]) from the device's checksum
+    of the staged words and the host's."""
     with jax.named_scope("integrity"):
-        chk = _device_checksum_expr((rw, sw, kw))
         ok = chk == expected.astype(jnp.uint32)
         payload = jnp.concatenate([mask, ~mask, ok[None]])
         tok = chk ^ jnp.where(allok & ok, OK_MAGIC, _BAD_MAGIC)
         return jnp.stack([tok, ~tok]), payload
+
+
+def _integrity_parts_arrs_expr(mask, allok, expected, *arrs):
+    """_integrity_parts_chk_expr with the checksum taken here, over an
+    arbitrary array set: three r/s/k planes, or the device-challenge
+    wire's flat block (+ the fallback-k scatter arrays). On the
+    device-challenge path the two halves run in the batch's two programs
+    (the checksum where the geometry's shapes end, challenge.derive_fn;
+    header and payload with the ladder, _verify_programs)."""
+    with jax.named_scope("integrity"):
+        chk = _device_checksum_expr(arrs)
+    return _integrity_parts_chk_expr(mask, allok, chk, expected)
+
+
+def _integrity_parts_expr(mask, allok, rw, sw, kw, expected):
+    return _integrity_parts_arrs_expr(mask, allok, expected, rw, sw, kw)
 
 
 # NOT donated: the header/payload outputs are tiny (2 words + 2B+1
@@ -267,22 +268,6 @@ def _integrity_parts_expr(mask, allok, rw, sw, kw, expected):
 # in-flight batch, freed at resolution) and the host-side StagingPool
 # reuse underneath it.
 _integrity_parts = jax.jit(_integrity_parts_expr)
-
-
-def _integrity_parts_arrs_expr(mask, allok, expected, *arrs):
-    """_integrity_parts_expr generalized over arbitrary checksummed array
-    sets: the device-challenge wire is a flat block (+ optional fallback-k
-    scatter arrays), not three fixed r/s/k planes, and the checksummed set
-    differs per degradation rung. Same header/payload contract."""
-    with jax.named_scope("integrity"):
-        chk = _device_checksum_expr(arrs)
-        ok = chk == expected.astype(jnp.uint32)
-        payload = jnp.concatenate([mask, ~mask, ok[None]])
-        tok = chk ^ jnp.where(allok & ok, OK_MAGIC, _BAD_MAGIC)
-        return jnp.stack([tok, ~tok]), payload
-
-
-_integrity_parts_arrs = jax.jit(_integrity_parts_arrs_expr)
 
 
 class _LateExpected:
@@ -457,15 +442,68 @@ def reset_shape_log() -> None:
     _dispatched_shapes.clear()
 
 
-def _dispatch_verify(a_dev, r_words, s_words, k_words):
-    """-> ((B,) mask, () all-ok scalar), both device-resident."""
+@functools.lru_cache(maxsize=None)
+def _verify_programs(hostk: bool):
+    """The verify program of a batch's trip as the PallasGate's
+    (pallas_fn, xla_fn) couple: the ladder and the integrity header and
+    payload in ONE program, -> ((2,) header, (2B+1,) payload), built per
+    bucket. The Pallas one keeps `verify_pallas` in its name (the
+    benchmark's roofline reader finds the module by it).
+
+    hostk=False, after a device derive (challenge.derive_fn), all
+    arguments its outputs but the host's checksum:
+      (ax, ay, az, at, rw, sw, kw, chk, expected).
+    hostk=True, the one program of a host-challenge batch, so the gather
+    from the key table and the checksum are in it too:
+      (idx, tx, ty, tz, tt, words, expected), words the (3, 8, B) r/s/k
+      block."""
     from cometbft_tpu.ops import pallas_verify as PV
 
-    _dispatched_shapes.add(int(r_words.shape[1]))
+    def build(ladder, rung: str):
+        def derived(ax, ay, az, at, rw, sw, kw, chk, expected):
+            mask, allok = ladder(ax, ay, az, at, rw, sw, kw)
+            return _integrity_parts_chk_expr(mask, allok, chk, expected)
+
+        def hostk_(idx, tx, ty, tz, tt, words, expected):
+            a = tuple(jnp.take(c, idx, axis=1) for c in (tx, ty, tz, tt))
+            mask, allok = ladder(*a, words[0], words[1], words[2])
+            return _integrity_parts_arrs_expr(mask, allok, expected, words)
+
+        fn = hostk_ if hostk else derived
+        fn.__name__ = f"verify_{rung}_{'hostk' if hostk else 'derived'}"
+        return jax.jit(fn)
+
+    # both ladders un-jitted: no program of the trip nests a jit
+    return (build(PV.verify_pallas_ok_traced, "pallas"),
+            build(verify_math_ok, "xla"))
+
+
+def _dispatch_verify(hostk: bool, args: tuple, lanes: int):
+    """-> ((2,) header, (2B+1,) payload), both device-resident. Host
+    arrays among args are uploaded by the call, un-awaited."""
+    pallas_fn, xla_fn = _verify_programs(hostk)
+    _dispatched_shapes.add(lanes)
     with _dispatch_lock:
-        return _pallas_gate.run(
-            PV.verify_pallas_ok, _verify_kernel_ok,
-            (*a_dev, r_words, s_words, k_words), r_words.shape[1])
+        parts = _pallas_gate.run(pallas_fn, xla_fn, args, lanes)
+    _residency.count_trip(programs=1)
+    return parts
+
+
+def _dispatch_hostk(idx, planes, words, expected, path: str, sigs: int):
+    """The ONE program of a host-challenge batch: the index vector and the
+    whole (3, 8, B) r/s/k block go up as its arguments, un-awaited;
+    gather, ladder, checksum and integrity run inside it. Nothing waits
+    for the upload: the program is ordered behind it on the device; a
+    leased block stays leased until the batch resolves, and the runtime
+    holds its own reference to a host argument while it reads it."""
+    b = words.shape[2]
+    with _trace.span("ed25519.dispatch", cat="compute", lanes=b,
+                     device=default_device_index()) as sp:
+        parts = _dispatch_verify(True, (idx, *planes, words, expected), b)
+        nbytes = idx.nbytes + words.nbytes
+        sp.add_bytes(tx=nbytes)
+    _residency.record_send(path, nbytes, sigs=sigs)
+    return parts
 
 
 def decompress_points(enc: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -622,13 +660,12 @@ class PubKeyCache:
             _trace.add_bytes(tx=nbytes)
             # full-key-path wire accounting: the coordinate-table upload
             # the reduced-send residency exists to amortize away
-            from cometbft_tpu.ops import residency as _residency
-
             _residency.record_send("full", nbytes)
             # upload-time integrity check: a corrupted coordinate table
             # would poison EVERY batch against this valset until eviction,
             # so the one extra round trip per cache miss is paid here
             got = int(np.asarray(_device_checksum(dev)))
+            _residency.count_trip(programs=1, waits=2)
             if got == expected:
                 break
             _count_integrity("transfer_checksum_mismatch")
@@ -656,41 +693,15 @@ def _gather_coords(dev_u, idx):
     return tuple(jnp.take(c, idx, axis=1) for c in dev_u)
 
 
-def _stage_gather(cache: "PubKeyCache", pubs: list[bytes], bucket: int,
-                  put_key: str = "", device=None, want_enc: bool = False
-                  ) -> tuple:
-    """(ok_a (N,), (ax, ay, az, at) device arrays (20, bucket), send
-    path, pubkey-staging wire bytes). With want_enc the tuple gains the
-    (8, bucket) resident pubkey-encoding words between a_dev and path —
-    served only by the indexed path (None otherwise), since only the
-    residency tables keep raw key bytes on device; a None enc is one of
-    the device-challenge degradation rungs (non-resident A).
-
-    Indexed path first (ops/residency.py): when the batch's keys fit the
-    device-resident validator table, the wire carries a 2-byte uint16
-    row index per lane (unseen keys delta-insert, counted separately) —
-    the reduced-send steady state. path="indexed".
-
-    Full-key path otherwise: a device-side gather from the UNIQUE pubkey
-    table. A batch that repeats a validator set W times (the coalesced
-    blocksync window) uploads ONE copy of the coordinates (digest-cached
-    across windows, since the unique set is stable even when window
-    composition changes) plus a 4-byte/lane index vector — not W copies
-    keyed on the exact concatenation. path="full".
-
-    `device` targets a specific chip (the mesh path stages each shard's
-    coordinate table on its own fault domain; put_key must then carry the
-    chip index so cache/table entries never alias across devices)."""
-    from cometbft_tpu.ops import residency as _residency
-
-    got = _residency.stage(cache, pubs, bucket, put_key=put_key,
-                           device=device, want_enc=want_enc)
-    if got is not None:
-        if want_enc:
-            ok_a, a_dev, enc_dev, staging_tx = got
-            return ok_a, a_dev, enc_dev, "indexed", staging_tx
-        ok_a, a_dev, staging_tx = got
-        return ok_a, a_dev, "indexed", staging_tx
+def _full_key_index(cache: "PubKeyCache", pubs: list[bytes], bucket: int,
+                    put_key: str = "", device=None) -> tuple:
+    """The full-key path's table and index: (ok_a (N,), idx (bucket,)
+    int32, (ax, ay, az, at) device planes of the batch's UNIQUE keys). A
+    batch that repeats a validator set W times (the coalesced blocksync
+    window) uploads ONE copy of the coordinates (digest-cached across
+    windows, since the unique set is stable even when window composition
+    changes) plus a 4-byte/lane index vector — not W copies keyed on the
+    exact concatenation."""
     uniq = list(dict.fromkeys(pubs))
     # an identity pad slot is needed only when padding lanes exist; when the
     # batch fills its bucket exactly (n == bucket == cap is legal) the +1
@@ -699,27 +710,61 @@ def _stage_gather(cache: "PubKeyCache", pubs: list[bytes], bucket: int,
     bu = bucket_size(len(uniq) + 1 if need_pad else len(uniq))
     put = None
     if device is not None:
-        import functools as _functools
-
-        put = _functools.partial(jax.device_put, device=device)
+        put = functools.partial(jax.device_put, device=device)
     ok_u, dev_u = cache.stage(uniq, bu, put=put, put_key=put_key)
     pos = {p: i for i, p in enumerate(uniq)}
     idx = np.full(bucket, len(uniq), dtype=np.int32)  # padding -> identity
     idx[: len(pubs)] = [pos[p] for p in pubs]
-    ok_a = np.asarray(ok_u)[idx[: len(pubs)]]
-    t0 = _time.perf_counter()
+    return np.asarray(ok_u)[idx[: len(pubs)]], idx, dev_u
+
+
+def _stage_index(cache: "PubKeyCache", pubs: list[bytes],
+                 bucket: int) -> tuple:
+    """Pubkey staging for a batch whose program gathers for itself:
+    (ok_a (N,), idx host index vector (bucket,), (tx, ty, tz, tt)
+    device coordinate planes to gather from, te the resident
+    pubkey-encoding words or None, send path). Nothing is uploaded and
+    no program runs here: idx rides the batch's first program as an
+    argument.
+
+    Indexed path first (ops/residency.py): when the batch's keys fit the
+    device-resident validator table, the wire carries a 2-byte uint16
+    row index per lane (unseen keys delta-insert, counted separately) —
+    the reduced-send steady state. path="indexed". Only the residency
+    tables keep raw key bytes on device; a None te is one of the
+    device-challenge degradation rungs (non-resident A).
+
+    Full-key path otherwise (_full_key_index). path="full"."""
+    got = _residency.index(cache, pubs, bucket)
+    if got is not None:
+        ok_a, idx, dev = got
+        return ok_a, idx, dev[:4], dev[4], "indexed"
+    ok_a, idx, dev_u = _full_key_index(cache, pubs, bucket)
+    return ok_a, idx, dev_u, None, "full"
+
+
+def _stage_gather(cache: "PubKeyCache", pubs: list[bytes], bucket: int,
+                  put_key: str = "", device=None) -> tuple:
+    """_stage_index with the gather as a program of its own, for the
+    callers whose verify program takes gathered coordinates (sr25519,
+    the mesh's shards): (ok_a (N,), (ax, ay, az, at) device arrays
+    (20, bucket), send path, pubkey-staging wire bytes). The index
+    vector goes up un-awaited; the gather is ordered behind it.
+
+    `device` targets a specific chip (the mesh path stages each shard's
+    coordinate table on its own fault domain; put_key must then carry the
+    chip index so cache/table entries never alias across devices)."""
+    got = _residency.stage(cache, pubs, bucket, put_key=put_key,
+                           device=device)
+    if got is not None:
+        ok_a, a_dev, staging_tx = got
+        return ok_a, a_dev, "indexed", staging_tx
+    ok_a, idx, dev_u = _full_key_index(cache, pubs, bucket, put_key, device)
     idx_dev = (jax.device_put(idx) if device is None
                else jax.device_put(idx, device))
-    # the 4 B/lane index vector is the steady-state small upload — the
-    # link model's h2d RTT probe (no pending compute to entangle with;
-    # blocked before t1 so async dispatch can't record enqueue time)
-    jax.block_until_ready(idx_dev)
-    _linkmodel.link().observe_transfer(
-        idx.nbytes, _time.perf_counter() - t0)
     _trace.add_bytes(tx=idx.nbytes)
     a_dev = _gather_coords(dev_u, idx_dev)
-    if want_enc:
-        return ok_a, a_dev, None, "full", idx.nbytes
+    _residency.count_trip(programs=1)
     return ok_a, a_dev, "full", idx.nbytes
 
 
@@ -1022,6 +1067,13 @@ def _ok_arr(ok_a) -> np.ndarray:
     return ok_a.resolve() if isinstance(ok_a, _LateOkA) else ok_a
 
 
+def _to_host(dev_arr) -> np.ndarray:
+    """THE device->host fetch of the verify trip (header, payload): the
+    host blocks here until the batch's programs have run."""
+    _residency.count_trip(waits=1)
+    return np.asarray(dev_arr)
+
+
 def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
                             n, pre_ok, ok_a, rows, info,
                             expected=0, lease=None):
@@ -1037,8 +1089,10 @@ def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
     is the host staging checksum the header is decoded against; `lease` is
     the StagingPool block backing the staged words, returned to the pool
     once the batch resolves (the _redo retry re-reads it, so release waits
-    for resolution, not dispatch). The DoubleBuffer in-flight slot is NOT
-    released here: the dispatch closure scopes it (acquire before h2d,
+    for resolution, not dispatch — and the batch's upload is un-awaited, so
+    until the header has been read a transfer may still be reading the
+    block). The DoubleBuffer in-flight slot is NOT released here: the
+    dispatch closure scopes it (acquire before the first program's call,
     release in a finally after the verify dispatch), so an abandoned thunk
     — a caller that takes device_parts() and never resolves, exactly like
     an unreleased pool block — can never leak a slot and wedge the gate."""
@@ -1081,9 +1135,8 @@ def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
             try:
                 chaos.fire(fetch_site)
                 t0 = _time.perf_counter()
-                out = _fetch_pool().submit(
-                    lambda: np.asarray(dev_arr)).result(
-                        timeout=_dispatch.watchdog_timeout())
+                out = _fetch_pool().submit(_to_host, dev_arr).result(
+                    timeout=_dispatch.watchdog_timeout())
                 if pure_transfer:
                     _linkmodel.link().observe_transfer(
                         out.nbytes, _time.perf_counter() - t0)
@@ -1103,7 +1156,7 @@ def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
         hang/failure is recorded so the breaker and crypto_health see it."""
         try:
             return _fetch_pool().submit(
-                lambda: np.asarray(sup.run(submit_fn)[1])).result(
+                lambda: _to_host(sup.run(submit_fn)[1])).result(
                     timeout=_dispatch.watchdog_timeout())
         except (_dispatch.DeviceOpFailed, _dispatch.DeviceUnavailable):
             raise  # sup.run already recorded it
@@ -1214,62 +1267,36 @@ def verify_batch_async(
 
         def _transfer_and_dispatch():
             from cometbft_tpu.libs import chaos
-            from cometbft_tpu.ops import residency as _residency
 
             chaos.fire("ed25519.dispatch")
             # pubkey staging rides the transfer pool too (reduced-send
-            # pipeline): the caller thread never blocks on the index/table
-            # round trip, so host staging of batch N+1 overlaps batch N's
-            # transfers instead of serializing behind the link RTT. A
-            # staging failure here feeds the supervisor/breaker exactly
-            # like a dispatch failure (the batch lands on the host oracle).
+            # pipeline): the caller thread never waits on the residency
+            # lookup (or a delta upload), so host staging of batch N+1
+            # overlaps it. A staging failure here feeds the
+            # supervisor/breaker exactly like a dispatch failure (the
+            # batch lands on the host oracle).
             with _trace.span("ed25519.stage_pubkeys", cat="transfer",
                              lanes=b):
-                ok_a, a_dev, path, staging_tx = _stage_gather(
+                ok_a, idx, planes, _enc, path = _stage_index(
                     cache, safe_pubs, b)
             ok_cell.value = ok_a
-            # in-flight slot, scoped to h2d THROUGH the verify dispatch
-            # (a _redo retry or an abandoned thunk can never leak it):
-            # batch N's h2d overlaps batch N-1's compute, batch N+1
-            # queues until a slot frees
+            # in-flight slot, scoped to the verify dispatch (a _redo
+            # retry or an abandoned thunk can never leak it): batch N's
+            # upload overlaps batch N-1's compute, batch N+1 queues
+            # until a slot frees
             with _trace.span("ed25519.slot", cat="queue", lanes=b):
                 rel = _dispatch.doublebuffer(
                     f"dev{default_device_index()}").acquire()
             try:
-                with _trace.span("ed25519.h2d", cat="transfer",
-                                 lanes=b) as sp:
-                    t0 = _time.perf_counter()
-                    # ONE transfer for the whole (3, 8, B) staged block —
-                    # the r/s/k planes were three separate puts (three
-                    # link round trips) before the reduced-send
-                    # protocol; the planes are sliced apart on device
-                    # where the copy is HBM-cheap. Blocking before t1
-                    # keeps the link-model sample honest (async dispatch
-                    # would record enqueue time, not wire time); the
-                    # verify dispatch below needs the words resident
-                    # anyway, and this thread is the transfer pool —
-                    # blocking it is the design.
-                    dev_block = jnp.asarray(block)
-                    jax.block_until_ready(dev_block)
-                    nbytes = block.nbytes
-                    _linkmodel.link().observe_transfer(
-                        nbytes, _time.perf_counter() - t0)
-                    sp.add_bytes(tx=nbytes)
-                _residency.record_send(path, staging_tx + nbytes, sigs=n)
-                rw, sw, kw = dev_block[0], dev_block[1], dev_block[2]
-                with _trace.span("ed25519.dispatch", cat="compute", lanes=b,
-                                 device=default_device_index()):
-                    mask, allok = _dispatch_verify(a_dev, rw, sw, kw)
-                    parts = _integrity_parts(
-                        mask, allok, rw, sw, kw, expected)
+                parts = _dispatch_hostk(idx, planes, block, expected, path, n)
             finally:
                 rel()
             _count_device_batch("ed25519", b)
+            _residency.count_trip(batches=1)
             return parts
 
-        # The host->device copy blocks the calling thread for the wire
-        # time, so it runs on a small pool: the caller can stage batch i+1
-        # while batch i's bytes are in flight.
+        # Residency lookup and dispatch run on a small pool: the caller
+        # can stage batch i+1 while batch i is on its way.
         return supervised_device_thunk(
             "ed25519", sup, _transfer_and_dispatch, "ed25519.fetch",
             n, pre_ok, ok_cell, rows, info, expected=expected, lease=block)
@@ -1299,109 +1326,93 @@ def verify_batch_async(
             fidx[:fb_lanes.size] = fb_lanes
             fkw = np.tile(k_fb[-1:].T, (1, fb)).astype(np.uint32)
             fkw[:, :fb_lanes.size] = k_fb.T
-    expected_cell = _LateExpected(
-        _host_checksum(block, fkw, fidx) if fb else _host_checksum(block))
+    fk = (fkw, fidx) if fb else ()
+    expected_dc = _host_checksum(block, *fk)
+    expected_cell = _LateExpected(expected_dc)
 
     def _transfer_and_dispatch_dc():
         from cometbft_tpu.libs import chaos
 
         chaos.fire("ed25519.dispatch")
         with _trace.span("ed25519.stage_pubkeys", cat="transfer", lanes=b):
-            ok_a, a_dev, enc_dev, path, staging_tx = _stage_gather(
-                cache, safe_pubs, b, want_enc=True)
+            ok_a, idx, planes, enc, path = _stage_index(cache, safe_pubs, b)
         ok_cell.value = ok_a
         with _trace.span("ed25519.slot", cat="queue", lanes=b):
             rel = _dispatch.doublebuffer(
                 f"dev{default_device_index()}").acquire()
         try:
-            return _challenge_rungs_and_dispatch(a_dev, enc_dev, path,
-                                                 staging_tx)
+            return _challenge_rungs_and_dispatch(idx, planes, enc, path)
         finally:
             rel()
 
-    def _challenge_rungs_and_dispatch(a_dev, enc_dev, path, staging_tx):
+    def _challenge_rungs_and_dispatch(idx, planes, enc, path):
         from cometbft_tpu.libs import chaos
         from cometbft_tpu.ops import challenge as _challenge
-        from cometbft_tpu.ops import residency as _residency
 
-        with _trace.span("ed25519.h2d", cat="transfer", lanes=b) as sp:
-            t0 = _time.perf_counter()
-            dev_block = jnp.asarray(block)
-            fkw_dev = fidx_dev = None
-            if fb:
-                fkw_dev = jnp.asarray(fkw)
-                fidx_dev = jnp.asarray(fidx)
-                jax.block_until_ready((dev_block, fkw_dev, fidx_dev))
-                nbytes = block.nbytes + fkw.nbytes + fidx.nbytes
-            else:
-                jax.block_until_ready(dev_block)
-                nbytes = block.nbytes
-            _linkmodel.link().observe_transfer(
-                nbytes, _time.perf_counter() - t0)
-            sp.add_bytes(tx=nbytes)
-        _residency.record_send(path, staging_tx + nbytes, sigs=n)
-        kw = None
-        if enc_dev is not None:
+        derived = None
+        if enc is not None:
             sup_ch = _dispatch.supervisor(_challenge.SITE)
 
             def _derive():
                 chaos.fire(_challenge.SITE)
                 run = _challenge.derive_fn(
-                    b, plan.var, plan.plen, plan.tlen, fb, _donate_ok())
-                args = (dev_block, enc_dev, plan.dev_tab)
-                if fb:
-                    args = args + (fkw_dev, fidx_dev)
+                    b, plan.var, plan.plen, plan.tlen, fb)
+                # the batch's ONE upload: block, index vector and the
+                # fallback-k arrays are this call's host arguments,
+                # un-awaited (the block stays leased until the batch
+                # resolves)
                 with _trace.span("ed25519.challenge", cat="challenge",
-                                 lanes=b, device=default_device_index()):
+                                 lanes=b,
+                                 device=default_device_index()) as sp:
                     with _dispatch_lock:
-                        return run(*args)
+                        out = run(block, idx, *planes, enc, plan.dev_tab,
+                                  *fk)
+                    nbytes = (block.nbytes + idx.nbytes
+                              + sum(a.nbytes for a in fk))
+                    sp.add_bytes(tx=nbytes)
+                _residency.count_trip(programs=1)
+                _residency.record_send(path, nbytes, sigs=n)
+                return out
 
             try:
-                dev_out, kw = sup_ch.run(_derive)
+                derived = sup_ch.run(_derive)
                 if chaos.should_corrupt(_challenge.SITE):
                     # perturbed device k: the failing lane must be caught
                     # by the recheck plane, never reported as invalid
-                    kw = kw.at[0, 0].add(np.uint32(1))
+                    rw, sw, kw, *rest = derived
+                    derived = (rw, sw, kw.at[0, 0].add(np.uint32(1)), *rest)
+                    _residency.count_trip(programs=1)
             except (_dispatch.DeviceUnavailable, _dispatch.DeviceOpFailed):
-                kw = None
                 _challenge.count("derive_failed")
         else:
             _challenge.count("enc_not_resident")
-        if kw is None:
+        if derived is None:
             # whole-batch host-k rung: compute k here on the transfer
-            # pool, re-upload the block (a donated derive may have
-            # consumed the first transfer) and the k plane
+            # pool and send the block's R and s planes with it as the
+            # (3, 8, B) words of the host-challenge program (which
+            # gathers for itself); the descriptor stream stays home
             with _trace.span("ed25519.challenge", cat="challenge", lanes=b,
                              rung="host_fallback"):
                 mlens = np.fromiter(map(len, msgs), np.int64, n)
                 k_rows = _challenge_words(
                     sig_rows[:, :32], pub_rows, msgs, mlens, pre_ok)
-                kw_host = np.zeros((8, b), dtype=np.uint32)
-                kw_host[:, :n] = k_rows.T
-            t0 = _time.perf_counter()
-            dev_out = jnp.asarray(block)
-            kw = jnp.asarray(kw_host)
-            jax.block_until_ready((dev_out, kw))
-            fb_bytes = block.nbytes + kw_host.nbytes
-            _linkmodel.link().observe_transfer(
-                fb_bytes, _time.perf_counter() - t0)
-            _trace.add_bytes(tx=fb_bytes)
-            _residency.record_send(path, fb_bytes)
-            expected_cell.value = _host_checksum(block, kw_host)
-            chk_arrs = (dev_out, kw)
+                words = np.zeros((3, 8, b), dtype=np.uint32)
+                words[:2] = block[:16 * b].reshape(2, 8, b)
+                words[2, :, :n] = k_rows.T
+            expected_cell.value = _host_checksum(words)
             _challenge.count("batch_host_fallback")
-        elif fb:
-            chk_arrs = (dev_out, fkw_dev, fidx_dev)
+            parts = _dispatch_hostk(
+                idx, planes, words, np.uint32(expected_cell.value), path, n)
         else:
-            chk_arrs = (dev_out,)
-        rw = dev_out[:8 * b].reshape(8, b)
-        sw = dev_out[8 * b:16 * b].reshape(8, b)
-        with _trace.span("ed25519.dispatch", cat="compute", lanes=b,
-                         device=default_device_index()):
-            mask, allok = _dispatch_verify(a_dev, rw, sw, kw)
-            parts = _integrity_parts_arrs(
-                mask, allok, np.uint32(int(expected_cell)), *chk_arrs)
+            expected_cell.value = expected_dc  # a _redo after a fallback
+            rw, sw, kw, chk, *a_dev = derived
+            with _trace.span("ed25519.dispatch", cat="compute", lanes=b,
+                             device=default_device_index()):
+                parts = _dispatch_verify(
+                    False, (*a_dev, rw, sw, kw, chk,
+                            np.uint32(expected_dc)), b)
         _count_device_batch("ed25519", b)
+        _residency.count_trip(batches=1)
         return parts
 
     return supervised_device_thunk(
@@ -1443,7 +1454,10 @@ def resolve_batches(thunks) -> list[np.ndarray]:
         from cometbft_tpu.libs import chaos
 
         chaos.fire("mixed.resolve")
-        return np.asarray(jnp.concatenate(arrs))
+        if len(arrs) == 1:  # nothing to join: no program, just the fetch
+            return _to_host(arrs[0])
+        _residency.count_trip(programs=1)
+        return _to_host(jnp.concatenate(arrs))
 
     headers = None
     if live:

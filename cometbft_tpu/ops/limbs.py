@@ -106,8 +106,10 @@ class StagingPool:
 
     Double-buffer contract (reduced-send protocol): a block is ONE
     contiguous array, so the whole r/s/k payload crosses the link as a
-    single transfer (`jnp.asarray(block)` in the dispatch closures), and
-    a block stays leased for its batch's full flight — so the steady
+    single transfer (a host argument of the batch's first program in the
+    ed25519 dispatch closures, un-awaited; `jnp.asarray(block)` in
+    sr25519's), and a block stays leased for its batch's full flight —
+    the transfer may read it until the batch resolves — so the steady
     state holds two blocks per bucket (batch N in transfer/compute while
     batch N+1 stages), which is why warm() preallocates pairs and
     MAX_FREE_PER_SHAPE is sized above 2. The dispatch-side half of the

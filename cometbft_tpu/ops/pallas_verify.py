@@ -233,6 +233,18 @@ def verify_pallas_ok(ax, ay, az, at, r_words, s_words, k_words,
     )
 
 
+def verify_pallas_ok_traced(ax, ay, az, at, r_words, s_words, k_words):
+    """verify_pallas_ok for a caller that is itself being traced (the
+    ed25519 trip's verify program, ed25519_kernel._verify_programs): the
+    entry's body WITHOUT its jit. A jit nested in the caller's made the
+    kernel's lowering eleven times slower on the chip (52.0 s against
+    4.7 s at 256 lanes, PERF.md PR 27 k3), every process, cache or no
+    cache; compiling for a described chip in the sandbox does not show
+    it."""
+    return _verify_pallas_bench.__wrapped__(
+        ax, ay, az, at, r_words, s_words, k_words)
+
+
 def verify_pallas_sr(ax, ay, az, at, r_words, s_words, k_words,
                      interpret=False):
     """sr25519 (schnorrkel/ristretto) variant of verify_pallas: same

@@ -159,6 +159,30 @@ def reset_send_stats() -> None:
     with _send_lock:
         for p in _PATHS:
             _send_stats[p] = {"sends": 0, "bytes": 0, "sigs": 0}
+        for k in _trip:
+            _trip[k] = 0
+
+
+# The trip of a batch to the device and back, counted where it happens:
+# `device_programs` at every call of a compiled program on the batch's
+# way (table maintenance included), `blocking_waits` wherever the host
+# blocks on the device (awaited uploads, header and payload fetches),
+# `batches` once a batch has been dispatched. A happy ed25519 batch over
+# a resident validator set is 2 programs and 1 wait (the header fetch).
+_trip = {"batches": 0, "device_programs": 0, "blocking_waits": 0}
+
+
+def count_trip(batches: int = 0, programs: int = 0, waits: int = 0) -> None:
+    with _send_lock:
+        _trip["batches"] += batches
+        _trip["device_programs"] += programs
+        _trip["blocking_waits"] += waits
+
+
+def trip_stats() -> dict:
+    """The crypto_health staging `trip` subsection."""
+    with _send_lock:
+        return dict(_trip)
 
 
 # ------------------------------------------------------- device programs
@@ -214,18 +238,6 @@ def _scatter_fn():
                 te.at[:, i].set(enc))
 
     return scatter
-
-
-@functools.lru_cache(maxsize=1)
-def _gather_enc_fn():
-    jax = _jax()
-    jnp = _jnp()
-
-    @jax.jit
-    def gather(te, idx):
-        return jnp.take(te, idx.astype(jnp.int32), axis=1)
-
-    return gather
 
 
 class _NoRoom(Exception):
@@ -379,6 +391,7 @@ class KeyTable:
                 nbytes, _time.perf_counter() - t0)
             _trace.add_bytes(tx=nbytes)
             got = int(np.asarray(EK._device_checksum((vals_dev, enc_dev))))
+            count_trip(programs=1, waits=2)
             if got == expected:
                 break
             self.counters["checksum_retries"] += 1
@@ -388,6 +401,7 @@ class KeyTable:
                     "validator-table delta upload corrupted twice; "
                     "refusing to cache a poisoned row")
         self._dev = tuple(scatter(*dev, idx_dev, vals_dev, enc_dev))
+        count_trip(programs=1)
         for i, key in enumerate(missing):
             self._rows[key] = rows[i]
             self._ok[key] = bool(ok[i])
@@ -454,19 +468,16 @@ class KeyTable:
 
     # ------------------------------------------------------------ staging
 
-    def stage(self, pubs: list[bytes], bucket: int,
-              announced: dict | None = None, want_enc: bool = False):
-        """The indexed send: (ok_a (N,), (ax, ay, az, at) device arrays
-        (20, bucket), index-vector wire bytes) — plus, with want_enc,
-        the (8, bucket) gathered compressed-encoding words between the
-        coords and the byte count (the device-challenge path's A rows).
-        Unseen keys delta-insert first (counted separately); raises
-        _NoRoom when the batch cannot fit, which returns the caller to
-        the full-key path."""
-        from cometbft_tpu.libs import linkmodel as _linkmodel
-        from cometbft_tpu.libs import trace as _trace
-        from cometbft_tpu.ops import ed25519_kernel as EK
-
+    def index(self, pubs: list[bytes], bucket: int,
+              announced: dict | None = None):
+        """The indexed send, host half: (ok_a (N,), idx (bucket,) uint16
+        row indices with padding lanes on the identity row, the table's
+        device snapshot (tx, ty, tz, tt, te)). The caller hands idx and
+        the snapshot to the program that gathers (one upload with the
+        batch's own words, no round trip of its own). Unseen keys
+        delta-insert first (counted separately); raises _NoRoom when the
+        batch cannot fit, which returns the caller to the full-key
+        path."""
         with self._lock:
             if announced:
                 self._sync_sets(announced)
@@ -489,19 +500,22 @@ class KeyTable:
                                count=len(pubs))
             dev = self._build()
             self.counters["indexed_batches"] += 1
-        # the 2 B/lane index vector is the steady-state send — also the
-        # link model's h2d RTT probe (blocked before t1 so async
-        # dispatch can't record enqueue time; same contract as the full
-        # path's 4-byte index upload)
-        t0 = _time.perf_counter()
-        idx_dev = self._put(idx)
-        _jax().block_until_ready(idx_dev)
-        _linkmodel.link().observe_transfer(
-            idx.nbytes, _time.perf_counter() - t0)
+        return ok_a, idx, dev
+
+    def stage(self, pubs: list[bytes], bucket: int,
+              announced: dict | None = None):
+        """index() plus the gather as a program of its own: (ok_a (N,),
+        (ax, ay, az, at) device arrays (20, bucket), index-vector wire
+        bytes). The 2 B/lane index vector goes up un-awaited: the gather
+        is ordered behind it on the device, and no caller needs it
+        sooner."""
+        from cometbft_tpu.libs import trace as _trace
+        from cometbft_tpu.ops import ed25519_kernel as EK
+
+        ok_a, idx, dev = self.index(pubs, bucket, announced=announced)
+        coords = EK._gather_coords(dev[:4], self._put(idx))
+        count_trip(programs=1)
         _trace.add_bytes(tx=idx.nbytes)
-        coords = EK._gather_coords(dev[:4], idx_dev)
-        if want_enc:
-            return ok_a, coords, _gather_enc_fn()(dev[4], idx_dev), idx.nbytes
         return ok_a, coords, idx.nbytes
 
     def stats(self) -> dict:
@@ -596,11 +610,10 @@ def table_for(cache, put_key: str = "", device=None) -> KeyTable | None:
         return tbl
 
 
-def stage(cache, pubs: list[bytes], bucket: int, put_key: str = "",
-          device=None, want_enc: bool = False):
-    """Try the reduced-send indexed path for a batch. Returns
-    (ok_a, a_dev, index_bytes) — or (ok_a, a_dev, enc_dev, index_bytes)
-    with want_enc — or None when the full-key path must serve (disabled,
+def _indexed(method, cache, pubs, bucket, put_key, device):
+    """KeyTable.index or KeyTable.stage (`method`) for a batch on its
+    (scheme, placement-key) replica, or None when the full-key path must
+    serve (disabled,
     untagged cache, capacity overflow, or a failed delta upload)."""
     if not _cfg["enabled"]:
         return None
@@ -611,8 +624,7 @@ def stage(cache, pubs: list[bytes], bucket: int, put_key: str = "",
     with _reg_lock:
         announced = dict(_announced.get(scheme, {}))
     try:
-        return tbl.stage(pubs, bucket, announced=announced,
-                         want_enc=want_enc)
+        return method(tbl, pubs, bucket, announced=announced)
     except _NoRoom:
         return None
     except Exception:  # noqa: BLE001 - degraded, never a wrong verdict
@@ -625,6 +637,20 @@ def stage(cache, pubs: list[bytes], bucket: int, put_key: str = "",
         except Exception:  # noqa: BLE001
             pass
         return None
+
+
+def index(cache, pubs: list[bytes], bucket: int, put_key: str = "",
+          device=None):
+    """Try the reduced-send indexed path for a batch whose program
+    gathers for itself: (ok_a, idx, (tx, ty, tz, tt, te)) or None."""
+    return _indexed(KeyTable.index, cache, pubs, bucket, put_key, device)
+
+
+def stage(cache, pubs: list[bytes], bucket: int, put_key: str = "",
+          device=None):
+    """Try the reduced-send indexed path for a batch, gathered:
+    (ok_a, a_dev, index_bytes) or None."""
+    return _indexed(KeyTable.stage, cache, pubs, bucket, put_key, device)
 
 
 def invalidate_device(index: int) -> int:

@@ -382,6 +382,10 @@ def verify_batch_async(
                     (*a_dev, r_w, s_w, k_w), r_w.shape[1])
             parts = EK._integrity_parts(mask, allok, r_w, s_w, k_w, expected)
         EK._count_device_batch("sr25519", b)
+        # the trip as this scheme still makes it (ROADMAP D3): the awaited
+        # block upload; three plane slices, the ladder and the integrity
+        # program (the gather is counted in _stage_gather)
+        _residency.count_trip(batches=1, programs=5, waits=1)
         return parts
 
     return EK.supervised_device_thunk(

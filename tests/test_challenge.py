@@ -282,10 +282,23 @@ def test_derive_fn_matches_host_challenges():
     fidx[:len(fb_lanes)] = fb_lanes
     fkw = np.tile(fkw_rows[-1:].T, (1, fb)).astype(np.uint32)
     fkw[:, :len(fb_lanes)] = fkw_rows.T
-    run = challenge.derive_fn(bucket, plan.var, plan.plen, plan.tlen,
-                              fb, False)
-    _, kw = run(jnp.asarray(block), jnp.asarray(aw), plan.dev_tab,
-                jnp.asarray(fkw), jnp.asarray(fidx))
+    run = challenge.derive_fn(bucket, plan.var, plan.plen, plan.tlen, fb)
+    # a bucket-row key table read in REVERSE lane order: lane i's key is
+    # row bucket-1-i, so the gather is in the program under test
+    idx = np.arange(bucket - 1, -1, -1, dtype=np.uint16)
+    coords = tuple(jnp.asarray(rng.integers(
+        0, 1 << 13, size=(20, bucket), dtype=np.int32)) for _ in range(4))
+    rw_dev, sw_dev, kw, chk, *a_dev = run(
+        block, idx, *coords, jnp.asarray(aw[:, ::-1]), plan.dev_tab,
+        fkw, fidx)
+    planes = block[:16 * bucket].reshape(2, 8, bucket)
+    assert np.array_equal(np.asarray(rw_dev), planes[0])
+    assert np.array_equal(np.asarray(sw_dev), planes[1])
+    for got, table in zip(a_dev, coords):
+        assert np.array_equal(np.asarray(got), np.asarray(table)[:, idx])
+    from cometbft_tpu.ops import ed25519_kernel as EK
+
+    assert int(chk) == EK._host_checksum(block, fkw, fidx)
     kw = np.asarray(kw)  # (8, bucket)
     want = hashvec.sha512_mod_l_words(
         [sigs[i].tobytes() + pubs[i].tobytes() + bytes(msgs[i])

@@ -83,8 +83,9 @@ def test_pallas_verify_compiles_for_v5e(one_chip, no_persistent_cache,
 
 def test_challenge_derive_compiles_for_v5e_vote_shape(one_chip,
                                                       no_persistent_cache):
-    """The device SHA-512 + Barrett challenge program (donating its wire
-    block, the TPU-only branch) at the geometry a 150-validator commit
+    """The device SHA-512 + Barrett challenge program (with the gather
+    from the 16,384-row key table in front and the block's checksum
+    behind) at the geometry a 150-validator commit
     plans: bucket 256, the votes' shared prefix/tail, ms-grained
     timestamps with their off-length lanes as host fallbacks."""
     import chip_smoke
@@ -101,18 +102,47 @@ def test_challenge_derive_compiles_for_v5e_vote_shape(one_chip,
     b = EK.bucket_size(len(msgs))
     assert b == 256
     fb = EK.bucket_size(plan.n_fallback) if plan.n_fallback else 0
-    run = challenge.derive_fn(b, plan.var, plan.plen, plan.tlen, fb, True)
+    run = challenge.derive_fn(b, plan.var, plan.plen, plan.tlen, fb)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    rows = 16384  # residency's default table (crypto.wire_table_rows)
     args = [arg((challenge.block_words(b, plan.var),), jnp.uint32),
-            arg((8, b), jnp.uint32),
+            arg((b,), jnp.uint16),
+            *[arg((20, rows), jnp.int32)] * 4,
+            arg((8, rows), jnp.uint32),
             arg((challenge.TABLE_ROWS, challenge.PREFIX_CAP), jnp.uint8)]
     if fb:
         args += [arg((8, fb), jnp.uint32), arg((fb,), jnp.int32)]
     compiled = run.lower(*args).compile()
     assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("hostk", [False, True])
+def test_trip_verify_program_compiles_for_v5e_commit_shape(
+        one_chip, no_persistent_cache, hostk):
+    """The second program of an ed25519 batch's trip at a 150-validator
+    commit's 256 lanes: the Pallas ladder and the integrity header and
+    payload in one module (and, host-challenge rung, the gather from the
+    16,384-row key table and the checksum before them)."""
+    from cometbft_tpu.ops import ed25519_kernel as EK
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, rows = 256, 16384
+    words, scalar = arg((8, b), jnp.uint32), arg((), jnp.uint32)
+    if hostk:
+        args = [arg((b,), jnp.uint16), *[arg((20, rows), jnp.int32)] * 4,
+                arg((3, 8, b), jnp.uint32), scalar]
+    else:
+        args = [*[arg((20, b), jnp.int32)] * 4, words, words, words,
+                scalar, scalar]
+    pallas_fn, _xla_fn = EK._verify_programs(hostk)
+    with KERNEL_DISPATCH_LOCK:
+        compiled = pallas_fn.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_pallas_verify_interpret_matches_host_oracle():

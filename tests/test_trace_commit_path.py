@@ -284,11 +284,13 @@ class TestProgramNames:
         from cometbft_tpu.ops import challenge
 
         bucket, var, plen, tlen = 8, 5, 60, 14
-        run = challenge.derive_fn(bucket, var, plen, tlen, 0, False)
+        run = challenge.derive_fn(bucket, var, plen, tlen, 0)
         lowered = run.lower(
             jax.ShapeDtypeStruct((challenge.block_words(bucket, var),),
                                  jnp.uint32),
-            jax.ShapeDtypeStruct((8, bucket), jnp.uint32),
+            jax.ShapeDtypeStruct((bucket,), jnp.uint16),
+            *[jax.ShapeDtypeStruct((20, 64), jnp.int32)] * 4,
+            jax.ShapeDtypeStruct((8, 64), jnp.uint32),
             jax.ShapeDtypeStruct(
                 (challenge.TABLE_ROWS, challenge.PREFIX_CAP), jnp.uint8))
         text = lowered.as_text(debug_info=True)
@@ -300,7 +302,7 @@ class TestProgramNames:
         (lambda ch: ch._digest_fn(1), "sha512_digest"),
         (lambda ch: ch._reduce_fn(), "reduce_mod_l"),
         (lambda ch: ch._tab_scatter_fn(1), "prefix_table_scatter"),
-        (lambda ch: ch.derive_fn(8, 5, 60, 14, 0, False), "derive_challenge"),
+        (lambda ch: ch.derive_fn(8, 5, 60, 14, 0), "derive_challenge"),
     ])
     def test_no_program_of_the_challenge_module_is_called_f(
             self, builder, name):
@@ -320,17 +322,28 @@ class TestProgramNames:
         mask = jax.ShapeDtypeStruct((8,), jnp.bool_)
         scalar = jax.ShapeDtypeStruct((), jnp.bool_)
         expected = jax.ShapeDtypeStruct((), jnp.uint32)
-        text = EK._integrity_parts_arrs.lower(
-            mask, scalar, expected, words).as_text(debug_info=True)
-        assert "module @jit__integrity_parts_arrs_expr" in text
+        text = EK._integrity_parts.lower(
+            mask, scalar, words, words, words, expected).as_text(
+                debug_info=True)
+        assert "module @jit__integrity_parts_expr" in text
         assert "/integrity/" in text
 
     def test_the_rooflines_module_keeps_its_name(self):
         """verify_kernel_roofline.commit finds the kernel by the module
         pattern `verify_pallas` (benchmarks/metrics)."""
+        import re
+
+        from cometbft_tpu.ops import ed25519_kernel as EK
         from cometbft_tpu.ops import pallas_verify as PV
 
         assert PV._verify_pallas_bench.__name__ == "_verify_pallas_bench"
+        # the ed25519 trip's verify programs (ladder + integrity in one):
+        # the Pallas rung matches the reader's pattern, the XLA rung must
+        # not be counted as the kernel
+        for hostk in (False, True):
+            pallas_fn, xla_fn = EK._verify_programs(hostk)
+            assert re.search("verify_pallas", pallas_fn.__name__)
+            assert not re.search("verify_pallas", xla_fn.__name__)
 
     @pytest.mark.parametrize("scheme", ["ed25519", "sr25519"])
     def test_pallas_call_is_named_by_scheme(self, scheme, monkeypatch):

@@ -1,0 +1,396 @@
+"""The trip of one ed25519 batch to the device and back (ISSUE 27): one
+un-awaited upload, two compiled programs (derive, verify + integrity) and
+one blocking wait (the 8-byte header fetch); the host-challenge branch is
+one program and one wait; a failing lane adds the payload pull and nothing
+else. The spies sit on the program callables, on jax.block_until_ready, on
+the fetch and on JAX's eager dispatch (where the four plane slices of the
+old trip ran); `crypto_health`'s staging.trip has to agree with them.
+
+One batch shape throughout (150 rows, 256 lanes, a commit's geometry):
+every further shape is a further trace of the ladder.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cometbft_tpu.crypto import ed25519
+from cometbft_tpu.libs.prefixrows import PrefixedMsg, as_bytes
+from cometbft_tpu.ops import challenge, residency
+from cometbft_tpu.ops import dispatch as D
+from cometbft_tpu.ops import ed25519_kernel as K
+from cometbft_tpu.ops import limbs as L
+
+N = 150
+BUCKET = 256
+BAD_LANE = 97
+
+
+@pytest.fixture(autouse=True)
+def _fresh_planes():
+    residency.reset()
+    challenge.reset()
+    challenge.configure(enabled=True)
+    yield
+    residency.reset()
+    challenge.reset()
+    challenge.configure(enabled=True)
+
+
+@pytest.fixture(scope="module")
+def rows():
+    """150 vote-shaped rows (shared prefix, 8 varying bytes, common
+    trailer), and the same with one signature that does not verify."""
+    keys = [ed25519.gen_priv_key() for _ in range(N)]
+    prefix = b"trip-vote-prefix|" + b"h" * 71
+    pubs, msgs, sigs = [], [], []
+    for i, key in enumerate(keys):
+        msg = PrefixedMsg(prefix, b"%08d" % i + b"|trip-chain")
+        pubs.append(key.pub_key().bytes_())
+        msgs.append(msg)
+        sigs.append(key.sign(as_bytes(msg)))
+    bad = list(sigs)
+    bad[BAD_LANE] = bad[BAD_LANE][:32] + bad[BAD_LANE + 1][32:]
+    return pubs, msgs, sigs, bad
+
+
+class _Spies:
+    """What a trip calls, counted at the seams it goes through."""
+
+    def __init__(self, monkeypatch):
+        self.programs: list[str] = []
+        self.eager: list[str] = []
+        self.awaited = 0
+        self.fetches = 0
+        self.concatenates = 0
+
+        def counting(fn, name):
+            def call(*args, **kwargs):
+                self.programs.append(name)
+                return fn(*args, **kwargs)
+            return call
+
+        derive_fn = challenge.derive_fn
+        monkeypatch.setattr(challenge, "derive_fn", lambda *a: counting(
+            derive_fn(*a), "derive_challenge"))
+        verify_programs = K._verify_programs
+        monkeypatch.setattr(K, "_verify_programs", lambda hostk: tuple(
+            counting(fn, fn.__name__) for fn in verify_programs(hostk)))
+        for name in ("_gather_coords", "_integrity_parts",
+                     "_device_checksum"):
+            monkeypatch.setattr(K, name, counting(getattr(K, name), name))
+
+        block_until_ready = jax.block_until_ready
+
+        def awaited(x):
+            self.awaited += 1
+            return block_until_ready(x)
+
+        monkeypatch.setattr(jax, "block_until_ready", awaited)
+        to_host = K._to_host
+
+        def fetch(arr):
+            self.fetches += 1
+            return to_host(arr)
+
+        monkeypatch.setattr(K, "_to_host", fetch)
+        concatenate = jnp.concatenate
+
+        def concat(*args, **kwargs):
+            self.concatenates += 1
+            return concatenate(*args, **kwargs)
+
+        monkeypatch.setattr(jnp, "concatenate", concat)
+        # an op on a device array outside any jit (x[a:b], .reshape, .at)
+        # is a compiled program of its own, dispatched from here
+        from jax._src import dispatch as jax_dispatch
+
+        apply_primitive = jax_dispatch.apply_primitive
+
+        def eager(prim, *args, **params):
+            self.eager.append(str(prim))
+            return apply_primitive(prim, *args, **params)
+
+        monkeypatch.setattr(jax_dispatch, "apply_primitive", eager)
+
+
+def _resolve(how: str, pubs, msgs, sigs) -> np.ndarray:
+    thunk = K.verify_batch_async(pubs, msgs, sigs)
+    if how == "thunk":
+        return thunk()
+    return K.resolve_batches([thunk])[0]
+
+
+def _health_trip() -> dict:
+    return D.health_snapshot()["staging"]["trip"]
+
+
+@pytest.mark.parametrize("how", ["thunk", "resolve_batches"])
+def test_happy_batch_is_two_programs_and_one_wait(rows, how, monkeypatch):
+    pubs, msgs, sigs, _bad = rows
+    assert K.verify_batch(pubs, msgs, sigs)[0]  # tables resident, warm
+    residency.reset_send_stats()
+    challenge.reset_stats()
+    spies = _Spies(monkeypatch)
+
+    mask = _resolve(how, pubs, msgs, sigs)
+
+    assert mask.all() and mask.shape == (N,)
+    assert spies.programs == ["derive_challenge", "verify_xla_derived"]
+    assert spies.eager == []
+    assert spies.awaited == 0
+    assert spies.fetches == 1
+    assert spies.concatenates == 0
+    trip = {"batches": 1, "device_programs": 2, "blocking_waits": 1}
+    assert residency.trip_stats() == trip
+    assert _health_trip() == trip
+    assert challenge.stats().get("lanes_device") == N
+    # the same bytes as ever: the wire block and the 2 B/lane index
+    sends = residency.send_stats()["indexed"]
+    assert sends["sends"] == 1 and sends["sigs"] == N
+    assert sends["bytes"] == 4 * challenge.block_words(BUCKET, 8) + 2 * BUCKET
+
+
+@pytest.mark.parametrize("how", ["thunk", "resolve_batches"])
+def test_host_challenge_batch_is_one_program_and_one_wait(
+        rows, how, monkeypatch):
+    """The branch a flush under MIN_LANES or with the plane off takes."""
+    pubs, msgs, sigs, _bad = rows
+    challenge.configure(enabled=False)
+    assert K.verify_batch(pubs, msgs, sigs)[0]
+    residency.reset_send_stats()
+    spies = _Spies(monkeypatch)
+
+    mask = _resolve(how, pubs, msgs, sigs)
+
+    assert mask.all()
+    assert spies.programs == ["verify_xla_hostk"]
+    assert spies.eager == []
+    assert (spies.awaited, spies.fetches, spies.concatenates) == (0, 1, 0)
+    trip = {"batches": 1, "device_programs": 1, "blocking_waits": 1}
+    assert residency.trip_stats() == trip == _health_trip()
+    sends = residency.send_stats()["indexed"]
+    assert sends["bytes"] == 96 * BUCKET + 2 * BUCKET
+
+
+@pytest.mark.parametrize("how", ["thunk", "resolve_batches"])
+def test_failing_lane_same_programs_two_waits_right_lane(
+        rows, how, monkeypatch):
+    pubs, msgs, _sigs, bad = rows
+    assert not K.verify_batch(pubs, msgs, bad)[0]
+    residency.reset_send_stats()
+    spies = _Spies(monkeypatch)
+
+    mask = _resolve(how, pubs, msgs, bad)
+
+    assert np.flatnonzero(~mask).tolist() == [BAD_LANE]
+    assert spies.programs == ["derive_challenge", "verify_xla_derived"]
+    assert spies.eager == []
+    assert (spies.awaited, spies.fetches, spies.concatenates) == (0, 2, 0)
+    trip = {"batches": 1, "device_programs": 2, "blocking_waits": 2}
+    assert residency.trip_stats() == trip == _health_trip()
+
+
+def test_derive_failure_falls_to_one_host_k_program(rows, monkeypatch):
+    """The whole-batch host-k rung behind the derive: the one program of
+    the host-challenge branch, with R, s, the host's k and the index."""
+    from cometbft_tpu.libs import chaos
+
+    pubs, msgs, sigs, _bad = rows
+
+    def with_a_failing_derive() -> np.ndarray:
+        chaos.arm(challenge.SITE, "permanent", 1)
+        try:
+            return _resolve("thunk", pubs, msgs, sigs)
+        finally:
+            chaos.reset()
+            D.reset_supervision()  # the challenge breaker opened
+
+    assert with_a_failing_derive().all()  # tables resident, rung traced
+    residency.reset_send_stats()
+    challenge.reset_stats()
+    spies = _Spies(monkeypatch)
+
+    mask = with_a_failing_derive()
+
+    assert mask.all()
+    assert spies.programs == ["verify_xla_hostk"]
+    assert (spies.awaited, spies.fetches) == (0, 1)
+    assert challenge.stats().get("batch_host_fallback") == 1
+    sends = residency.send_stats()["indexed"]
+    assert sends["sends"] == 1 and sends["sigs"] == N
+    assert sends["bytes"] == 96 * BUCKET + 2 * BUCKET  # no stream
+
+
+@pytest.mark.parametrize("device_challenge", [True, False])
+def test_staged_block_stays_leased_until_the_batch_resolves(
+        rows, device_challenge, monkeypatch):
+    """The upload is not awaited, so the host block must not be back in
+    the pool (where the next batch would overwrite it) before the header
+    has been read: until then a transfer may still be reading it."""
+    pubs, msgs, sigs, _bad = rows
+    challenge.configure(enabled=device_challenge)
+    assert K.verify_batch(pubs, msgs, sigs)[0]
+    leased = []
+    for name in ("lease", "lease_flat"):
+        lease = getattr(L.POOL, name)
+        monkeypatch.setattr(L.POOL, name, lambda n, lease=lease: (
+            leased.append(lease(n)) or leased[-1]))
+
+    def pooled(block) -> bool:
+        with L.POOL._lock:
+            return any(b is block for b in L.POOL._free.get(block.shape, []))
+
+    thunk = K.verify_batch_async(pubs, msgs, sigs)
+    assert len(leased) == 1
+    block = leased[0]
+    staged = block.copy()
+    header_dev, _payload_dev = thunk.device_parts()[0]()  # dispatched
+    assert not pooled(block)
+    np.asarray(header_dev)  # both programs have run
+    assert not pooled(block)
+    assert np.array_equal(block, staged)
+    assert thunk().all()
+    assert pooled(block)
+
+
+# ------------------------------------------- integrity, traced in-program
+
+
+@pytest.fixture(scope="module")
+def staged(rows):
+    """A failing-lane batch staged by hand as the dispatch closure stages
+    it, and the s words that make the failing lane good again (its R, and
+    so its k, are the good signature's)."""
+    pubs, msgs, sigs, bad = rows
+    residency.reset()
+    challenge.reset()
+    pre_ok, safe_pubs, sig_rows, _pub_rows = K._structural_stage(pubs, bad)
+    plan = challenge.plan_batch(msgs, pre_ok)
+    assert plan is not None and plan.n_fallback == 0
+    flat = np.empty(challenge.block_words(BUCKET, plan.var), np.uint32)
+    K._pack_device_block(sig_rows, BUCKET, plan, flat)
+    ok_a, idx, planes, enc, _path = K._stage_index(
+        K._default_cache, safe_pubs, BUCKET)
+    assert ok_a.all()
+    run = challenge.derive_fn(BUCKET, plan.var, plan.plen, plan.tlen, 0)
+    table = (idx, *planes, enc, plan.dev_tab)
+    residency.reset()
+    challenge.reset()
+    good_s = np.frombuffer(sigs[BAD_LANE][32:], np.uint32)
+    return flat, run, table, good_s, plan.var
+
+
+@pytest.mark.parametrize("case", ["good", "flipped_word", "failing_lane"])
+@pytest.mark.parametrize("hostk", [False, True])
+def test_in_program_integrity_equals_the_expression(staged, case, hostk):
+    """Header and payload as the batch's programs make them against
+    _integrity_parts_arrs_expr on the lanes' verdicts and the words that
+    arrived: a block that arrived whole, one that did not (a bit of a
+    padding lane, which moves no verdict), one with a failing lane."""
+    flat, run, table, good_s, var = staged
+    flat = flat.copy()
+    pad_lane = BUCKET - 3
+    verdicts = np.ones(BUCKET, dtype=bool)
+    if case == "failing_lane":
+        verdicts[BAD_LANE] = False
+    else:
+        flat[8 * BUCKET:16 * BUCKET].reshape(8, BUCKET)[:, BAD_LANE] = good_s
+    if hostk:
+        words = np.empty((3, 8, BUCKET), np.uint32)
+        words[:2] = flat[:16 * BUCKET].reshape(2, 8, BUCKET)
+        words[2] = np.asarray(run(flat, *table)[2])
+        expected = np.uint32(K._host_checksum(words))
+        if case == "flipped_word":  # k of a lane whose A is the identity
+            words[2, 0, pad_lane] ^= np.uint32(1 << 9)
+        header, payload = K._verify_programs(True)[1](
+            *table[:5], words, expected)
+        arrived = words
+    else:
+        expected = np.uint32(K._host_checksum(flat))
+        if case == "flipped_word":  # suffix bytes of a lane not derived
+            flat[16 * BUCKET + (2 * BUCKET + pad_lane * var) // 4] ^= (
+                np.uint32(1 << 9))
+        rw, sw, kw, chk, *a_dev = run(flat, *table)
+        header, payload = K._verify_programs(False)[1](
+            *a_dev, rw, sw, kw, chk, expected)
+        arrived = flat
+    want_header, want_payload = K._integrity_parts_arrs_expr(
+        jnp.asarray(verdicts), jnp.asarray(verdicts.all()),
+        jnp.asarray(expected), jnp.asarray(arrived))
+    assert np.array_equal(np.asarray(header), np.asarray(want_header))
+    assert np.array_equal(np.asarray(payload), np.asarray(want_payload))
+    verdict = {"good": "happy", "flipped_word": "chk_mismatch",
+               "failing_lane": "full"}[case]
+    assert K.decode_header(np.asarray(header), expected) == verdict
+    assert bool(np.asarray(payload)[2 * BUCKET]) == (case != "flipped_word")
+
+
+def test_resolve_batches_joins_only_when_there_is_something_to_join(
+        rows, monkeypatch):
+    """Two batches: one concatenate for the two headers (a program), one
+    fetch; the one unhappy payload is pulled as it is."""
+    pubs, msgs, sigs, bad = rows
+    assert K.verify_batch(pubs, msgs, sigs)[0]
+    assert not K.verify_batch(pubs, msgs, bad)[0]
+    residency.reset_send_stats()
+    spies = _Spies(monkeypatch)
+
+    good_mask, bad_mask = K.resolve_batches([
+        K.verify_batch_async(pubs, msgs, sigs),
+        K.verify_batch_async(pubs, msgs, bad)])
+
+    assert good_mask.all()
+    assert np.flatnonzero(~bad_mask).tolist() == [BAD_LANE]
+    assert spies.concatenates == 1 and spies.fetches == 2
+    assert spies.eager == []
+    assert residency.trip_stats() == {
+        "batches": 2, "device_programs": 5, "blocking_waits": 2}
+
+
+# ------------------------------------- the benchmark's reading of the trip
+
+
+def test_benchmark_reads_programs_per_batch_and_nothing_on_a_parent(rows):
+    """benchmarks/metrics/device_programs_per_batch.json over the
+    flattened crypto_health snapshot, differenced as a run differences
+    it: 2.0 for happy batches; None (and no error) on a program whose
+    snapshot has no staging.trip, as the parent's has not."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from benchmarks import program, readers
+
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        entry = json.load(fh)["per_layer"][-1]
+    assert entry == {
+        "name": "device_programs_per_batch.commit",
+        "unit": "programs/batch", "better": "lower",
+        "source": "program_counter", "layer": "residency and wire",
+        "moves": "commit_verify_ms", "workloads": ["hub-150.commit"]}
+    metrics_dir = os.path.join(root, "benchmarks", "metrics")
+
+    def flat() -> dict:
+        out: dict = {}
+        program._flatten(D.health_snapshot(), "", out)
+        return out
+
+    pubs, msgs, sigs, _bad = rows
+    assert K.verify_batch(pubs, msgs, sigs)[0]
+    before = flat()
+    for _ in range(3):
+        assert K.verify_batch(pubs, msgs, sigs)[0]
+    counters = program.Counters.diff(before, flat())
+    reading = readers.read_metric(metrics_dir, entry["name"],
+                                  {"counters": counters})
+    assert reading == {"value": 2.0, "unit": "programs/batch"}
+    parents = {k: v for k, v in counters.items()
+               if not k.startswith("staging.trip.")}
+    assert readers.read_metric(metrics_dir, entry["name"],
+                               {"counters": parents}) is None
