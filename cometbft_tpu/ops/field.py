@@ -14,9 +14,10 @@ Invariant ("carried"): per-limb SIGNED intervals — the least fixpoint of
 safe by tests/test_field_intervals.py (see the block comment above
 CARRIED_MAX; the naive "every limb small enough for any column sum" bound
 does NOT hold). add/sub/mul/sq take and return carried values. Values are
-redundant mod p (anywhere in [0, ~2^260)); canonicalize() produces the
-unique representative in [0, p) for comparisons, parity checks, and
-re-compression.
+redundant mod p (roughly [0, 2^260), and a wrap round can leave limbs that
+spell a small NEGATIVE integer); canonicalize() produces the unique
+representative in [0, p), exactly, for every such input: comparisons,
+parity checks and re-compression rest on it.
 
 Reference seam: this replaces the 64-bit limb arithmetic inside
 curve25519-voi that the Go reference leans on (crypto/ed25519/ed25519.go:37);
@@ -61,7 +62,6 @@ def _const_loose(x: int) -> jnp.ndarray:
     return jnp.asarray(out, dtype=jnp.int32)[:, None]
 
 
-P_LIMBS = _const(P)
 D = _const(_D_INT)
 D2 = _const((2 * _D_INT) % P)
 SQRT_M1 = _const(_SQRT_M1_INT)
@@ -109,15 +109,6 @@ def _carry_round20(x: jnp.ndarray) -> jnp.ndarray:
     r = x & MASK
     shifted = jnp.concatenate([c[NLIMBS - 1:] * FOLD, c[: NLIMBS - 1]], axis=0)
     return r + shifted
-
-
-def weak_carry(x: jnp.ndarray) -> jnp.ndarray:
-    """Reduce limbs to the carried range. Three rounds handle any input with
-    |limb| <= ~2^15 (add/sub magnitudes); canonicalize and the comparison
-    entry points call this before interpreting limbs."""
-    for _ in range(3):
-        x = _carry_round20(x)
-    return x
 
 
 def add(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -238,34 +229,58 @@ def pow22523(z: jnp.ndarray) -> jnp.ndarray:
     return mul(_sqn(z_250_0, 2), z)
 
 
+_TOP_SHIFT = 255 - (NLIMBS - 1) * RADIX  # bit 255 sits at bit 8 of limb 19
+_P_INTS = tuple(int(v) for v in L.int_to_limbs(P))
+
+
+def _ripple(l: list[jnp.ndarray]) -> list[jnp.ndarray]:
+    """One sequential carry over limbs 0..18 into limb 19, no wrap: every
+    carry reaches the top however long the run of full limbs it crosses.
+    Leaves limbs 0..18 in [0, MASK] and the value unchanged."""
+    out = []
+    c = jnp.zeros_like(l[0])
+    for i in range(NLIMBS - 1):
+        v = l[i] + c
+        out.append(v & MASK)
+        c = v >> RADIX
+    out.append(l[NLIMBS - 1] + c)
+    return out
+
+
 def canonicalize(x: jnp.ndarray) -> jnp.ndarray:
-    """Unique representative mod p, limbs canonical, value in [0, p)."""
-    x = weak_carry(x)
-    top_shift = 255 - (NLIMBS - 1) * RADIX  # bit 255 within limb 19
-    top_mask = (1 << top_shift) - 1
-    for _ in range(3):  # fold bits >= 255 (2^255 = 19 mod p) + re-carry
-        hi = x[NLIMBS - 1] >> top_shift
-        x = jnp.concatenate(
-            [
-                (x[0] + 19 * hi)[None],
-                x[1: NLIMBS - 1],
-                (x[NLIMBS - 1] & top_mask)[None],
-            ],
-            axis=0,
-        )
-        x = _carry_round20(x)
-    l = [x[i] for i in range(NLIMBS)]
-    # value now < 2^255 + eps < 2p: one conditional subtract of p.
-    pl = [P_LIMBS[i, 0] for i in range(NLIMBS)]
-    borrow = jnp.zeros_like(l[0])
-    sub_l = []
-    for i in range(NLIMBS):
-        v = l[i] - pl[i] - borrow
-        borrow = (v < 0).astype(jnp.int32)
-        sub_l.append(v + (borrow << RADIX))
-    ge_p = borrow == 0
-    out = [jnp.where(ge_p, sub_l[i], l[i]) for i in range(NLIMBS)]
-    return jnp.stack(out, axis=0)
+    """Unique representative mod p, limbs canonical, value in [0, p), for
+    EVERY input with limbs in [-M_SUB limb, 2^30) — the carried invariant
+    and anything an add/sub of carried values can hold. Exact, not
+    probabilistic: the parallel rounds of the ops above move a carry one
+    limb a round, and a multiple of p written with a run of 8191 limbs
+    (p's own limbs 1..18) needs it to cross up to 19; here two sequential
+    ripples do that. Steps, each mirrored in exact interval arithmetic by
+    tests/test_field_intervals.py:
+      1. bias by 33p limb-wise (M_SUB): every limb, so the value, is >= 0
+         (redundant limbs CAN encode a negative integer after a wrap
+         round), and no borrow can run off the top;
+      2. ripple: limbs 0..18 canonical, limb 19 holds all the excess;
+      3. fold bits >= 255 of limb 19 into limb 0 (2^255 = 19 mod p);
+      4. ripple again: limb 19 in [0, 256], value < 2^255 + 2^247 < 2p;
+      5. one conditional subtract of p.
+    The scope names its ops in the XLA ladder's device trace; the Pallas
+    kernel traces this same function into its one custom call."""
+    with jax.named_scope("canonical_ripple"):
+        x = x + M_SUB
+        l = _ripple([x[i] for i in range(NLIMBS)])
+        hi = l[NLIMBS - 1] >> _TOP_SHIFT
+        l[NLIMBS - 1] = l[NLIMBS - 1] & ((1 << _TOP_SHIFT) - 1)
+        l[0] = l[0] + 19 * hi
+        l = _ripple(l)
+        borrow = jnp.zeros_like(l[0])
+        sub_l = []
+        for i in range(NLIMBS):
+            v = l[i] - _P_INTS[i] - borrow
+            borrow = (v < 0).astype(jnp.int32)
+            sub_l.append(v + (borrow << RADIX))
+        ge_p = borrow == 0
+        out = [jnp.where(ge_p, sub_l[i], l[i]) for i in range(NLIMBS)]
+        return jnp.stack(out, axis=0)
 
 
 def is_zero(x: jnp.ndarray) -> jnp.ndarray:

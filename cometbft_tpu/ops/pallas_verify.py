@@ -48,7 +48,7 @@ NDIG = U.NDIGITS5
 
 # Constants the traced field/curve code needs, pre-broadcast to the lane
 # width so they're ordinary VMEM blocks (index_map pins them to block 0).
-_FIELD_CONST_NAMES = ("M_SUB", "P_LIMBS", "D", "D2", "SQRT_M1", "ONE")
+_FIELD_CONST_NAMES = ("M_SUB", "D", "D2", "SQRT_M1", "ONE")
 
 
 def _const_args() -> tuple[np.ndarray, ...]:
@@ -245,20 +245,24 @@ def verify_pallas_ok_traced(ax, ay, az, at, r_words, s_words, k_words):
         ax, ay, az, at, r_words, s_words, k_words)
 
 
-def verify_pallas_sr(ax, ay, az, at, r_words, s_words, k_words,
-                     interpret=False):
-    """sr25519 (schnorrkel/ristretto) variant of verify_pallas: same
-    ladder, ristretto decode, cofactor-4 coset check."""
-    return _verify_pallas_bench(
-        ax, ay, az, at, r_words, s_words, k_words, interpret=interpret,
-        scheme="sr25519",
-    )[0]
-
-
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def verify_pallas_sr_ok(ax, ay, az, at, r_words, s_words, k_words,
                         interpret=False):
-    """sr25519 variant of verify_pallas_ok (mask, all-ok scalar)."""
-    return _verify_pallas_bench(
+    """sr25519 (schnorrkel/ristretto) variant of verify_pallas_ok (mask,
+    all-ok scalar): same ladder, ristretto decode, cofactor-4 coset check.
+    A program of its own name, `jit_verify_pallas_sr_ok`, so that a device
+    trace tells the sr25519 ladder from the ed25519 ones (the benchmark's
+    sr25519_kernel_roofline finds it by `verify_pallas_sr`; `verify_pallas`
+    still matches it for the roofline of both schemes). The entry's body
+    without its jit: no program nests one (verify_pallas_ok_traced)."""
+    return _verify_pallas_bench.__wrapped__(
         ax, ay, az, at, r_words, s_words, k_words, interpret=interpret,
         scheme="sr25519",
     )
+
+
+def verify_pallas_sr(ax, ay, az, at, r_words, s_words, k_words,
+                     interpret=False):
+    """The mask of verify_pallas_sr_ok alone."""
+    return verify_pallas_sr_ok(
+        ax, ay, az, at, r_words, s_words, k_words, interpret=interpret)[0]

@@ -20,6 +20,7 @@ described-device entry is written but cannot be read back without a chip).
 
 from __future__ import annotations
 
+import json
 import os
 
 import jax
@@ -28,6 +29,8 @@ import numpy as np
 import pytest
 
 from cometbft_tpu.ops.dispatch import KERNEL_DISPATCH_LOCK
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
@@ -75,9 +78,13 @@ def test_pallas_verify_compiles_for_v5e(one_chip, no_persistent_cache,
     accepts it and the program really carries the custom call."""
     from cometbft_tpu.ops import pallas_verify as PV
 
-    with KERNEL_DISPATCH_LOCK:
-        compiled = PV._verify_pallas_bench.lower(
-            *_verify_args(lanes, one_chip), scheme=scheme).compile()
+    # each scheme through the program its dispatch runs at that bucket:
+    # sr25519 has one of its own name (PERF.md section 3, PR 28)
+    args = _verify_args(lanes, one_chip)
+    with KERNEL_DISPATCH_LOCK:  # the kernel's trace swaps module constants
+        lowered = (PV.verify_pallas_sr_ok.lower(*args) if scheme == "sr25519"
+                   else PV._verify_pallas_bench.lower(*args, scheme=scheme))
+        compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -162,6 +169,14 @@ def test_pallas_verify_interpret_matches_host_oracle():
     msgs = [b"pallas interpret lane %d" % i for i in range(n)]
     sigs = [p.sign(m) for p, m in zip(privs, msgs)]
     sigs[bad] = sigs[bad][:40] + bytes([sigs[bad][40] ^ 1]) + sigs[bad][41:]
+    # lanes 1..5: the valid signatures the kernels condemned until
+    # canonicalize was made exact (tests/test_canonical_exact.py has them
+    # through the XLA ladder); the kernel body traces the same function
+    with open(os.path.join(ROOT, "benchmarks", "tests",
+                           "condemned_valid_signatures.json")) as fh:
+        for lane, case in enumerate(json.load(fh)["cases"], start=1):
+            pubs[lane], msgs[lane], sigs[lane] = (
+                bytes.fromhex(case[k]) for k in ("pub", "msg", "sig"))
     pre_ok, safe_pubs, rw, sw, kw = EK.stage_batch(pubs, msgs, sigs, n)
     enc = np.frombuffer(b"".join(safe_pubs), dtype=np.uint8).reshape(n, 32)
     ok_a, coords = EK.decompress_points(enc)
