@@ -92,6 +92,11 @@ from typing import Any, Callable, Optional
 STAGES = ("node", "signbytes", "collect", "queue", "stage", "transfer",
           "challenge", "compute", "fetch", "resolve", "gc")
 
+# The builders of a commit's sign-rows (types/commit.py): a finished
+# `commit.sign_bytes` span that built rows says which one ran (`path`) and
+# how many rows it made (`rows`); a cache hit or the serial loop says neither.
+SIGN_ROW_PATHS = ("vector", "scalar")
+
 _enabled = False  # module-global fast path: read before anything else
 
 _current: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
@@ -227,6 +232,7 @@ class Tracer:
         self._attr_rows = 0
         self._attr_tx = 0
         self._attr_rx = 0
+        self._sign_rows = dict.fromkeys(SIGN_ROW_PATHS, 0)
         # collector pauses: the gc hook stamps them here lock-free (it
         # runs wherever an allocation triggers a collection, also inside
         # _finish under self._lock); the next _finish or attribution()
@@ -283,6 +289,7 @@ class Tracer:
             rows = span.attrs.get("sig_rows", 0)
             if not isinstance(rows, int):
                 rows = 0
+            built = _sign_rows_built(span.name, span.attrs)
             # attribution is updated inline with the lock taken BEFORE t1
             # is read: lock acquisition and the dict updates are tracer
             # overhead that must be timed inside the span, not in the
@@ -297,6 +304,8 @@ class Tracer:
                 self._attr_rows += rows
                 self._attr_tx += span.bytes_tx
                 self._attr_rx += span.bytes_rx
+                if built:
+                    self._sign_rows[built[0]] += built[1]
                 if parent is not None and not parent._done:
                     # a counted span covers its full duration at the parent
                     parent._covered += dur
@@ -425,8 +434,9 @@ class Tracer:
             self._fold_gc()
             ns = dict(self._attr_ns)
             rows, tx, rx = self._attr_rows, self._attr_tx, self._attr_rx
+            sign_rows = dict(self._sign_rows)
             gen0, gen1, gen2 = self._gc_counts
-        out = _attribution_dict(ns, rows, tx, rx)
+        out = _attribution_dict(ns, rows, tx, rx, sign_rows)
         out["gc_collections"] = {"gen0": gen0, "gen1": gen1, "gen2": gen2}
         return out
 
@@ -436,6 +446,7 @@ class Tracer:
             self._attr_rows = 0
             self._attr_tx = 0
             self._attr_rx = 0
+            self._sign_rows = dict.fromkeys(SIGN_ROW_PATHS, 0)
             self._gc_pending.clear()
             self._gc_counts = [0, 0, 0]
 
@@ -676,7 +687,18 @@ def reset_attribution() -> None:
 # --------------------------------------------------------- the model
 
 
-def _attribution_dict(ns: dict, rows: int, tx: int, rx: int) -> dict:
+def _sign_rows_built(name: str, attrs: dict) -> Optional[tuple[str, int]]:
+    """(path, rows) of a `commit.sign_bytes` span that built its rows."""
+    if name != "commit.sign_bytes":
+        return None
+    path, rows = attrs.get("path"), attrs.get("rows", 0)
+    if path not in SIGN_ROW_PATHS or not isinstance(rows, int):
+        return None
+    return path, rows
+
+
+def _attribution_dict(ns: dict, rows: int, tx: int, rx: int,
+                      sign_rows: dict) -> dict:
     total = sum(ns.get(s, 0) for s in STAGES)
     shares = {
         s: (round(ns.get(s, 0) / total, 4) if total else 0.0)
@@ -691,6 +713,7 @@ def _attribution_dict(ns: dict, rows: int, tx: int, rx: int) -> dict:
         "wire_rx_bytes": rx,
         "bytes_per_sig_tx": round(tx / rows, 2) if rows else None,
         "bytes_per_sig_rx": round(rx / rows, 2) if rows else None,
+        "sign_rows": sign_rows,
     }
 
 
@@ -708,6 +731,7 @@ def attribution_of(spans: list[dict]) -> dict:
     order = sorted(spans, key=lambda r: r["t0_ns"] + r["dur_ns"])
     ns = {s: 0 for s in STAGES}
     rows = tx = rx = 0
+    sign_rows = dict.fromkeys(SIGN_ROW_PATHS, 0)
     for r in order:
         counted = r["cat"] in STAGES
         cov = covered.get(r["id"], 0)
@@ -717,11 +741,14 @@ def attribution_of(spans: list[dict]) -> dict:
             rows += n if isinstance(n, int) else 0
             tx += r.get("bytes_tx", 0)
             rx += r.get("bytes_rx", 0)
+            built = _sign_rows_built(r["name"], r["attrs"])
+            if built:
+                sign_rows[built[0]] += built[1]
         pid = r.get("parent_id")
         if pid is not None and pid in by_id:
             covered[pid] = covered.get(pid, 0) + (
                 r["dur_ns"] if counted else cov)
-    return _attribution_dict(ns, rows, tx, rx)
+    return _attribution_dict(ns, rows, tx, rx, sign_rows)
 
 
 # ----------------------------------------------------------- exporters

@@ -10,6 +10,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+import numpy as np
+
 from cometbft_tpu import crypto
 from cometbft_tpu.crypto import merkle
 from cometbft_tpu.libs import trace
@@ -20,6 +22,14 @@ from cometbft_tpu.utils import protobuf as pb
 
 MAX_COMMIT_OVERHEAD_BYTES = 94
 MAX_COMMIT_SIG_BYTES = 109
+
+# Rows from which the array pass builds a commit's sign-rows faster than
+# one Writer a signature: where it first won on the chip's host (32 rows:
+# 156 us against 169; 36: 171 against 165; PERF.md section 6, PR 29, has
+# the readings from 1 to 10,240 rows).
+VECTOR_SIGN_ROWS_MIN = 36
+
+_TS_TAG = 5 << 3 | 2  # CanonicalVote field 5, wire 2: the timestamp message
 
 
 @dataclass
@@ -142,24 +152,28 @@ class Commit:
         materialize as exception rows. The factored form flows through
         validation into kernel staging, where the whole run reassembles
         on the batch axis with one prefix broadcast instead of N row
-        copies (the reduced-send protocol's host half) — per-row Writer
-        construction was the dominant host cost of blocksync staging,
-        and the prefix copies were most of what remained."""
+        copies (the reduced-send protocol's host half).
+
+        Two builders make the same three parts, chosen by the row count
+        alone: from VECTOR_SIGN_ROWS_MIN rows on, _sign_row_parts_vector
+        encodes every timestamp and cuts every suffix in one array pass;
+        below it numpy's fixed cost loses to _sign_row_parts_scalar's one
+        Writer a signature, which also takes a commit whose stamps do not
+        fit int64. The span says which ran (`path`) and how many rows it
+        built (`rows`); a hit of the per-object memo says `cached`."""
         if self._sign_rows is None:
             self._sign_rows = {}
         rows = self._sign_rows.get(chain_id)
         with trace.span("commit.sign_bytes", cat="signbytes",
-                        cached=rows is not None):
+                        cached=rows is not None) as sp:
             if rows is None:
-                rows = self._build_sign_rows(chain_id)
+                rows, path = self._build_sign_rows(chain_id)
+                sp.set(rows=len(rows), path=path)
         return rows
 
     def _build_sign_rows(self, chain_id: str):
-        from collections import Counter
-
         from cometbft_tpu.libs.prefixrows import SharedPrefixRows
         from cometbft_tpu.types import canonical
-        from cometbft_tpu.utils.protobuf import encode_uvarint
 
         w = pb.Writer()
         w.uvarint(1, int(SignedMsgType.PRECOMMIT))
@@ -169,39 +183,20 @@ class Commit:
         w.message(4, canonical.canonical_block_id_bytes(self.block_id))
         head_commit = w.output()
         tail = pb.Writer().string(6, chain_id).output()
-        ts_tag = bytes([5 << 3 | 2])  # field 5, wire 2 (timestamp message)
-        ts_all = [pb.timestamp_bytes(cs.timestamp.seconds,
-                                     cs.timestamp.nanos)
-                  for cs in self.signatures]
-        # the shared prefix covers COMMIT rows at the commit's modal
-        # timestamp-encoding length (the length varint in front of the
-        # body pins the total row length, so an off-length timestamp
-        # cannot share it)
-        commit_lens = Counter(
-            len(ts) for ts, cs in zip(ts_all, self.signatures)
-            if cs.block_id_flag == BlockIDFlag.COMMIT)
-        modal_ts_len = commit_lens.most_common(1)[0][0] if commit_lens else 0
-        modal_body = (len(head_commit) + len(ts_tag)
-                      + len(encode_uvarint(modal_ts_len)) + modal_ts_len
-                      + len(tail))
-        prefix = encode_uvarint(modal_body) + head_commit
-        suffixes: list = []
-        exceptions: dict[int, bytes] = {}
-        for i, (ts, cs) in enumerate(zip(ts_all, self.signatures)):
-            if (cs.block_id_flag == BlockIDFlag.COMMIT
-                    and len(ts) == modal_ts_len):
-                suffixes.append(ts_tag + encode_uvarint(len(ts)) + ts + tail)
-                continue
-            head = (head_commit if cs.block_id_flag == BlockIDFlag.COMMIT
-                    else head_nil)
-            body = head + ts_tag + encode_uvarint(len(ts)) + ts + tail
-            suffixes.append(None)
-            exceptions[i] = encode_uvarint(len(body)) + body
-        rows = SharedPrefixRows(prefix, suffixes, exceptions)
+        args = (self.signatures, head_commit, head_nil, tail)
+        path, parts = "scalar", None
+        if len(self.signatures) >= VECTOR_SIGN_ROWS_MIN:
+            try:
+                path, parts = "vector", _sign_row_parts_vector(*args)
+            except OverflowError:
+                pass  # a stamp past int64: the Writer masks it to 64 bits
+        if parts is None:
+            parts = _sign_row_parts_scalar(*args)
+        rows = SharedPrefixRows(*parts)
         if len(self._sign_rows) >= self._MAX_SIGN_ROW_CHAINS:
             self._sign_rows.pop(next(iter(self._sign_rows)))
         self._sign_rows[chain_id] = rows
-        return rows
+        return rows, path
 
     def hash(self) -> bytes:
         """Merkle root over CommitSig protos (types/block.go Commit.Hash)."""
@@ -250,6 +245,102 @@ class Commit:
             else:
                 r.skip(w)
         return c
+
+
+# The two builders of Commit.vote_sign_bytes_all's rows. Both return what
+# SharedPrefixRows takes: the prefix of the COMMIT rows whose timestamp has
+# the commit's modal encoded length (the length varint in front of the
+# body pins the total row length, so an off-length timestamp cannot share
+# it), a suffix `ts_tag | len | timestamp | tail` for each of those rows
+# and None for the others, and the others whole, by index.
+
+
+def _sign_row_parts_scalar(signatures, head_commit: bytes, head_nil: bytes,
+                           tail: bytes):
+    """One Writer a signature: the builder of small commits, of stamps
+    that do not fit int64, and the tests' oracle for the array pass."""
+    from collections import Counter
+
+    ts_tag = bytes([_TS_TAG])
+    ts_all = [pb.timestamp_bytes(cs.timestamp.seconds, cs.timestamp.nanos)
+              for cs in signatures]
+    commit_lens = Counter(
+        len(ts) for ts, cs in zip(ts_all, signatures)
+        if cs.block_id_flag == BlockIDFlag.COMMIT)
+    modal_ts_len = commit_lens.most_common(1)[0][0] if commit_lens else 0
+    prefix = _shared_prefix(head_commit, modal_ts_len, tail)
+    suffixes: list = []
+    exceptions: dict[int, bytes] = {}
+    for i, (ts, cs) in enumerate(zip(ts_all, signatures)):
+        if (cs.block_id_flag == BlockIDFlag.COMMIT
+                and len(ts) == modal_ts_len):
+            suffixes.append(ts_tag + pb.encode_uvarint(len(ts)) + ts + tail)
+            continue
+        head = (head_commit if cs.block_id_flag == BlockIDFlag.COMMIT
+                else head_nil)
+        body = head + ts_tag + pb.encode_uvarint(len(ts)) + ts + tail
+        suffixes.append(None)
+        exceptions[i] = pb.encode_uvarint(len(body)) + body
+    return prefix, suffixes, exceptions
+
+
+def _sign_row_parts_vector(signatures, head_commit: bytes, head_nil: bytes,
+                           tail: bytes):
+    """The same parts in one array pass: the timestamps of all rows as one
+    ragged matrix (pb.timestamp_rows) set between the constant columns,
+    compacted once and cut into a suffix a row; the rows that cannot share
+    the prefix then take their head in front. OverflowError where a stamp
+    does not fit int64."""
+    n = len(signatures)
+    seconds = np.array([cs.timestamp.seconds for cs in signatures],
+                       dtype=np.int64)
+    nanos = np.array([cs.timestamp.nanos for cs in signatures],
+                     dtype=np.int64)
+    for_block = np.array([cs.block_id_flag == BlockIDFlag.COMMIT
+                          for cs in signatures], dtype=bool)
+    ts_cells, ts_keep, ts_len = pb.timestamp_rows(seconds, nanos)
+    # a timestamp is 22 bytes at most: its length varint is one byte
+    ts_end = 2 + ts_cells.shape[1]
+    cells = np.empty((n, ts_end + len(tail)), dtype=np.uint8)
+    cells[:, 0] = _TS_TAG
+    cells[:, 1] = ts_len
+    cells[:, 2:ts_end] = ts_cells
+    cells[:, ts_end:] = np.frombuffer(tail, dtype=np.uint8)
+    keep = np.ones(cells.shape, dtype=bool)
+    keep[:, 2:ts_end] = ts_keep
+    flat = cells[keep].tobytes()
+    ends = np.cumsum(ts_len + (2 + len(tail))).tolist()
+    suffixes: list = [flat[a:b] for a, b in zip([0] + ends, ends)]
+
+    commit_lens = ts_len[for_block]
+    modal_ts_len = 0
+    if len(commit_lens):
+        counts = np.bincount(commit_lens)
+        top = np.flatnonzero(counts == counts.max())
+        if len(top) > 1:  # Counter.most_common's choice: the one met first
+            top = commit_lens[np.isin(commit_lens, top)]
+        modal_ts_len = int(top[0])
+    prefix = _shared_prefix(head_commit, modal_ts_len, tail)
+    exceptions: dict[int, bytes] = {}
+    fronts: dict = {}  # (for the block?, suffix length) -> length + head
+    others = np.flatnonzero(~(for_block & (ts_len == modal_ts_len)))
+    for i, commits in zip(others.tolist(), for_block[others].tolist()):
+        suffix, suffixes[i] = suffixes[i], None
+        front = fronts.get((commits, len(suffix)))
+        if front is None:
+            head = head_commit if commits else head_nil
+            front = fronts[commits, len(suffix)] = (
+                pb.encode_uvarint(len(head) + len(suffix)) + head)
+        exceptions[i] = front + suffix
+    return prefix, suffixes, exceptions
+
+
+def _shared_prefix(head_commit: bytes, modal_ts_len: int,
+                   tail: bytes) -> bytes:
+    modal_body = (len(head_commit) + 1
+                  + len(pb.encode_uvarint(modal_ts_len)) + modal_ts_len
+                  + len(tail))
+    return pb.encode_uvarint(modal_body) + head_commit
 
 
 @dataclass

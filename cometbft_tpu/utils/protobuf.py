@@ -15,6 +15,8 @@ from __future__ import annotations
 
 import struct
 
+import numpy as np
+
 _U64_MASK = (1 << 64) - 1
 
 
@@ -262,3 +264,39 @@ def timestamp_bytes(seconds: int, nanos: int) -> bytes:
     w.varint_i64(1, seconds)
     w.varint_i64(2, nanos)
     return w.output()
+
+
+# a uint64's varint takes one byte more at each of these, and its k-th
+# byte holds the seven bits above the k-th shift
+_VARINT_STEPS = np.array([1 << 7 * k for k in range(1, 10)], dtype=np.uint64)
+_VARINT_SHIFTS = np.arange(10, dtype=np.uint64) * np.uint64(7)
+
+
+def _varint_fields(field: int, values: np.ndarray):
+    """Writer.varint_i64(field, v) for every v of an int64 array in one
+    pass: an (n, 1 + k) uint8 matrix of the tag and the k varint bytes the
+    longest value takes, and how many bytes of each row count from the
+    left (none where v is zero: proto3 omits the field). A negative value
+    is its ten-byte two's complement, as encode_varint_i64 has it."""
+    v = values.view(np.uint64)
+    count = np.searchsorted(_VARINT_STEPS, v, side="right") + 1
+    k = int(count.max()) if len(v) else 1
+    cells = np.empty((len(v), 1 + k), dtype=np.uint8)
+    cells[:, 0] = field << 3  # wire type 0; one byte for fields under 16
+    cells[:, 1:] = (v[:, None] >> _VARINT_SHIFTS[:k]) & np.uint64(0x7F)
+    # every byte but a value's last carries the continuation bit
+    cells[:, 1:] |= (np.arange(1, k + 1) < count[:, None]).astype(np.uint8) << 7
+    return cells, np.where(v != 0, count + 1, 0)
+
+
+def timestamp_rows(seconds: np.ndarray, nanos: np.ndarray):
+    """timestamp_bytes(seconds[i], nanos[i]) for all i in one array pass
+    (both int64 arrays), as ragged rows: (cells, keep, lens) where
+    cells[i][keep[i]] are the lens[i] bytes of row i. The caller sets the
+    rows between its own columns and compacts once (types/commit.py)."""
+    s_cells, s_len = _varint_fields(1, seconds)
+    n_cells, n_len = _varint_fields(2, nanos)
+    keep = np.concatenate(
+        (np.arange(s_cells.shape[1]) < s_len[:, None],
+         np.arange(n_cells.shape[1]) < n_len[:, None]), axis=1)
+    return np.concatenate((s_cells, n_cells), axis=1), keep, s_len + n_len
