@@ -256,7 +256,7 @@ def bench_blocksync(detail: dict) -> None:
 
 def bench_mixed_megacommit(detail: dict) -> None:
     """BASELINE config 5: a mixed ed25519+sr25519 10k-validator mega-commit
-    through MixedBatchVerifier — half the rows each scheme, one device batch
+    through the verify scheduler — half the rows each scheme, one device batch
     per scheme, both dispatched async and resolved with one fetch. Reports
     wall latency (link-inclusive), a host-staging/device/link
     decomposition, and the sr25519 kernel's rep-differenced device time."""
@@ -284,7 +284,7 @@ def bench_mixed_megacommit(detail: dict) -> None:
         rows.append(distinct[i % len(distinct)])
 
     def run() -> float:
-        v = crypto_batch.MixedBatchVerifier()
+        v = crypto_batch.create_mixed_batch_verifier()
         for pk, m, s in rows:
             v.add(pk, m, s)
         t0 = time.perf_counter()
@@ -1735,10 +1735,7 @@ def bench_scheduler(detail: dict) -> None:
                                   architecture), measured on this load
       sched_latency_per_class     submit->dispatch p50/p99 ms
       sched_direct_flush_*        consensus flush-sized batches through
-                                  the scheduler (with filler queued) vs
-                                  the direct fragmented verifier path —
-                                  the no-regression check for consensus
-                                  flush latency
+                                  the scheduler (with filler queued)
     """
     import asyncio
 
@@ -1748,12 +1745,10 @@ def bench_scheduler(detail: dict) -> None:
 
     from cometbft_tpu import sched
     from cometbft_tpu.consensus.config import test_consensus_config
-    from cometbft_tpu.crypto import batch as crypto_batch
     from cometbft_tpu.crypto import ed25519
     from cometbft_tpu.types import validation
 
     sched.reset()
-    sched.configure(enabled=True)
     out: dict = {}
 
     # ---- live mixed load: 4-val net + mempool pump + sync windows
@@ -1871,26 +1866,12 @@ def bench_scheduler(detail: dict) -> None:
         mask = sched.get().verify_now(rows, sched.CONSENSUS)
         sched_ts.append(time.perf_counter() - t0)
         assert all(mask)
-    direct_ts = []
-    sched.configure(enabled=False)
-    try:
-        for _ in range(20):
-            bv = crypto_batch.create_mixed_batch_verifier()
-            for pk, m, s in rows:
-                bv.add(pk, m, s)
-            t0 = time.perf_counter()
-            ok, _ = bv.verify()
-            direct_ts.append(time.perf_counter() - t0)
-            assert ok
-    finally:
-        sched.configure(enabled=True)
     out["direct_flush_sched_p50_ms"], out["direct_flush_sched_p99_ms"] = p50p99(sched_ts)
-    out["direct_flush_frag_p50_ms"], out["direct_flush_frag_p99_ms"] = p50p99(direct_ts)
     out["note"] = (
         "fill_ratio_mean vs fragmented_fill_ratio_mean measures the SAME "
         "live load batched by the scheduler vs one-batch-per-producer; "
-        "direct_flush_* is the consensus-flush latency no-regression pair "
-        "(scheduler with filler vs pre-scheduler fragmented verifier)")
+        "direct_flush_sched_* is the consensus-flush latency through the "
+        "scheduler with filler queued")
     detail["sched"] = out
 
 
@@ -1922,7 +1903,6 @@ def bench_soak(detail: dict) -> None:
     from cometbft_tpu.mempool.mempool import ErrMempoolIsFull
 
     sched.reset()
-    sched.configure(enabled=True)
     heights_goal = int(os.environ.get("BENCH_SOAK_HEIGHTS", "30"))
     quiet_goal = int(os.environ.get("BENCH_SOAK_QUIET_HEIGHTS", "8"))
     pool_size = int(os.environ.get("BENCH_SOAK_POOL", "512"))

@@ -79,12 +79,10 @@ class CryptoConfig:
     backend: str = "auto"  # "cpu" | "tpu" | "auto"
     # coalesce at most this many signatures into one device batch
     max_batch_size: int = 16384
-    # --- global verify scheduler (sched/scheduler.py) ---
-    # route ALL batch verification through the node-wide scheduler
-    # (continuous batching: consensus flushes drain immediately and
-    # coalesce queued sync/mempool work as filler). Off = the pre-
-    # scheduler fragmented dispatch (each producer its own batch).
-    scheduler: bool = True
+    # --- global verify scheduler (sched/scheduler.py): every batch
+    # verification goes through it (continuous batching: consensus
+    # flushes drain immediately and coalesce queued sync/mempool work
+    # as filler) ---
     # cap on rows coalesced into one scheduler batch (groups never split)
     sched_max_lanes: int = 16384
     # flush deadlines per class: consensus is always 0 (inline drain);

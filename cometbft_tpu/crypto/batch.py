@@ -4,6 +4,9 @@ The reference keys verifier creation on pubkey *type*; this framework adds the
 backend dimension — "cpu" (OpenSSL loop), "tpu" (JAX/Pallas device kernel),
 or "auto" (tpu when an accelerator is present, else cpu). The chosen backend
 is process-global, set once from config (config.crypto.backend) at node boot.
+Every verifier handed out here is a client of the node-wide verify scheduler
+(sched/scheduler.py): VerifyScheduler._run_batch alone decides which backend
+and which kernel serve a batch's rows.
 """
 
 from __future__ import annotations
@@ -18,13 +21,14 @@ from cometbft_tpu.libs.prefixrows import PrefixedMsg
 _BACKEND = "auto"
 _device: Optional[dict] = None
 
-# key type -> backend name -> factory
-_REGISTRY: dict[str, dict[str, Callable[[], crypto.BatchVerifier]]] = {}
+# batchable key type -> host batch verifier factory (the scheduler's CPU
+# rung, VerifyScheduler._host_mask)
+_REGISTRY: dict[str, Callable[[], crypto.BatchVerifier]] = {}
 
 
-def register(key_type: str, backend: str,
+def register(key_type: str,
              factory: Callable[[], crypto.BatchVerifier]) -> None:
-    _REGISTRY.setdefault(key_type, {})[backend] = factory
+    _REGISTRY[key_type] = factory
 
 
 def set_backend(backend: str) -> None:
@@ -108,7 +112,6 @@ def configure(crypto_cfg) -> None:
     from cometbft_tpu import sched
 
     sched.configure(
-        enabled=crypto_cfg.scheduler,
         max_lanes=crypto_cfg.sched_max_lanes,
         sync_deadline=crypto_cfg.sched_sync_deadline,
         light_deadline=crypto_cfg.sched_light_deadline,
@@ -170,86 +173,24 @@ def supports_batch_verifier(pub_key: crypto.PubKey | None) -> bool:
 
 
 def create_batch_verifier(pub_key: crypto.PubKey) -> crypto.BatchVerifier:
-    """Create a verifier for this key type on the configured backend.
-    Raises ErrInvalidKey for unbatchable key types (caller falls back to
-    serial verification, as the reference does).
+    """Create a verifier for this key type. Raises ErrInvalidKey for
+    unbatchable key types (caller falls back to serial verification, as
+    the reference does).
 
-    With the global verify scheduler enabled (the default) the returned
-    verifier is a CLIENT of the node-wide scheduler: verify() drains as
-    one inline batch that coalesces whatever compatible queued work fits
-    the bucket (sched/scheduler.py). The producer no longer owns device
-    dispatch — that inversion is what keeps the device running few full
-    batches instead of many fragmented ones."""
-    backends = _REGISTRY.get(pub_key.type_())
-    if not backends:
+    The returned verifier is a CLIENT of the node-wide scheduler: verify()
+    drains as one inline batch that coalesces whatever compatible queued
+    work fits the bucket (sched/scheduler.py). The producer does not own
+    device dispatch — that inversion is what keeps the device running few
+    full batches instead of many fragmented ones."""
+    if pub_key.type_() not in _REGISTRY:
         raise crypto.ErrInvalidKey(
             f"key type {pub_key.type_()!r} has no batch verifier")
-    from cometbft_tpu import sched
-
-    if sched.enabled():
-        return ScheduledBatchVerifier()
-    backend = resolve_backend()
-    factory = backends.get(backend) or backends["cpu"]
-    try:
-        return factory()
-    except Exception:  # noqa: BLE001 - device backend unavailable/broken
-        if backend == "cpu":
-            raise
-        return backends["cpu"]()
-
-
-class MixedBatchVerifier(crypto.BatchVerifier):
-    """Coalesces a mixed-scheme batch (BASELINE config 5: ed25519+sr25519
-    mega-commits): add() routes each row to a per-type sub-verifier on the
-    configured backend; verify() runs every sub-batch and stitches the
-    per-lane masks back into input order. On the TPU backend each scheme is
-    one device batch — a mixed 10k-commit costs two kernel dispatches, not
-    10k serial verifies."""
-
-    def __init__(self):
-        self._subs: dict[str, crypto.BatchVerifier] = {}
-        self._route: list[tuple[str, int]] = []  # (key type, index in sub)
-
-    def add(self, pub_key: crypto.PubKey, msg: bytes, sig: bytes) -> None:
-        kt = pub_key.type_()
-        _check_bls_enabled(kt)
-        sub = self._subs.get(kt)
-        if sub is None:
-            backends = _REGISTRY.get(kt)
-            if not backends:
-                raise crypto.ErrInvalidKey(f"key type {kt!r} has no batch verifier")
-            backend = resolve_backend()
-            sub = (backends.get(backend) or backends["cpu"])()
-            self._subs[kt] = sub
-        sub.add(pub_key, msg, sig)
-        self._route.append((kt, sub.count() - 1))
-
-    def verify(self) -> tuple[bool, list[bool]]:
-        if len(self._subs) > 1 and all(
-            hasattr(sub, "verify_async") for sub in self._subs.values()
-        ):
-            # device backends: dispatch every scheme's sub-batch without
-            # blocking, then resolve ALL masks with one device->host fetch
-            # (over a high-RTT link the serial per-scheme sync path paid
-            # one full round trip per scheme)
-            from cometbft_tpu.ops import ed25519_kernel
-
-            thunks = {kt: sub.verify_async() for kt, sub in self._subs.items()}
-            resolved = ed25519_kernel.resolve_batches(list(thunks.values()))
-            masks = {kt: m for kt, m in zip(thunks, resolved)}
-        else:
-            masks = {kt: sub.verify()[1] for kt, sub in self._subs.items()}
-        with trace.span("commit.verdict", cat="collect"):
-            out = [bool(masks[kt][i]) for kt, i in self._route]
-            return all(out), out
-
-    def count(self) -> int:
-        return len(self._route)
+    return ScheduledBatchVerifier()
 
 
 class ScheduledBatchVerifier(crypto.BatchVerifier):
     """The scheduler-client face of crypto.BatchVerifier: add() stages
-    rows host-side (cheap structural checks, same contract as the CPU/TPU
+    rows host-side (cheap structural checks, same contract as the CPU
     verifiers); verify() submits the rows to the global VerifyScheduler
     as ONE group under the caller's ambient priority class
     (sched.work_class) and drains inline, coalescing queued filler.
@@ -295,23 +236,9 @@ class ScheduledBatchVerifier(crypto.BatchVerifier):
 
 
 def create_mixed_batch_verifier() -> crypto.BatchVerifier:
-    from cometbft_tpu import sched
-
-    if sched.enabled():
-        return ScheduledBatchVerifier()
-    return MixedBatchVerifier()
-
-
-def _tpu_ed25519_factory() -> crypto.BatchVerifier:
-    from cometbft_tpu.ops.batch_verifier import TPUBatchVerifier
-
-    return TPUBatchVerifier()
-
-
-def _tpu_sr25519_factory() -> crypto.BatchVerifier:
-    from cometbft_tpu.ops.batch_verifier import SrTPUBatchVerifier
-
-    return SrTPUBatchVerifier()
+    """A verifier for rows of any mix of batchable key types (BASELINE
+    config 5: ed25519 + sr25519 mega-commits)."""
+    return ScheduledBatchVerifier()
 
 
 def _cpu_sr25519_factory() -> crypto.BatchVerifier:
@@ -320,21 +247,12 @@ def _cpu_sr25519_factory() -> crypto.BatchVerifier:
     return sr25519.CPUBatchVerifier()
 
 
-def _tpu_bls_factory() -> crypto.BatchVerifier:
-    from cometbft_tpu.ops.batch_verifier import BlsTPUBatchVerifier
-
-    return BlsTPUBatchVerifier()
-
-
 def _cpu_bls_factory() -> crypto.BatchVerifier:
     from cometbft_tpu.crypto import bls12381
 
     return bls12381.CPUBatchVerifier()
 
 
-register(ed25519.KEY_TYPE, "cpu", ed25519.CPUBatchVerifier)
-register(ed25519.KEY_TYPE, "tpu", _tpu_ed25519_factory)
-register("sr25519", "cpu", _cpu_sr25519_factory)
-register("sr25519", "tpu", _tpu_sr25519_factory)
-register("bls12381", "cpu", _cpu_bls_factory)
-register("bls12381", "tpu", _tpu_bls_factory)
+register(ed25519.KEY_TYPE, ed25519.CPUBatchVerifier)
+register("sr25519", _cpu_sr25519_factory)
+register("bls12381", _cpu_bls_factory)

@@ -12,5 +12,4 @@ Layout:
   field.py            GF(2^255-19) arithmetic, radix-2^13 x 20 limbs, int32
   curve.py            edwards25519 point ops, decompression, Straus ladder
   ed25519_kernel.py   jitted batch-verify entry + host glue (hashing, padding)
-  batch_verifier.py   crypto.BatchVerifier implementation backed by the kernel
 """

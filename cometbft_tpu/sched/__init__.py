@@ -2,7 +2,7 @@
 
 Public surface: the process-global VerifyScheduler singleton (get()),
 the priority-class constants and the ambient-class context manager
-(work_class), plus configure()/enabled()/reset() for node boot and tests.
+(work_class), plus configure()/reset() for node boot and tests.
 See cometbft_tpu/sched/scheduler.py for the design.
 """
 
@@ -24,17 +24,10 @@ from cometbft_tpu.sched.scheduler import (  # noqa: F401 - public re-exports
 
 _lock = threading.Lock()
 _sched: VerifyScheduler | None = None
-_enabled = True
 
 # constructor kwargs applied at (re)creation — configure() records them so
 # a get() after reset() rebuilds with the node's knobs, not the defaults
 _kwargs: dict = {}
-
-
-def enabled() -> bool:
-    """Is scheduler routing on? When off, crypto/batch falls back to the
-    pre-scheduler fragmented dispatch (each producer its own batch)."""
-    return _enabled
 
 
 def get() -> VerifyScheduler:
@@ -46,19 +39,16 @@ def get() -> VerifyScheduler:
     return _sched
 
 
-def configure(enabled: bool | None = None, **kwargs) -> None:
+def configure(**kwargs) -> None:
     """Apply config.crypto scheduler knobs (node boot; tests poke
     directly). Unknown knobs raise. Live instance updated in place so a
     reconfig doesn't orphan queued work."""
-    global _enabled
     allowed = {"max_lanes", "sync_deadline", "light_deadline",
                "mempool_deadline", "queue_limit", "starvation_limit"}
     bad = set(kwargs) - allowed
     if bad:
         raise ValueError(f"unknown scheduler knob(s) {sorted(bad)}")
     with _lock:
-        if enabled is not None:
-            _enabled = enabled
         _kwargs.update(kwargs)
         if _sched is not None:
             if "max_lanes" in kwargs:
@@ -93,5 +83,5 @@ def health_snapshot() -> dict:
     """The crypto_health `verify_sched` section. Never creates the
     singleton implicitly beyond what get() would."""
     snap = get().health()
-    snap["enabled"] = _enabled
+    snap["enabled"] = True  # the only route; crypto_health readers keep the key
     return snap

@@ -695,9 +695,9 @@ class VerifyScheduler:
         from cometbft_tpu.libs.prefixrows import as_bytes
 
         n = len(d["sigs"])
-        backends = crypto_batch._REGISTRY.get(scheme)
-        if backends is not None:
-            bv = backends["cpu"]()
+        factory = crypto_batch._REGISTRY.get(scheme)
+        if factory is not None:
+            bv = factory()
             staged: list[int] = []
             mask = np.zeros(n, dtype=bool)
             for i in range(n):

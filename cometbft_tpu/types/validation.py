@@ -377,33 +377,35 @@ def verify_commit_light_trusting(
 # The reference verifies each commit synchronously, twice (VerifyCommitLight
 # in the blocksync reactor, then VerifyCommit again inside validateBlock,
 # blocksync/reactor.go:463 + state/validation.go:92). TPU-first redesign:
-# stage ONE full-semantics verification per commit on the device without
-# blocking (verify_batch_async), resolve a whole window of heights with a
-# single device fetch (resolve_batches), and let ApplyBlock skip the
-# redundant re-verification (last_commit_verified).
+# stage ONE full-semantics verification per commit without verifying it,
+# resolve a whole window of heights as one scheduler batch (prefetch_staged),
+# and let ApplyBlock skip the redundant re-verification
+# (last_commit_verified).
 # ---------------------------------------------------------------------------
 
 
 class StagedCommitVerification:
     """A staged-but-unresolved verify_commit: finish() raises exactly what
-    the sync path would. On the TPU backend the prepared rows (ed_rows) are
-    NOT dispatched at staging time — prefetch_staged coalesces every staged
-    commit in a window into ONE device batch (one transfer, one kernel
-    dispatch, one device->host fetch), which is what makes the blocksync
-    window pipeline device-bound instead of dispatch-overhead-bound.
-    device_thunk remains supported for callers that pre-dispatched."""
+    the sync path would. The rows (PubKey objects, sign-bytes, signatures:
+    one form whatever the scheme or backend) are NOT verified at staging
+    time — prefetch_staged coalesces every staged commit in a window into
+    ONE scheduler batch (one transfer, one kernel dispatch, one
+    device->host fetch on the device backend), which is what makes the
+    blocksync window pipeline device-bound instead of
+    dispatch-overhead-bound."""
 
-    def __init__(self, commit: Commit, sig_idxs: list[int], device_thunk=None,
-                 cpu_rows=None, ed_rows=None, bls_rows=None):
+    def __init__(self, commit: Commit, pubs: list, msgs: list,
+                 sigs: list[bytes], sig_idxs: list[int]):
+        """The rows as _commit_rows returns them."""
         self.commit = commit
         self.sig_idxs = sig_idxs
-        self.device_thunk = device_thunk
-        self._cpu_rows = cpu_rows
-        self._ed_rows = ed_rows  # (pub_bytes, msgs, sigs) all-ed25519 rows
-        # (pubs, msgs, sigs) all-bls12381 rows: finish() tries ONE
-        # aggregate pairing-product check first; only a failed aggregate
-        # pays the per-lane pinpoint pass
-        self._bls_rows = bls_rows
+        self._rows = (pubs, msgs, sigs)
+        # every key is bls12381: finish() tries ONE aggregate
+        # pairing-product check first (blocksync/light windows decide a
+        # BLS commit with it); only a failed aggregate pays the per-lane
+        # pinpoint pass
+        self._bls_rows = bool(pubs) and all(
+            p.type_() == "bls12381" for p in pubs)
         self._mask = None
         self._passed = False
 
@@ -420,59 +422,29 @@ class StagedCommitVerification:
     def _finish(self, mask) -> None:
         if mask is None:
             mask = self._mask
-        if mask is None and self._bls_rows is not None:
-            pubs, msgs, sigs = self._bls_rows
-            if _bls_aggregate_ok(pubs, msgs, sigs):
-                self._passed = True
-                return
-            # pinpoint below through the per-lane batch path
-            self._cpu_rows = self._bls_rows
+        pubs, msgs, sigs = self._rows
+        if mask is None and self._bls_rows and _bls_aggregate_ok(
+                pubs, msgs, sigs):
+            self._passed = True
+            return
         if mask is None:
-            if self.device_thunk is not None:
-                mask = self.device_thunk()
-            elif self._ed_rows is not None:
-                # solo finish without a window prefetch: dispatch this
-                # commit's rows as their own device batch
-                from cometbft_tpu.ops import ed25519_kernel
+            # solo finish without a window prefetch (or the pinpoint pass
+            # of a failed BLS aggregate): this commit's rows as their own
+            # scheduler batch
+            bv = crypto_batch.create_mixed_batch_verifier()
+            try:
+                for p, m, s in zip(pubs, msgs, sigs):
+                    bv.add(p, m, s)
+                _, mask = bv.verify()
+            except Exception:  # noqa: BLE001 - unbatchable key type
+                from cometbft_tpu.libs.prefixrows import as_bytes
 
-                mask = ed25519_kernel.verify_batch_async(*self._ed_rows)()
-            else:
-                # non-ed25519 / non-TPU rows: still batched per scheme (the
-                # mixed verifier reaches the sr25519 device kernel on the
-                # TPU backend) rather than serial per-signature host calls
-                pubs, msgs, sigs = self._cpu_rows
-                bv = crypto_batch.create_mixed_batch_verifier()
-                try:
-                    for p, m, s in zip(pubs, msgs, sigs):
-                        bv.add(p, m, s)
-                    _, mask = bv.verify()
-                except Exception:  # noqa: BLE001 - unbatchable key type
-                    from cometbft_tpu.libs.prefixrows import as_bytes
-
-                    # materialize factored rows: schemes outside the
-                    # batch registry (secp256k1) take raw bytes only
-                    mask = [p.verify_signature(as_bytes(m), s)
-                            for p, m, s in zip(pubs, msgs, sigs)]
+                # materialize factored rows: schemes outside the
+                # batch registry (secp256k1) take raw bytes only
+                mask = [p.verify_signature(as_bytes(m), s)
+                        for p, m, s in zip(pubs, msgs, sigs)]
         _raise_first_bad(self.commit, self.sig_idxs, mask)
         self._passed = True
-
-
-def _stage_rows(commit: Commit, rows) -> StagedCommitVerification:
-    """Prepare commit rows for the device batch when every key is ed25519
-    on the TPU backend (dispatch deferred to prefetch_staged / finish);
-    else defer to per-scheme host batching at finish()."""
-    pubs, msgs, sigs, idxs = rows
-    if pubs and all(p.type_() == "bls12381" for p in pubs):
-        # aggregate-verified at finish(): blocksync/light windows decide
-        # each BLS commit with one pairing-product check
-        return StagedCommitVerification(
-            commit, idxs, bls_rows=(pubs, msgs, sigs))
-    if crypto_batch.resolve_backend() == "tpu" and all(
-        p.type_() == "ed25519" for p in pubs
-    ):
-        return StagedCommitVerification(
-            commit, idxs, ed_rows=([p.bytes_() for p in pubs], msgs, sigs))
-    return StagedCommitVerification(commit, idxs, cpu_rows=(pubs, msgs, sigs))
 
 
 def stage_verify_commit(
@@ -492,7 +464,7 @@ def stage_verify_commit(
             count_all_signatures=True,
             lookup_by_index=True,
         )
-        return _stage_rows(commit, rows)
+        return StagedCommitVerification(commit, *rows)
 
 
 def stage_verify_commit_light(
@@ -511,7 +483,7 @@ def stage_verify_commit_light(
             count_all_signatures=False,
             lookup_by_index=True,
         )
-        return _stage_rows(commit, rows)
+        return StagedCommitVerification(commit, *rows)
 
 
 def stage_verify_commit_light_trusting(
@@ -529,131 +501,33 @@ def stage_verify_commit_light_trusting(
             count_all_signatures=False,
             lookup_by_index=False,
         )
-        return _stage_rows(commit, rows)
+        return StagedCommitVerification(commit, *rows)
 
 
 def prefetch_staged(staged: list[StagedCommitVerification],
                     klass: str | None = None) -> None:
-    """Resolve every staged commit in the window with ONE device batch:
-    the window's rows concatenate into a single transfer + kernel dispatch +
-    device->host fetch, then the combined mask is sliced back per commit.
-    The fetch rides the reduced-fetch protocol (ed25519_kernel.
-    resolve_batches): a happy window — every commit valid, the steady
-    state — transfers 8 bytes per batch; the per-lane masks are pulled
-    only when some batch's header reports a failure. Subsequent finish()
-    calls are pure host work (per-commit error isolation stays with the
-    caller). Pre-dispatched device_thunk items are resolved alongside with
-    the same single fetch.
-
-    With the global verify scheduler enabled (the default) the window is
-    submitted to it instead — one group per commit, so each keeps its own
-    host-oracle recheck budget — under `klass` (default SYNC: blocksync
-    and light-client windows yield the device to consensus flushes), and
-    queued mempool-admission work rides the same batch as filler."""
+    """Resolve every staged commit in the window with ONE scheduler batch
+    (sched/scheduler.py): one group per commit, so each keeps its own
+    host-oracle recheck budget, under `klass` (default SYNC: blocksync
+    and light-client windows yield the device to consensus flushes);
+    queued mempool-admission work rides the same batch as filler. The
+    scheduler picks the backend per dispatch and chunks the window below
+    the kernel's lane cap; on the device the fetch rides the reduced-fetch
+    protocol (a happy window — every commit valid, the steady state —
+    transfers 8 bytes per batch). Subsequent finish() calls are pure host
+    work (per-commit error isolation stays with the caller). All-BLS
+    commits are left to finish(): one aggregate check each."""
     from cometbft_tpu import sched
 
     with trace.span("commit.prefetch", cat="node", commits=len(staged)):
-        if sched.enabled():
-            _prefetch_via_scheduler(staged, klass or sched.SYNC)
-        else:
-            _prefetch_direct(staged)
-
-
-def _prefetch_direct(staged: list[StagedCommitVerification]) -> None:
-    """prefetch_staged with the scheduler off: the window's ed25519 rows
-    as chunks of one device batch each, all resolved with one fetch."""
-    from cometbft_tpu.ops import ed25519_kernel
-
-    rows = [s for s in staged
-            if s._ed_rows is not None and s._mask is None and not s._passed]
-    pre = [s for s in staged
-           if s.device_thunk is not None and s._mask is None
-           and not s._passed]
-    thunks = [s.device_thunk for s in pre]
-    # chunk the combined batch below the kernel's lane cap (chunks aligned
-    # to commit boundaries; a single commit is bounded by the 10k-validator
-    # cap). All chunks still resolve with the one fetch below.
-    chunk_cap = 1 << (ed25519_kernel.MAX_BUCKET_LOG2 - 1)
-    chunks: list[list[StagedCommitVerification]] = []
-    cur: list[StagedCommitVerification] = []
-    cur_n = 0
-    for s in rows:
-        n = len(s._ed_rows[2])
-        if cur and cur_n + n > chunk_cap:
-            chunks.append(cur)
-            cur, cur_n = [], 0
-        cur.append(s)
-        cur_n += n
-    if cur:
-        chunks.append(cur)
-    n_pre = len(thunks)
-    for chunk in chunks:
-        pubs: list[bytes] = []
-        msgs: list[bytes] = []
-        sigs: list[bytes] = []
-        groups: list[tuple[int, int]] = []
-        for s in chunk:
-            p, m, g = s._ed_rows
-            groups.append((len(sigs), len(sigs) + len(g)))
-            pubs.extend(p)
-            msgs.extend(m)
-            sigs.extend(g)
-        thunks.append(ed25519_kernel.verify_batch_async(
-            pubs, msgs, sigs, recheck_groups=groups))
-    if not thunks:
-        return
-    resolved = ed25519_kernel.resolve_batches(thunks)
-    for chunk, combined in zip(chunks, resolved[n_pre:]):
-        off = 0
-        for s in chunk:
-            n = len(s._ed_rows[2])
-            s._mask = combined[off:off + n]
-            off += n
-    for s, m in zip(pre, resolved[:n_pre]):
-        s._mask = m
-
-
-def _prefetch_via_scheduler(staged: list[StagedCommitVerification],
-                            klass: str) -> None:
-    """Scheduler-side window resolution: every unresolved staged commit
-    (device-staged ed rows AND host-staged cpu rows — the scheduler picks
-    the backend per dispatch, so a CPU-backend window still coalesces)
-    becomes one scheduler group; pre-dispatched device thunks resolve
-    alongside through the kernel fetch path as before."""
-    from cometbft_tpu import sched
-    from cometbft_tpu.ops import ed25519_kernel
-
-    pre = [s for s in staged
-           if s.device_thunk is not None and s._mask is None and not s._passed]
-    todo: list[StagedCommitVerification] = []
-    rowlists: list[list] = []
-    with trace.span("commit.rows", cat="collect"):
-        for s in staged:
-            if s._passed or s._mask is not None or s.device_thunk is not None:
-                continue
-            if getattr(s, "_bls_rows", None) is not None:
-                continue  # aggregate-verified at finish(), one check total
-            if s._ed_rows is not None:
-                from cometbft_tpu.crypto import ed25519 as _ed
-
-                pubs_b, msgs, sigs = s._ed_rows
-                rows = [(_ed.PubKey(p), m, g)
-                        for p, m, g in zip(pubs_b, msgs, sigs)]
-            elif s._cpu_rows is not None:
-                pubs, msgs, sigs = s._cpu_rows
-                rows = list(zip(pubs, msgs, sigs))
-            else:
-                continue
-            todo.append(s)
-            rowlists.append(rows)
-    if rowlists:
-        masks = sched.get().verify_many(rowlists, klass)
-        for s, mask in zip(todo, masks):
-            s._mask = mask
-    if pre:
-        resolved = ed25519_kernel.resolve_batches([s.device_thunk for s in pre])
-        for s, m in zip(pre, resolved):
-            s._mask = m
+        with trace.span("commit.rows", cat="collect"):
+            todo = [s for s in staged
+                    if not (s._passed or s._mask is not None or s._bls_rows)]
+            rowlists = [list(zip(*s._rows)) for s in todo]
+        if rowlists:
+            masks = sched.get().verify_many(rowlists, klass or sched.SYNC)
+            for s, mask in zip(todo, masks):
+                s._mask = mask
 
 
 def resolve_staged(staged: list[StagedCommitVerification]) -> None:

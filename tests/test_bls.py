@@ -312,9 +312,6 @@ def test_bls_disabled_is_loud_not_silent():
     try:
         with pytest.raises(crypto.ErrInvalidKey, match="bls_enabled"):
             crypto_batch.supports_batch_verifier(key)
-        mv = crypto_batch.MixedBatchVerifier()
-        with pytest.raises(crypto.ErrInvalidKey, match="bls_enabled"):
-            mv.add(key, b"m", bytes(96))
         sv = crypto_batch.ScheduledBatchVerifier()
         with pytest.raises(crypto.ErrInvalidKey, match="bls_enabled"):
             sv.add(key, b"m", bytes(96))

@@ -99,7 +99,7 @@ def test_commit_verify_uses_aggregate_and_pinpoints_failures():
     # staged (blocksync/light window) flavor resolves the same way
     staged = stage_verify_commit(
         chain_id, vals, commit.block_id, commit.height, commit)
-    assert staged._bls_rows is not None, "BLS commit must stage aggregate"
+    assert staged._bls_rows, "BLS commit must stage aggregate"
     staged.finish()
     # corrupt one signature: aggregate fails, per-lane pass pinpoints it
     k = bls.gen_priv_key_from_secret(b"intruder")

@@ -36,21 +36,32 @@ SEED = 2_147_489_028  # over 2**31, as the driver's are
 PER_SCHEME = 6
 
 
-@pytest.fixture(scope="module")
-def committee():
+def _committee(validators: dict):
     """(ValsetSpec, ring of CommitSpec, the program's ValidatorSet, the
     program's (block_id, Commit) for each ring entry)."""
     with open(os.path.join(ROOT, "benchmarks", "configs",
                            "committee-10k-mixed.json")) as fh:
         config = json.load(fh)
     assert config["validators"] == {"ed25519": 5120, "sr25519": 5120}
-    config["validators"] = {"ed25519": PER_SCHEME, "sr25519": PER_SCHEME}
+    config["validators"] = validators
     vals_spec, signers = datagen.make_validators(config, SEED)
     ring = datagen.make_ring(config, vals_spec, signers, SEED)
     assert len(ring) == config["ring_heights"] == 2
     vals = program.build_validator_set(vals_spec)
     commits = [program.build_commit(vals, spec) for spec in ring]
     return vals_spec, ring, vals, commits
+
+
+@pytest.fixture(scope="module")
+def committee():
+    return _committee({"ed25519": PER_SCHEME, "sr25519": PER_SCHEME})
+
+
+@pytest.fixture(scope="module")
+def ed25519_committee():
+    """The configuration's ed25519 half alone: the commits a staged solo
+    finish() used to hand to the kernel itself, around the scheduler."""
+    return _committee({"ed25519": PER_SCHEME})
 
 
 @pytest.fixture(scope="module")
@@ -138,6 +149,36 @@ def test_verify_commit_answers_as_the_reference(committee, device_plane,
     assert moved["verify_sched.rows_total"] == 2 * PER_SCHEME
     assert moved["verify_sched.lanes_total"] == 2 * EK.bucket_size(PER_SCHEME)
     assert moved["staging.trip.batches"] == 2
+
+
+@pytest.mark.parametrize("which", ["ed25519_committee", "committee"])
+def test_solo_finish_enters_through_the_scheduler(request, device_plane,
+                                                  which):
+    """stage_verify_commit + finish() with no window prefetch, backend
+    "tpu": one scheduler batch, one device batch a scheme, and a flipped
+    signature named, as through verify_commit."""
+    vals_spec, _ring, vals, commits = request.getfixturevalue(which)
+    schemes = sorted(set(vals_spec.schemes))
+    block_id, commit = commits[0]
+
+    def solo(commit):
+        return lambda: validation.stage_verify_commit(
+            vals_spec.chain_id, vals, block_id, commit.height,
+            commit).finish()
+
+    for lane, want in ((None, "accept"), (3, "reject#3")):
+        before = device_plane.read()
+        got = program.verdict_of(solo(program.fresh(commit, lane)))
+        moved = program.Counters.diff(before, device_plane.read())
+        assert got == want
+        assert moved["verify_sched.batches"] == 1
+        assert moved["verify_sched.rows_total"] == len(vals_spec.schemes)
+        assert moved["staging.trip.batches"] == len(schemes)
+        for scheme in ("ed25519", "sr25519"):
+            assert moved[f"metrics.device_batches.{scheme}"] == (
+                scheme in schemes)
+        assert moved["metrics.mask_oracle_disagreement"] == 0
+        assert moved["metrics.fallback_verifies"] == 0
 
 
 def test_the_committee_is_the_configurations(committee):

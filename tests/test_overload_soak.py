@@ -51,7 +51,6 @@ def test_saturation_soak_graded_liveness():
     verify-flush deadline misses; nonzero mempool sheds (saturation was
     real); p99 inter-height gap bounded vs the unloaded baseline."""
     sched.reset()
-    sched.configure(enabled=True)
 
     async def main():
         cfg = test_consensus_config()
@@ -128,7 +127,6 @@ def test_soak_recheck_storms_are_windowed():
     pressure ladder must bound them into windows (>= 2 with a window
     smaller than the pool) without starving admission to zero."""
     sched.reset()
-    sched.configure(enabled=True)
 
     async def main():
         cfg = test_consensus_config()

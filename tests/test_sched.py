@@ -29,11 +29,10 @@ def _fresh_scheduler():
     """Each case gets a fresh scheduler (and leaves none behind)."""
     sched.reset()
     chaos.reset()
-    sched.configure(enabled=True)
     yield
     chaos.reset()
     sched.reset()
-    sched.configure(enabled=True, max_lanes=16384, sync_deadline=0.002,
+    sched.configure(max_lanes=16384, sync_deadline=0.002,
                     mempool_deadline=0.010, queue_limit=16384,
                     starvation_limit=0.25)
 
@@ -283,15 +282,6 @@ class TestRouting:
         bv2 = crypto_batch.create_mixed_batch_verifier()
         assert type(bv2).__name__ == "ScheduledBatchVerifier"
 
-    def test_disabled_falls_back_to_direct(self):
-        sched.configure(enabled=False)
-        try:
-            bv = crypto_batch.create_batch_verifier(
-                ed25519.gen_priv_key().pub_key())
-            assert type(bv).__name__ != "ScheduledBatchVerifier"
-        finally:
-            sched.configure(enabled=True)
-
     def test_ambient_work_class(self):
         assert sched.current_class() == CONSENSUS
         with sched.work_class(SYNC):
@@ -334,6 +324,195 @@ class TestRouting:
             s.finish()
         assert sched.get().batches == before + 1
         assert sched.get().health()["class_rows"]["sync"] == 12
+
+
+# ------------------------------------------------- one door to the device
+
+
+def _signed_commits(chain_id: str, schemes: list[str], heights: int):
+    """(vals, [(block_id, Commit)]): a validator set with one key a
+    scheme name given (in CometBFT's order, by address) and `heights`
+    full commits it signed."""
+    import hashlib
+
+    from cometbft_tpu.libs.prefixrows import as_bytes
+    from cometbft_tpu.types.basic import BlockID, BlockIDFlag, PartSetHeader
+    from cometbft_tpu.types.commit import Commit, CommitSig
+    from cometbft_tpu.types.validator import Validator, ValidatorSet
+    from cometbft_tpu.utils import cmttime
+
+    mods = {"ed25519": ed25519, "sr25519": sr25519}
+    privs = [mods[k].gen_priv_key_from_secret(b"one-door-%d" % i)
+             for i, k in enumerate(schemes)]
+    vals = ValidatorSet([Validator.new(p.pub_key(), 10) for p in privs])
+    by_addr = {p.pub_key().address(): p for p in privs}
+    privs = [by_addr[v.address] for v in vals.validators]
+    out = []
+    for h in range(1, heights + 1):
+        block_id = BlockID(hash=hashlib.sha256(b"door-%d" % h).digest(),
+                           part_set_header=PartSetHeader(1, b"\x33" * 32))
+        commit = Commit(height=h, round_=0, block_id=block_id, signatures=[
+            CommitSig(block_id_flag=BlockIDFlag.COMMIT,
+                      validator_address=v.address,
+                      timestamp=cmttime.Timestamp(1_700_000_000 + h, i * 1000))
+            for i, v in enumerate(vals.validators)])
+        rows = commit.vote_sign_bytes_all(chain_id)
+        for i, cs in enumerate(commit.signatures):
+            cs.signature = privs[i].sign(as_bytes(rows.rows_for([i])[0]))
+        out.append((block_id, commit))
+    return vals, out
+
+
+def _flipped(commit, idx: int):
+    """A new Commit object (the sign-bytes cache rides the object) with
+    one signature's first byte flipped."""
+    import dataclasses
+
+    from cometbft_tpu.types.commit import Commit
+
+    sigs = list(commit.signatures)
+    sig = sigs[idx].signature
+    sigs[idx] = dataclasses.replace(
+        sigs[idx], signature=bytes([sig[0] ^ 1]) + sig[1:])
+    return Commit(height=commit.height, round_=commit.round_,
+                  block_id=commit.block_id, signatures=sigs)
+
+
+_KEY_MIXES = {
+    "ed25519": ["ed25519"] * 4,
+    "sr25519": ["sr25519"] * 4,
+    "interleaved": ["ed25519", "sr25519"] * 3,
+}
+_DOOR_CHAIN = "one-door"
+_DOOR_BAD = 1  # inside the rows even the 1/3-trusting path verifies
+
+
+@pytest.fixture(scope="module")
+def door_commits():
+    """key mix -> (vals, three signed commits); signing is the slow part
+    (sr25519 in Python integers), so once a module."""
+    made = {mix: _signed_commits(_DOOR_CHAIN, schemes, 3)
+            for mix, schemes in _KEY_MIXES.items()}
+    kinds = [v.pub_key.type_() for v in made["interleaved"][0].validators]
+    assert kinds != sorted(kinds) and kinds != sorted(kinds, reverse=True)
+    return made
+
+
+def _door_entries():
+    """name -> (commits the entry takes, call(vals, [(block_id, commit)]));
+    a window's second commit carries the flipped signature."""
+    from cometbft_tpu.types import validation as V
+
+    def stage(vals, pair):
+        block_id, commit = pair
+        return V.stage_verify_commit(
+            _DOOR_CHAIN, vals, block_id, commit.height, commit)
+
+    def window(vals, pairs):
+        staged = [stage(vals, p) for p in pairs]
+        V.prefetch_staged(staged, klass="sync")
+        first_exc = None
+        for s in staged:
+            try:
+                s.finish()  # per-commit error isolation stays with the caller
+            except V.ErrInvalidCommitSignature as exc:
+                first_exc = first_exc or exc
+        if first_exc is not None:
+            raise first_exc
+
+    return {
+        "verify_commit": (1, lambda vals, ps: V.verify_commit(
+            _DOOR_CHAIN, vals, ps[0][0], ps[0][1].height, ps[0][1])),
+        "verify_commit_light": (1, lambda vals, ps: V.verify_commit_light(
+            _DOOR_CHAIN, vals, ps[0][0], ps[0][1].height, ps[0][1])),
+        "verify_commit_light_trusting": (
+            1, lambda vals, ps: V.verify_commit_light_trusting(
+                _DOOR_CHAIN, vals, ps[0][1], V.Fraction(1, 3))),
+        "stage_then_solo_finish": (
+            1, lambda vals, ps: stage(vals, ps[0]).finish()),
+        "window_of_three": (3, window),
+        "resolve_staged": (2, lambda vals, ps: V.resolve_staged(
+            [stage(vals, p) for p in ps])),
+    }
+
+
+class TestOneDoor:
+    def test_old_scheduler_key_in_config_toml_is_ignored(self, tmp_path):
+        """A node whose config.toml still carries the removed
+        `crypto.scheduler` switch starts as before, on the scheduler."""
+        from cometbft_tpu.config import Config
+
+        cfg = Config(home=str(tmp_path))
+        cfg.crypto.backend = "cpu"
+        path = cfg.save()
+        with open(path) as fh:
+            text = fh.read()
+        assert "\nscheduler =" not in text
+        with open(path, "w") as fh:
+            fh.write(text.replace("[crypto]\n", "[crypto]\nscheduler = false\n"))
+        loaded = Config.load(str(tmp_path))
+        assert not hasattr(loaded.crypto, "scheduler")
+        assert loaded.crypto.backend == "cpu"
+        crypto_batch.configure(loaded.crypto)
+        bv = crypto_batch.create_batch_verifier(
+            ed25519.gen_priv_key().pub_key())
+        assert isinstance(bv, crypto_batch.ScheduledBatchVerifier)
+
+    @pytest.mark.parametrize("package", [
+        "types", "light", "blocksync", "state", "evidence", "consensus",
+        "mempool", "crypto"])
+    def test_upper_layers_import_no_kernel_module(self, package):
+        """Which kernel serves a batch is the scheduler's decision alone:
+        nothing above it reaches the kernel drivers."""
+        import ast
+        from pathlib import Path
+
+        import cometbft_tpu
+
+        banned = {"cometbft_tpu.ops." + m for m in (
+            "ed25519_kernel", "sr25519_kernel", "batch_verifier")}
+        files = sorted((Path(cometbft_tpu.__file__).parent / package)
+                       .rglob("*.py"))
+        assert files
+        found = []
+        for path in files:
+            for node in ast.walk(ast.parse(path.read_text())):
+                if isinstance(node, ast.Import):
+                    names = [a.name for a in node.names]
+                elif isinstance(node, ast.ImportFrom):
+                    names = [node.module or ""] + [
+                        f"{node.module}.{a.name}" for a in node.names]
+                else:
+                    continue
+                found += [f"{path.name}:{node.lineno} {n}" for n in names
+                          if n in banned]
+        assert not found, found
+
+    @pytest.mark.parametrize("mix", list(_KEY_MIXES))
+    @pytest.mark.parametrize("entry", list(_door_entries()))
+    def test_every_entry_is_one_scheduler_batch(self, door_commits, entry,
+                                                mix):
+        """Each call or window into types/validation costs exactly one
+        scheduler batch, passes a clean commit and names a flipped
+        signature, whatever the key mix (cpu backend)."""
+        from cometbft_tpu.types import validation as V
+
+        n, call = _door_entries()[entry]
+        vals, pairs = door_commits[mix]
+        pairs = pairs[:n]
+
+        before = sched.get().batches
+        call(vals, pairs)
+        assert sched.get().batches == before + 1
+
+        bad_at = n // 2  # the middle commit of a window
+        bad = [(bid, _flipped(c, _DOOR_BAD) if k == bad_at else c)
+               for k, (bid, c) in enumerate(pairs)]
+        before = sched.get().batches
+        with pytest.raises(V.ErrInvalidCommitSignature,
+                           match=rf"\(#{_DOOR_BAD}\)"):
+            call(vals, bad)
+        assert sched.get().batches == before + 1
 
 
 # ------------------------------------------------------------- surfaces

@@ -796,7 +796,6 @@ class TestFlushCoverage:
         _arm(capacity=16384, clock=time.thread_time_ns)
         crypto_batch.set_backend("cpu")
         sched.reset()
-        sched.configure(enabled=True)
         try:
             priv = ed25519.gen_priv_key()
             rows = []
@@ -807,7 +806,6 @@ class TestFlushCoverage:
             assert mask.all()
         finally:
             sched.reset()
-            sched.configure(enabled=True)
         spans = trace.snapshot()
         flushes = [r for r in spans if r["name"] == "sched.flush"]
         assert flushes, "no sched.flush span recorded"
@@ -839,7 +837,6 @@ class TestTracedNet:
         _arm(capacity=65536, slow_ms=-1.0)
         crypto_batch.set_backend("cpu")
         sched.reset()
-        sched.configure(enabled=True)
 
         async def run():
             cfg = test_consensus_config()
@@ -856,7 +853,6 @@ class TestTracedNet:
             net = asyncio.run(run())
         finally:
             sched.reset()
-            sched.configure(enabled=True)
         for node in net.nodes:
             assert node.block_store.height() >= 4
 

@@ -32,11 +32,9 @@ def _fresh_tracer_and_scheduler():
     trace.reset()
     crypto_batch.set_backend("cpu")
     sched.reset()
-    sched.configure(enabled=True)
     yield
     trace.reset()
     sched.reset()
-    sched.configure(enabled=True)
 
 
 @pytest.fixture(scope="module")
