@@ -204,14 +204,23 @@ class ScheduledBatchVerifier(crypto.BatchVerifier):
         from cometbft_tpu import sched
 
         self._klass = klass or sched.current_class()
+        # rows added one at a time, or one libs/rowblock.RowBlock added
+        # whole (add_block): never both
         self._rows: list[tuple[crypto.PubKey, bytes, bytes]] = []
+        self._block = None
 
-    def add(self, pub_key: crypto.PubKey, msg: bytes, sig: bytes) -> None:
-        kt = pub_key.type_()
+    @staticmethod
+    def _check_key_type(kt: str) -> None:
         _check_bls_enabled(kt)
         if kt not in _REGISTRY:
             raise crypto.ErrInvalidKey(
                 f"key type {kt!r} has no batch verifier")
+
+    def add(self, pub_key: crypto.PubKey, msg: bytes, sig: bytes) -> None:
+        if self._block is not None:
+            raise RuntimeError("add() after add_block()")
+        kt = pub_key.type_()
+        self._check_key_type(kt)
         if len(sig) != self.SIGNATURE_SIZES.get(kt, 64):
             raise crypto.ErrInvalidSignature("bad signature length")
         # shared-prefix rows (libs/prefixrows.py) ride to the scheduler
@@ -221,18 +230,39 @@ class ScheduledBatchVerifier(crypto.BatchVerifier):
             msg if isinstance(msg, PrefixedMsg) else bytes(msg),
             bytes(sig)))
 
+    def add_block(self, block) -> None:
+        """add() for a whole RowBlock (a commit's rows): the same
+        structural rules once a key type, not once a row; raises what
+        add() would have raised at the first row that breaks one. The
+        block goes to the scheduler as it is."""
+        if self._rows or self._block is not None:
+            raise RuntimeError("add_block() on a verifier that has rows")
+        for kt, (_lanes, cols) in block.parts.items():
+            self._check_key_type(kt)
+            if cols.sig_widths() - {self.SIGNATURE_SIZES.get(kt, 64)}:
+                raise crypto.ErrInvalidSignature("bad signature length")
+        self._block = block
+
     def verify(self) -> tuple[bool, list[bool]]:
-        if not self._rows:
-            return True, []
+        """(all valid, the per-row verdicts): a list for rows that were
+        add()ed, the scheduler's (N,) bool array for a block."""
         from cometbft_tpu import sched
 
+        if self._block is not None:
+            if not len(self._block):
+                return True, []
+            mask = sched.get().verify_now(self._block, self._klass)
+            with trace.span("commit.verdict", cat="collect"):
+                return bool(mask.all()), mask
+        if not self._rows:
+            return True, []
         mask = sched.get().verify_now(self._rows, self._klass)
         with trace.span("commit.verdict", cat="collect"):
             out = [bool(x) for x in mask]
             return all(out), out
 
     def count(self) -> int:
-        return len(self._rows)
+        return len(self._rows) if self._block is None else len(self._block)
 
 
 def create_mixed_batch_verifier() -> crypto.BatchVerifier:

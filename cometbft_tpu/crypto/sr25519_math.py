@@ -678,6 +678,40 @@ def batch_challenge_words_rows(pubs: list[bytes], r_rows, msgs: list[bytes]):
     return out
 
 
+def batch_challenge_words_block(pub_rows, r_rows, msgs):
+    """batch_challenge_words_rows over columns: the keys as their (N, 32)
+    uint8 matrix and the messages as a libs/prefixrows.MsgBlock, one
+    message matrix a message length (MsgBlock.matrix) and no row cut out
+    for the rows that batch; the same words, row for row."""
+    import numpy as np
+
+    from cometbft_tpu.ops import hashvec
+
+    n = len(msgs)
+    out = np.zeros((n, 8), dtype=np.uint32)
+    if n == 0:
+        return out
+    lens = msgs.lengths()
+    loose = []
+    for mlen in np.unique(lens).tolist():
+        sel = np.flatnonzero(lens == mlen)
+        if (len(sel) < hashvec.VEC_MIN_ROWS
+                or os.environ.get("CBFT_HASHVEC") == "serial"):
+            loose.append(sel)
+            continue
+        digests = _batch_challenge_digests(
+            np.ascontiguousarray(pub_rows[sel]),
+            np.ascontiguousarray(r_rows[sel]),
+            msgs.take(sel).matrix(mlen))
+        out[sel] = hashvec.reduce512_mod_l(digests)
+    if loose:
+        sel = np.concatenate(loose)
+        out[sel] = batch_challenge_words_rows(
+            [pub_rows[i].tobytes() for i in sel],
+            np.ascontiguousarray(r_rows[sel]), msgs.take(sel).tolist())
+    return out
+
+
 def batch_compute_challenges(
     pubs: list[bytes], r_list: list[bytes], msgs: list[bytes]
 ) -> list[int]:

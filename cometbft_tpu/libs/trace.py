@@ -97,6 +97,22 @@ STAGES = ("node", "signbytes", "collect", "queue", "stage", "transfer",
 # how many rows it made (`rows`); a cache hit or the serial loop says neither.
 SIGN_ROW_PATHS = ("vector", "scalar")
 
+# The ways types/validation._commit_rows turns a commit into rows: a
+# finished `commit.rows` span of its says which ran (`path`: over columns,
+# or a lane at a time) and how many rows it handed over (`rows`); the
+# other `commit.rows` spans (the verifier's add, a window's selection)
+# say neither.
+COMMIT_ROW_PATHS = ("block", "lane")
+
+# span name -> (the attribution key its rows are summed under, its paths)
+_ROW_PATHS = {"commit.sign_bytes": ("sign_rows", SIGN_ROW_PATHS),
+              "commit.rows": ("commit_rows", COMMIT_ROW_PATHS)}
+
+
+def _no_rows_by_path() -> dict:
+    return {key: dict.fromkeys(paths, 0)
+            for key, paths in _ROW_PATHS.values()}
+
 _enabled = False  # module-global fast path: read before anything else
 
 _current: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
@@ -232,7 +248,7 @@ class Tracer:
         self._attr_rows = 0
         self._attr_tx = 0
         self._attr_rx = 0
-        self._sign_rows = dict.fromkeys(SIGN_ROW_PATHS, 0)
+        self._rows_by_path = _no_rows_by_path()
         # collector pauses: the gc hook stamps them here lock-free (it
         # runs wherever an allocation triggers a collection, also inside
         # _finish under self._lock); the next _finish or attribution()
@@ -289,7 +305,7 @@ class Tracer:
             rows = span.attrs.get("sig_rows", 0)
             if not isinstance(rows, int):
                 rows = 0
-            built = _sign_rows_built(span.name, span.attrs)
+            built = _rows_said(span.name, span.attrs)
             # attribution is updated inline with the lock taken BEFORE t1
             # is read: lock acquisition and the dict updates are tracer
             # overhead that must be timed inside the span, not in the
@@ -305,7 +321,7 @@ class Tracer:
                 self._attr_tx += span.bytes_tx
                 self._attr_rx += span.bytes_rx
                 if built:
-                    self._sign_rows[built[0]] += built[1]
+                    self._rows_by_path[built[0]][built[1]] += built[2]
                 if parent is not None and not parent._done:
                     # a counted span covers its full duration at the parent
                     parent._covered += dur
@@ -434,9 +450,9 @@ class Tracer:
             self._fold_gc()
             ns = dict(self._attr_ns)
             rows, tx, rx = self._attr_rows, self._attr_tx, self._attr_rx
-            sign_rows = dict(self._sign_rows)
+            by_path = {k: dict(v) for k, v in self._rows_by_path.items()}
             gen0, gen1, gen2 = self._gc_counts
-        out = _attribution_dict(ns, rows, tx, rx, sign_rows)
+        out = _attribution_dict(ns, rows, tx, rx, by_path)
         out["gc_collections"] = {"gen0": gen0, "gen1": gen1, "gen2": gen2}
         return out
 
@@ -446,7 +462,7 @@ class Tracer:
             self._attr_rows = 0
             self._attr_tx = 0
             self._attr_rx = 0
-            self._sign_rows = dict.fromkeys(SIGN_ROW_PATHS, 0)
+            self._rows_by_path = _no_rows_by_path()
             self._gc_pending.clear()
             self._gc_counts = [0, 0, 0]
 
@@ -687,18 +703,18 @@ def reset_attribution() -> None:
 # --------------------------------------------------------- the model
 
 
-def _sign_rows_built(name: str, attrs: dict) -> Optional[tuple[str, int]]:
-    """(path, rows) of a `commit.sign_bytes` span that built its rows."""
-    if name != "commit.sign_bytes":
-        return None
+def _rows_said(name: str, attrs: dict) -> Optional[tuple[str, str, int]]:
+    """(attribution key, path, rows) of a `commit.sign_bytes` span that
+    built its rows, or of the `commit.rows` span that selected a commit's."""
+    key, paths = _ROW_PATHS.get(name, (None, ()))
     path, rows = attrs.get("path"), attrs.get("rows", 0)
-    if path not in SIGN_ROW_PATHS or not isinstance(rows, int):
+    if path not in paths or not isinstance(rows, int):
         return None
-    return path, rows
+    return key, path, rows
 
 
 def _attribution_dict(ns: dict, rows: int, tx: int, rx: int,
-                      sign_rows: dict) -> dict:
+                      by_path: dict) -> dict:
     total = sum(ns.get(s, 0) for s in STAGES)
     shares = {
         s: (round(ns.get(s, 0) / total, 4) if total else 0.0)
@@ -713,7 +729,7 @@ def _attribution_dict(ns: dict, rows: int, tx: int, rx: int,
         "wire_rx_bytes": rx,
         "bytes_per_sig_tx": round(tx / rows, 2) if rows else None,
         "bytes_per_sig_rx": round(rx / rows, 2) if rows else None,
-        "sign_rows": sign_rows,
+        **by_path,
     }
 
 
@@ -731,7 +747,7 @@ def attribution_of(spans: list[dict]) -> dict:
     order = sorted(spans, key=lambda r: r["t0_ns"] + r["dur_ns"])
     ns = {s: 0 for s in STAGES}
     rows = tx = rx = 0
-    sign_rows = dict.fromkeys(SIGN_ROW_PATHS, 0)
+    by_path = _no_rows_by_path()
     for r in order:
         counted = r["cat"] in STAGES
         cov = covered.get(r["id"], 0)
@@ -741,14 +757,14 @@ def attribution_of(spans: list[dict]) -> dict:
             rows += n if isinstance(n, int) else 0
             tx += r.get("bytes_tx", 0)
             rx += r.get("bytes_rx", 0)
-            built = _sign_rows_built(r["name"], r["attrs"])
+            built = _rows_said(r["name"], r["attrs"])
             if built:
-                sign_rows[built[0]] += built[1]
+                by_path[built[0]][built[1]] += built[2]
         pid = r.get("parent_id")
         if pid is not None and pid in by_id:
             covered[pid] = covered.get(pid, 0) + (
                 r["dur_ns"] if counted else cov)
-    return _attribution_dict(ns, rows, tx, rx, sign_rows)
+    return _attribution_dict(ns, rows, tx, rx, by_path)
 
 
 # ----------------------------------------------------------- exporters

@@ -439,17 +439,20 @@ class PrefixTable:
                          "upload_failures": 0, "syncs": 0}
 
     def ensure(self, prefix: bytes, tail: bytes,
-               protect: set[int] | None = None) -> int | None:
+               protect: set[int] | None = None,
+               lanes: int = 1) -> int | None:
         """Row index for (prefix, tail), inserting (and evicting LRU) as
         needed. None when the content cannot be resident: over CAP, or
-        every evictable row is protected by the in-flight plan."""
+        every evictable row is protected by the in-flight plan. `lanes`
+        says for how many lanes of a batch the caller asks at once (the
+        `hits` counter counts lanes)."""
         if len(prefix) + len(tail) > PREFIX_CAP:
             return None
         key = (bytes(prefix), bytes(tail))
         with self._lock:
             row = self._rows.get(key)
             if row is not None:
-                self.counters["hits"] += 1
+                self.counters["hits"] += lanes
                 self._lru.pop(key, None)
                 self._lru[key] = None  # refresh recency
                 return row
@@ -477,6 +480,7 @@ class PrefixTable:
             self._dirty.add(row)
             self.version += 1
             self.counters["inserts"] += 1
+            self.counters["hits"] += lanes - 1
             return row
 
     def sync(self):
@@ -597,22 +601,19 @@ def plan_batch(msgs, pre_ok, put_key: str = "", device=None) -> Plan | None:
     if not _dispatch.supervisor(SITE).breaker.peek():
         count("plan_breaker_open")
         return None
-    from cometbft_tpu.libs.prefixrows import PrefixedMsg
+    from cometbft_tpu.libs.prefixrows import MsgBlock
 
     pre_ok = np.asarray(pre_ok, dtype=bool)
-    prefixes: list = [None] * n
-    suffixes: list = [None] * n
+    # the messages as columns (libs/prefixrows.MsgBlock: a class of rows a
+    # (prefix, suffix width); a list is turned into one by its lane loop,
+    # a commit's rows arrive so): what follows reads index vectors and
+    # matrices, and walks classes, never lanes
+    msgs = MsgBlock.of(msgs)
+    live = msgs.take(np.flatnonzero(pre_ok)).present()
     combos: dict[tuple[int, int], int] = {}
-    for i, m in enumerate(msgs):
-        if not pre_ok[i]:
-            continue
-        if isinstance(m, PrefixedMsg):
-            p, s = m.prefix, m.suffix
-        else:
-            p, s = b"", bytes(m)
-        prefixes[i] = p
-        suffixes[i] = s
-        combos[(len(p), len(s))] = combos.get((len(p), len(s)), 0) + 1
+    for c, lanes in live:
+        key = (len(msgs.fronts[c]), msgs.bodies[c].shape[1])
+        combos[key] = combos.get(key, 0) + len(lanes)
     if not combos:
         count("plan_no_ok_lanes")
         return None
@@ -624,15 +625,18 @@ def plan_batch(msgs, pre_ok, put_key: str = "", device=None) -> Plan | None:
     if nc < MIN_LANES or nc < MIN_ELIGIBLE_FRAC * n_ok:
         count("plan_low_eligibility")
         return None
-    conf = np.zeros(n, dtype=bool)
-    for i in range(n):
-        conf[i] = (prefixes[i] is not None and len(prefixes[i]) == plen
-                   and len(suffixes[i]) == slen)
+    # the classes of the dominant geometry, and their live lanes
+    conforming = [c for c, _ in live
+                  if (len(msgs.fronts[c]), msgs.bodies[c].shape[1])
+                  == (plen, slen)]
+    conf = pre_ok & np.isin(msgs.cls, conforming)
     cidx = np.flatnonzero(conf)
+    sfx = np.empty((len(cidx), slen), dtype=np.uint8)
+    ccls = msgs.cls[cidx]
+    for c in conforming:
+        at = np.flatnonzero(ccls == c)
+        sfx[at] = msgs.bodies[c][msgs.pos[cidx[at]]]
     if slen:
-        sfx = np.frombuffer(
-            b"".join(suffixes[i] for i in cidx),
-            dtype=np.uint8).reshape(len(cidx), slen)
         # the batch-common trailing run (vote rows: the chain-id trailer
         # after the per-lane timestamp) rides the table row, not the wire
         eqcols = (sfx == sfx[0]).all(axis=0)
@@ -642,7 +646,6 @@ def plan_batch(msgs, pre_ok, put_key: str = "", device=None) -> Plan | None:
                 break
             tlen += 1
     else:
-        sfx = np.zeros((len(cidx), 0), dtype=np.uint8)
         tlen = 0
     tlen = min(tlen, PREFIX_CAP - plen)
     var = slen - tlen
@@ -654,13 +657,15 @@ def plan_batch(msgs, pre_ok, put_key: str = "", device=None) -> Plan | None:
     pids = np.full(n, -1, dtype=np.int32)
     protect: set[int] = set()
     misses = 0
-    for i in cidx:
-        pid = tab.ensure(prefixes[i], tail, protect=protect)
+    for c in conforming:  # in the order of each class's first live lane
+        at = cidx[ccls == c]
+        pid = tab.ensure(msgs.fronts[c], tail, protect=protect,
+                         lanes=len(at))
         if pid is None:
-            misses += 1
+            misses += len(at)
             continue
         protect.add(pid)
-        pids[i] = pid
+        pids[at] = pid
     if misses:
         count("lane_table_miss", misses)
     eligible = pids >= 0

@@ -338,6 +338,12 @@ def reset_fetch_stats() -> None:
             _fetch_stats[k] = 0
 
 
+def _row_bytes(row):
+    """A signature a host oracle reads: the bytes of a matrix's row (rows
+    kept as columns, libs/rowblock.py), anything else as it is."""
+    return row.tobytes() if isinstance(row, np.ndarray) else row
+
+
 def host_oracle_mask(n, pre_ok, ok_a, rows, info) -> np.ndarray:
     """The CPU rung of the verify ladder: the scheme's exact host oracle
     over the batch rows. Counts the lanes as fallback verifies."""
@@ -348,7 +354,7 @@ def host_oracle_mask(n, pre_ok, ok_a, rows, info) -> np.ndarray:
     pubs, msgs, sigs = rows
     with _trace.span("host_oracle", cat="compute", scheme=info[1], rows=n):
         host = np.fromiter(
-            (verify_fn(p, as_bytes(m), s)
+            (verify_fn(p, as_bytes(m), _row_bytes(s))
              for p, m, s in zip(pubs, msgs, sigs)),
             dtype=bool, count=n)
     _count_fallback(info[1], n)
@@ -825,13 +831,22 @@ def _challenge_words(r_rows, pub_rows, msgs, mlens, pre_ok) -> np.ndarray:
     """(N, 8) uint32 packed challenge words k = SHA-512(R||A||M) mod L.
     Uniform-length messages (every commit: sign-bytes share one length)
     hash as ONE (N, 64+mlen) batch call; ragged messages group inside
-    sha512_many. Rows with pre_ok False get k = 0 (their placeholder
-    R/A content is hashed but discarded)."""
-    from cometbft_tpu.libs.prefixrows import as_bytes
+    sha512_many, or, where they come as columns (a prefixrows.MsgBlock),
+    hash as one such call a message length with no row cut out. Rows with
+    pre_ok False get k = 0 (their placeholder R/A content is hashed but
+    discarded)."""
+    from cometbft_tpu.libs.prefixrows import MsgBlock, as_bytes
     from cometbft_tpu.ops import hashvec
 
     n = r_rows.shape[0]
-    if n and (mlens == mlens[0]).all():
+    if isinstance(msgs, MsgBlock) and n and not (mlens == mlens[0]).all():
+        digests = np.empty((n, 64), dtype=np.uint8)
+        for mlen in np.unique(mlens).tolist():
+            at = np.flatnonzero(mlens == mlen)
+            digests[at] = hashvec.sha512_rows(np.concatenate(
+                [r_rows[at], pub_rows[at], msgs.take(at).matrix(mlen)],
+                axis=1))
+    elif n and (mlens == mlens[0]).all():
         # batch-axis reassembly: shared-prefix vote rows broadcast their
         # per-commit prefix once instead of joining N full copies
         msg_rows = hashvec.assemble_prefixed_rows(msgs, int(mlens[0]))
@@ -847,22 +862,39 @@ def _challenge_words(r_rows, pub_rows, msgs, mlens, pre_ok) -> np.ndarray:
     return k_words
 
 
+def _byte_rows(items, width: int) -> tuple[np.ndarray, np.ndarray | None]:
+    """A column of byte strings that should each have `width` bytes:
+    (which do (N,) bool, the (N, width) uint8 matrix if all do, else
+    None). A column that arrives as a matrix already (libs/rowblock.py:
+    a commit's signatures and keys) is returned as it is."""
+    if isinstance(items, np.ndarray):
+        return np.ones(items.shape[0], dtype=bool), items
+    n = len(items)
+    ok_len = np.fromiter(map(len, items), np.int64, n) == width
+    if not ok_len.all():
+        return ok_len, None
+    return ok_len, np.frombuffer(
+        b"".join(items), dtype=np.uint8).reshape(n, width)
+
+
 def _structural_stage(
-    pubs: list[bytes], sigs: list[bytes],
+    pubs: list[bytes], sigs, pub_rows: np.ndarray | None = None,
 ) -> tuple[np.ndarray, list[bytes], np.ndarray, np.ndarray]:
     """The host-side structural checks every staging path shares (lengths,
     s < L — never reach the device), with placeholder substitution for the
     failing rows. Returns (pre_ok, safe_pubs, sig_rows, pub_rows) — the
     row matrices feed challenge computation (host or the device fallback
-    lanes) and the word packing."""
+    lanes) and the word packing. sigs: a list of bytes or the (N, 64)
+    matrix; pub_rows: the keys as their (N, 32) matrix where the caller
+    has it (pubs stays the list the residency lookup reads). Neither
+    matrix is written to."""
     n = len(sigs)
-    ok_len = np.fromiter(map(len, sigs), np.int64, n) == 64
-    ok_len &= np.fromiter(map(len, pubs), np.int64, n) == 32
-    if ok_len.all():
-        sig_rows = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64)
-        pub_rows = np.frombuffer(b"".join(pubs), dtype=np.uint8).reshape(n, 32)
-        safe_pubs = list(pubs)
-    else:  # ragged stragglers: per-row placeholder substitution
+    ok_sig, sig_rows = _byte_rows(sigs, 64)
+    ok_pub, pub_rows = _byte_rows(pubs if pub_rows is None else pub_rows, 32)
+    ok_len = ok_sig & ok_pub
+    safe_pubs = pubs
+    if sig_rows is None or pub_rows is None:
+        # ragged stragglers: per-row placeholder substitution
         sig_rows = np.zeros((n, 64), dtype=np.uint8)
         pub_rows = np.zeros((n, 32), dtype=np.uint8)
         sig_rows[:, :32] = _ID_ROW32
@@ -875,8 +907,7 @@ def _structural_stage(
     pre_ok = ok_len & scalars_lt_l(sig_rows[:, 32:])
     bad = np.flatnonzero(ok_len & ~pre_ok)  # s >= L rows need placeholders
     if bad.size:
-        if not sig_rows.flags.writeable:
-            sig_rows = sig_rows.copy()
+        sig_rows = sig_rows.copy()  # the caller's matrix, or read-only
         sig_rows[bad, :32] = _ID_ROW32
         sig_rows[bad, 32:] = 0
         safe_pubs = [p if pre_ok[i] else _ID_ENC32
@@ -910,10 +941,11 @@ def _pack_host_words(pre_ok, sig_rows, pub_rows, msgs, bucket,
                      out=None) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Host-challenge word packing: SHA-512 challenges plus the r/s/k
     planes, identity-padded to `bucket`."""
+    from cometbft_tpu.libs.prefixrows import msg_lengths
+
     n = sig_rows.shape[0]
-    mlens = np.fromiter(map(len, msgs), np.int64, n)
     k_rows = _challenge_words(
-        sig_rows[:, :32], pub_rows, msgs, mlens, pre_ok)
+        sig_rows[:, :32], pub_rows, msgs, msg_lengths(msgs), pre_ok)
 
     sig_u4 = sig_rows.view("<u4")  # (n, 16): words 0-7 = R, 8-15 = s
     if out is None:
@@ -989,7 +1021,7 @@ def recheck_failed_lanes(mask, eligible, pubs, msgs, sigs,
         return mask
     flipped = []
     for i in bad:
-        if verify_fn(pubs[i], as_bytes(msgs[i]), sigs[i]):
+        if verify_fn(pubs[i], as_bytes(msgs[i]), _row_bytes(sigs[i])):
             mask[i] = True
             flipped.append(int(i))
     if flipped:
@@ -1205,16 +1237,23 @@ def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
 
 def verify_batch_async(
     pubs: list[bytes],
-    msgs: list[bytes],
-    sigs: list[bytes],
+    msgs,
+    sigs,
     cache: PubKeyCache | None = None,
     recheck_groups: list[tuple[int, int]] | None = None,
+    pub_rows: np.ndarray | None = None,
 ):
     """Stage + dispatch without blocking on the device: returns a thunk that
     materializes the (N,) bool mask. Lets callers (blocksync streaming,
     VoteSet flush) overlap host staging of batch N+1 with device compute of
     batch N. recheck_groups: per-commit row boundaries of a coalesced
     window (see apply_recheck).
+
+    The rows as lists of bytes, or as the scheduler has them since PR 31
+    (libs/rowblock.SigColumns): msgs a prefixrows.MsgBlock, sigs the
+    (N, 64) uint8 matrix, pub_rows the (N, 32) key matrix beside the key
+    list. The columns are staged as they are and kept, unread and
+    uncopied, for the host oracle.
 
     Device faults never escape the thunk: dispatch runs under the "device"
     supervisor (transient retry + breaker, ops/dispatch.py), fetches are
@@ -1229,13 +1268,17 @@ def verify_batch_async(
             (oracle.verify_zip215, "ed25519", None), None)
         return empty
     cache = cache or _default_cache
+    from cometbft_tpu.libs.prefixrows import MsgBlock
 
     b = bucket_size(n)
     # sig_rows: THE attribution row-counting site for this batch (one
     # stage span per dispatched batch; everything else is informational)
     with _trace.span("ed25519.stage", cat="stage", sig_rows=n, lanes=b,
                      hash_rung=_staging_rung()):
-        pre_ok, safe_pubs, sig_rows, pub_rows = _structural_stage(pubs, sigs)
+        pre_ok, safe_pubs, sig_rows, pub_rows = _structural_stage(
+            pubs, sigs, pub_rows)
+        # the messages as columns from here on: a list's one lane loop
+        msgs = MsgBlock.of(msgs)
         plan = None
         if _dispatch.device_allowed():
             try:
@@ -1253,7 +1296,7 @@ def verify_batch_async(
 
             block = L.POOL.lease_flat(_challenge.block_words(b, plan.var))
             _pack_device_block(sig_rows, b, plan, block)
-    rows = (safe_pubs, list(msgs), list(sigs))
+    rows = (safe_pubs, msgs, sigs)
     info = (oracle.verify_zip215, "ed25519", recheck_groups)
     sup = _dispatch.supervisor("device")
 
@@ -1311,12 +1354,11 @@ def verify_batch_async(
     if fb_lanes.size:
         with _trace.span("ed25519.challenge", cat="challenge",
                          lanes=int(fb_lanes.size), rung="lane_fallback"):
-            mlens_fb = np.fromiter((len(msgs[i]) for i in fb_lanes),
-                                   np.int64, fb_lanes.size)
+            msgs_fb = msgs.take(fb_lanes)
             k_fb = _challenge_words(
                 np.ascontiguousarray(sig_rows[fb_lanes, :32]),
                 np.ascontiguousarray(pub_rows[fb_lanes]),
-                [msgs[i] for i in fb_lanes], mlens_fb,
+                msgs_fb, msgs_fb.lengths(),
                 np.ones(fb_lanes.size, dtype=bool))
             fb = bucket_size(int(fb_lanes.size))
             # pad by repeating the last real lane: the device scatter is
@@ -1393,9 +1435,9 @@ def verify_batch_async(
             # gathers for itself); the descriptor stream stays home
             with _trace.span("ed25519.challenge", cat="challenge", lanes=b,
                              rung="host_fallback"):
-                mlens = np.fromiter(map(len, msgs), np.int64, n)
                 k_rows = _challenge_words(
-                    sig_rows[:, :32], pub_rows, msgs, mlens, pre_ok)
+                    sig_rows[:, :32], pub_rows, msgs, msgs.lengths(),
+                    pre_ok)
                 words = np.zeros((3, 8, b), dtype=np.uint32)
                 words[:2] = block[:16 * b].reshape(2, 8, b)
                 words[2, :, :n] = k_rows.T

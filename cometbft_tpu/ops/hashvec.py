@@ -357,8 +357,11 @@ def assemble_prefixed_rows(msgs, mlen: int) -> np.ndarray:
     suffixes; plain bytes rows join as before. For a vote flush this
     cuts the host copy from ~122 B/row to ~17 B/row of suffix plus one
     ~105-byte prefix per commit."""
-    from cometbft_tpu.libs.prefixrows import PrefixedMsg
+    from cometbft_tpu.libs.prefixrows import MsgBlock, PrefixedMsg
 
+    if isinstance(msgs, MsgBlock):
+        # rows that are columns already: a front a class, no row walked
+        return msgs.matrix(mlen)
     n = len(msgs)
     out = np.empty((n, mlen), dtype=np.uint8)
     i = 0

@@ -556,6 +556,9 @@ def announce_validator_set(vals) -> None:
             return
         h = vals.hash()
         if h == _last_announced_hash:
+            # another object of the set announced last (a copy of it a
+            # height): stamp this one too, or every call hashes it again
+            vals._wire_announced = True
             return
         by_scheme: dict[str, list[bytes]] = {}
         for v in vals.validators:

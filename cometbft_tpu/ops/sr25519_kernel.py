@@ -175,10 +175,11 @@ _default_cache = SrPubKeyCache()
 
 def stage_rows_sr(
     pubs: list[bytes],
-    msgs: list[bytes],
-    sigs: list[bytes],
+    msgs,
+    sigs,
     bucket: int,
     out: np.ndarray | None = None,
+    pub_rows: np.ndarray | None = None,
 ) -> tuple[np.ndarray, list[bytes], np.ndarray, np.ndarray, np.ndarray]:
     """Host-only sr25519 staging, the scheme's analog of
     ed25519_kernel.stage_batch (the mesh path shards it per chip):
@@ -188,17 +189,25 @@ def stage_rows_sr(
     permutation per duplex boundary), r/s/k packed batch-minor
     (8, bucket) into `out` (a leased StagingPool block) when given.
     Returns (pre_ok, safe_pubs, r_words, s_words, k_words) — no device
-    arrays; pubkey staging is the dispatcher's (per-chip) concern."""
+    arrays; pubkey staging is the dispatcher's (per-chip) concern.
+
+    The rows as lists of bytes, or as columns (libs/rowblock.SigColumns:
+    msgs a prefixrows.MsgBlock, sigs the (N, 64) matrix, pub_rows the
+    (N, 32) key matrix beside the key list): columns are staged as they
+    are, written to nowhere, and no row of them is cut out."""
     n = len(sigs)
+    from cometbft_tpu.libs.prefixrows import MsgBlock
     from cometbft_tpu.ops import ed25519_kernel as EK
 
-    ok_len = np.fromiter(map(len, sigs), np.int64, n) == 64
-    ok_len &= np.fromiter(map(len, pubs), np.int64, n) == 32
-    if ok_len.all():
-        sig_rows = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, 64)
-        safe_pubs = list(pubs)
-    else:  # ragged stragglers: per-row placeholder substitution
+    ok_sig, sig_rows = EK._byte_rows(sigs, 64)
+    ok_pub, pub_rows = EK._byte_rows(
+        pubs if pub_rows is None else pub_rows, 32)
+    ok_len = ok_sig & ok_pub
+    safe_pubs = pubs
+    if sig_rows is None or pub_rows is None:
+        # ragged stragglers: per-row placeholder substitution
         sig_rows = np.zeros((n, 64), dtype=np.uint8)
+        pub_rows = None
         safe_pubs = [_ID_ENC32] * n
         for i in np.flatnonzero(ok_len):
             sig_rows[i] = np.frombuffer(sigs[i], dtype=np.uint8)
@@ -211,22 +220,31 @@ def stage_rows_sr(
     pre_ok = ok_len & marker & EK.scalars_lt_l(s_rows)
     bad = np.flatnonzero(~pre_ok)
     if bad.size:
-        if not sig_rows.flags.writeable:
-            sig_rows = sig_rows.copy()
+        sig_rows = sig_rows.copy()  # the caller's matrix, or read-only
         sig_rows[bad, :32] = 0  # ristretto identity encoding
         s_rows[bad] = 0
         safe_pubs = [p if pre_ok[i] else _ID_ENC32
                      for i, p in enumerate(safe_pubs)]
+        pub_rows = None
     r_rows = sig_rows[:, :32]
-    # Merlin transcripts absorb the exact message bytes: materialize any
-    # shared-prefix factored rows here (the batch STROBE sponge keeps its
-    # own per-mlen transcript-prefix snapshots, so the prefix work is
-    # still shared inside srm)
-    from cometbft_tpu.libs.prefixrows import as_bytes
-
     with _trace.span("sr25519.transcript", cat="signbytes", rows=n):
-        k_rows = srm.batch_challenge_words_rows(
-            safe_pubs, r_rows, [as_bytes(m) for m in msgs])
+        if isinstance(msgs, MsgBlock):
+            # rows that are columns: the transcripts absorb the exact
+            # message bytes as one matrix a message length
+            if pub_rows is None:
+                pub_rows = np.frombuffer(
+                    b"".join(safe_pubs), dtype=np.uint8).reshape(n, 32)
+            k_rows = srm.batch_challenge_words_block(
+                pub_rows, r_rows, msgs)
+        else:
+            # materialize any shared-prefix factored rows here (the
+            # batch STROBE sponge keeps its own per-mlen
+            # transcript-prefix snapshots, so the prefix work is still
+            # shared inside srm)
+            from cometbft_tpu.libs.prefixrows import as_bytes
+
+            k_rows = srm.batch_challenge_words_rows(
+                safe_pubs, r_rows, [as_bytes(m) for m in msgs])
     k_rows[~pre_ok] = 0
 
     if out is None:
@@ -276,15 +294,18 @@ def stage_batch_sr(
 
 def verify_batch_async(
     pubs: list[bytes],
-    msgs: list[bytes],
-    sigs: list[bytes],
+    msgs,
+    sigs,
     cache: SrPubKeyCache | None = None,
+    pub_rows: np.ndarray | None = None,
 ):
     """Stage + dispatch without blocking on the device (mirror of
     ed25519_kernel.verify_batch_async): returns a thunk materializing the
     (N,) bool mask, with .device_parts for the shared single-fetch resolver
     (ed25519_kernel.resolve_batches) — the mixed mega-commit dispatches both
-    schemes' sub-batches and pays ONE device round trip."""
+    schemes' sub-batches and pays ONE device round trip. Rows as lists or
+    as columns, as stage_rows_sr takes them; they are kept as they come
+    for the host oracle."""
     n = len(sigs)
     assert len(pubs) == n and len(msgs) == n
     if n == 0:
@@ -303,7 +324,7 @@ def verify_batch_async(
     # host oracle — right verdicts, wrong rung (found by chip_smoke's
     # rung accounting)
     cache = cache or _default_cache
-    rows = (list(pubs), list(msgs), list(sigs))
+    rows = (pubs, msgs, sigs)
     info = (srm.verify, "sr25519", None)
     sup = D.supervisor("device")
 
@@ -321,7 +342,8 @@ def verify_batch_async(
             with _trace.span("sr25519.stage", cat="stage", sig_rows=n,
                              lanes=b, hash_rung=EK._staging_rung()):
                 stage_counted = True  # span finishes (and counts) even
-                staged = stage_rows_sr(pubs, msgs, sigs, b, out=block)
+                staged = stage_rows_sr(pubs, msgs, sigs, b, out=block,
+                                       pub_rows=pub_rows)
         except Exception as exc:  # noqa: BLE001 - hashvec died in staging
             sup.record_op_failure(exc)
     if staged is None:
@@ -336,7 +358,7 @@ def verify_batch_async(
         with _trace.span("sr25519.host_precheck", cat="stage",
                          sig_rows=0 if stage_counted else n):
             pre_ok = np.fromiter(
-                (len(p) == 32 and srm.parse_signature(s) is not None
+                (len(p) == 32 and srm.parse_signature(EK._row_bytes(s)) is not None
                  for p, s in zip(pubs, sigs)), dtype=bool, count=n)
         return EK.make_host_thunk(n, pre_ok, rows, info)
     pre_ok, safe_pubs, r_np, s_np, k_np = staged

@@ -60,6 +60,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from cometbft_tpu.libs import trace
+from cometbft_tpu.libs.rowblock import RowBlock, SigColumns
 
 # priority classes, highest first (the wire values appear in metrics
 # labels and the crypto_health snapshot — keep in sync with README).
@@ -137,7 +138,9 @@ class _Group:
     architecture dispatched — one device batch per producer call."""
 
     klass: str
-    rows: list  # [(crypto.PubKey, bytes msg, bytes sig)]
+    # [(crypto.PubKey, bytes msg, bytes sig)], or the same rows as one
+    # libs/rowblock.RowBlock (a commit's rows arrive so)
+    rows: "list | RowBlock"
     submitted_at: float
     unit: int = 0
     deadline: float | None = None  # monotonic; None = inline-only
@@ -338,8 +341,9 @@ class VerifyScheduler:
         dispatch, one mask per group."""
         unit = self._next_unit()
         own = [
-            _Group(klass=klass, rows=list(rows), submitted_at=self._clock(),
-                   unit=unit)
+            _Group(klass=klass,
+                   rows=rows if isinstance(rows, RowBlock) else list(rows),
+                   submitted_at=self._clock(), unit=unit)
             for rows in rowlists
         ]
         n_own = sum(len(g.rows) for g in own)
@@ -576,11 +580,11 @@ class VerifyScheduler:
         mid-flush re-shards inside the mesh; only an all-chips-dead mesh
         degrades to the single-chip ladder this method otherwise uses."""
         from cometbft_tpu.crypto import batch as crypto_batch
-        from cometbft_tpu.libs.prefixrows import PrefixedMsg
         from cometbft_tpu.ops import ed25519_kernel
 
-        # scheme -> (pubs, msgs, sigs, bounds, [(group_idx, row_idx)])
-        per: dict[str, dict] = {}
+        # scheme -> (the sub-batch's SigColumns, the recheck groups' bounds
+        # in it, [(group, the group's lanes, from, to)])
+        per: dict[str, tuple] = {}
         # batch preparation is all "stage": backend selection plus the
         # scheme grouping/bounds pass (the span starts before
         # resolve_backend so flush glue stays inside the coverage model)
@@ -591,29 +595,24 @@ class VerifyScheduler:
             klasses = {g.klass for g in groups}
             # the batch's placement class: its highest-priority member
             batch_klass = next(k for k in CLASSES if k in klasses)
+            # the door: a group that came as tuples (riders, vote flushes,
+            # evidence) becomes a block here, by the one lane loop; from
+            # here on rows are columns (libs/rowblock.py) and a scheme's
+            # sub-batch is its columns in every group, end to end
+            members: dict[str, list] = {}
             for gi, g in enumerate(groups):
-                for ri, (pub, msg, sig) in enumerate(g.rows):
-                    scheme = pub.type_()
-                    d = per.setdefault(scheme, {
-                        "pubs": [], "msgs": [], "sigs": [], "where": [],
-                        "bounds": [], "open": None,
-                    })
-                    if d["open"] != gi:
-                        if d["open"] is not None:
-                            d["bounds"].append((d["_b0"], len(d["sigs"])))
-                        d["open"] = gi
-                        d["_b0"] = len(d["sigs"])
-                    d["pubs"].append(pub)
-                    # shared-prefix rows stay FACTORED through the
-                    # scheduler (the kernel staging fast path broadcasts
-                    # each run's prefix once — libs/prefixrows.py)
-                    d["msgs"].append(msg if isinstance(msg, PrefixedMsg)
-                                     else bytes(msg))
-                    d["sigs"].append(bytes(sig))
-                    d["where"].append((gi, ri))
-            for d in per.values():
-                if d["open"] is not None:
-                    d["bounds"].append((d["_b0"], len(d["sigs"])))
+                block = (g.rows if isinstance(g.rows, RowBlock)
+                         else RowBlock.from_tuples(g.rows))
+                for scheme, (lanes, cols) in block.parts.items():
+                    members.setdefault(scheme, []).append((gi, lanes, cols))
+            for scheme, found in members.items():
+                bounds, where, a = [], [], 0
+                for gi, lanes, cols in found:
+                    bounds.append((a, a + len(cols)))
+                    where.append((gi, lanes, a, a + len(cols)))
+                    a += len(cols)
+                per[scheme] = (SigColumns.concat([f[2] for f in found]),
+                               bounds, where)
         thunks: list = []
         thunk_schemes: list[str] = []
         host_masks: dict[str, np.ndarray] = {}
@@ -626,42 +625,41 @@ class VerifyScheduler:
         mesh_thunks: list[tuple[str, object]] = []
         with trace.span("sched.dispatch", cat="compute",
                         schemes=len(per)):
-            for scheme, d in per.items():
+            for scheme, (cols, bounds, _where) in per.items():
                 if mesh is not None and scheme in (
                         "ed25519", "sr25519", "bls12381"):
                     # mesh shards dispatch eagerly inside verify_async;
                     # both schemes' shards are in flight before any join
                     mesh_thunks.append((scheme, mesh.verify_async(
-                        scheme, [p.bytes_() for p in d["pubs"]],
-                        d["msgs"], d["sigs"], klass=batch_klass,
-                        recheck_groups=d["bounds"])))
+                        scheme, cols.pubs, cols.msgs.tolist(),
+                        cols.sig_list(), klass=batch_klass,
+                        recheck_groups=bounds)))
                 elif backend == "tpu" and scheme == "ed25519":
                     thunks.append(ed25519_kernel.verify_batch_async(
-                        [p.bytes_() for p in d["pubs"]], d["msgs"],
-                        d["sigs"], recheck_groups=d["bounds"]))
+                        cols.pubs, cols.msgs, cols.sigs,
+                        recheck_groups=bounds, pub_rows=cols.pub_rows))
                     thunk_schemes.append(scheme)
                 elif backend == "tpu" and scheme == "sr25519":
                     from cometbft_tpu.ops import sr25519_kernel
 
                     thunks.append(sr25519_kernel.verify_batch_async(
-                        [p.bytes_() for p in d["pubs"]], d["msgs"],
-                        d["sigs"]))
+                        cols.pubs, cols.msgs, cols.sigs,
+                        pub_rows=cols.pub_rows))
                     thunk_schemes.append(scheme)
                 elif backend == "tpu" and scheme == "bls12381":
                     from cometbft_tpu.ops import bls_kernel
 
                     thunks.append(bls_kernel.verify_batch_async(
-                        [p.bytes_() for p in d["pubs"]], d["msgs"],
-                        d["sigs"], recheck_groups=d["bounds"]))
+                        cols.pubs, cols.msgs.tolist(), cols.sig_list(),
+                        recheck_groups=bounds))
                     thunk_schemes.append(scheme)
                 else:
                     # sig_rows marks THE counting site for these rows
                     # (rolling attribution row totals; every other span
                     # annotates informational `rows` only)
                     with trace.span("sched.host_verify", cat="compute",
-                                    scheme=scheme,
-                                    sig_rows=len(d["sigs"])):
-                        host_masks[scheme] = self._host_mask(scheme, d)
+                                    scheme=scheme, sig_rows=len(cols)):
+                        host_masks[scheme] = self._host_mask(scheme, cols)
             if thunks:
                 resolved = ed25519_kernel.resolve_batches(thunks)
                 for scheme, mask in zip(thunk_schemes, resolved):
@@ -679,31 +677,30 @@ class VerifyScheduler:
                 raise mesh_err
         with trace.span("sched.slice_masks", cat="resolve"):
             out = [np.zeros(len(g.rows), dtype=bool) for g in groups]
-            for scheme, d in per.items():
+            for scheme, (_cols, _bounds, where) in per.items():
                 mask = host_masks[scheme]
-                for (gi, ri), ok in zip(d["where"], mask):
-                    out[gi][ri] = bool(ok)
+                for gi, lanes, a, b in where:
+                    out[gi][lanes] = mask[a:b]
         return out
 
     @staticmethod
-    def _host_mask(scheme: str, d: dict) -> np.ndarray:
+    def _host_mask(scheme: str, cols: SigColumns) -> np.ndarray:
         """CPU rung for one scheme's rows: the registry batch verifier
         when the scheme has one, else a serial host loop (an unbatchable
         key type — secp256k1 — must still verify, not crash the batch).
         A structurally-bad row fails alone instead of raising."""
         from cometbft_tpu.crypto import batch as crypto_batch
-        from cometbft_tpu.libs.prefixrows import as_bytes
 
-        n = len(d["sigs"])
+        n = len(cols)
+        rows = list(zip(cols.keys, cols.msgs.tolist(), cols.sig_list()))
         factory = crypto_batch._REGISTRY.get(scheme)
         if factory is not None:
             bv = factory()
             staged: list[int] = []
             mask = np.zeros(n, dtype=bool)
-            for i in range(n):
+            for i, row in enumerate(rows):
                 try:
-                    bv.add(d["pubs"][i], as_bytes(d["msgs"][i]),
-                           d["sigs"][i])
+                    bv.add(*row)
                     staged.append(i)
                 except Exception:  # noqa: BLE001 - structural reject
                     pass
@@ -713,10 +710,9 @@ class VerifyScheduler:
                     mask[i] = bool(ok)
             return mask
         mask = np.zeros(n, dtype=bool)
-        for i in range(n):
+        for i, (key, msg, sig) in enumerate(rows):
             try:
-                mask[i] = bool(d["pubs"][i].verify_signature(
-                    as_bytes(d["msgs"][i]), d["sigs"][i]))
+                mask[i] = bool(key.verify_signature(msg, sig))
             except Exception:  # noqa: BLE001
                 mask[i] = False
         return mask

@@ -29,6 +29,17 @@ MAX_COMMIT_SIG_BYTES = 109
 # the readings from 1 to 10,240 rows).
 VECTOR_SIGN_ROWS_MIN = 36
 
+# Rows from which types/validation._commit_rows selects, tallies and cuts a
+# commit's rows with index vectors (the block path) and no longer walks
+# its signatures: between the last size at which the lane path still won
+# on the chip's host and the first at which the block path did, with one
+# key type (150 rows: 162.6 us a call against 166.2; 200: 193.0 against
+# 189.1; with two key types the block path wins from 150 on; PERF.md
+# section 6, PR 31, has tools/row_block_crossover.py's table from 16 to
+# 10,240 rows). Never below VECTOR_SIGN_ROWS_MIN: the block path takes the
+# columns the array pass made.
+ROW_BLOCK_MIN = 192
+
 _TS_TAG = 5 << 3 | 2  # CanonicalVote field 5, wire 2: the timestamp message
 
 
@@ -251,8 +262,9 @@ class Commit:
 # SharedPrefixRows takes: the prefix of the COMMIT rows whose timestamp has
 # the commit's modal encoded length (the length varint in front of the
 # body pins the total row length, so an off-length timestamp cannot share
-# it), a suffix `ts_tag | len | timestamp | tail` for each of those rows
-# and None for the others, and the others whole, by index.
+# it), then either a suffix `ts_tag | len | timestamp | tail` for each of
+# those rows, None for the others and the others whole by index (the
+# Writer loop), or all of them as one MsgBlock (the array pass).
 
 
 def _sign_row_parts_scalar(signatures, head_commit: bytes, head_nil: bytes,
@@ -286,11 +298,17 @@ def _sign_row_parts_scalar(signatures, head_commit: bytes, head_nil: bytes,
 
 def _sign_row_parts_vector(signatures, head_commit: bytes, head_nil: bytes,
                            tail: bytes):
-    """The same parts in one array pass: the timestamps of all rows as one
-    ragged matrix (pb.timestamp_rows) set between the constant columns,
-    compacted once and cut into a suffix a row; the rows that cannot share
-    the prefix then take their head in front. OverflowError where a stamp
-    does not fit int64."""
+    """The same rows in one array pass, and left as columns: the
+    timestamps of all rows as one ragged matrix (pb.timestamp_rows) set
+    between the constant columns, then one compaction a CLASS of rows.
+    Rows that are for the block or not alike and whose timestamps are as
+    long have one front (length varint + head) and suffixes of one width:
+    a commit is two or three such classes, class 0 the one that shares
+    the prefix, and no row is cut out as an object (MsgBlock;
+    SharedPrefixRows cuts them for a reader that asks). OverflowError
+    where a stamp does not fit int64."""
+    from cometbft_tpu.libs.prefixrows import MsgBlock
+
     n = len(signatures)
     seconds = np.array([cs.timestamp.seconds for cs in signatures],
                        dtype=np.int64)
@@ -308,9 +326,6 @@ def _sign_row_parts_vector(signatures, head_commit: bytes, head_nil: bytes,
     cells[:, ts_end:] = np.frombuffer(tail, dtype=np.uint8)
     keep = np.ones(cells.shape, dtype=bool)
     keep[:, 2:ts_end] = ts_keep
-    flat = cells[keep].tobytes()
-    ends = np.cumsum(ts_len + (2 + len(tail))).tolist()
-    suffixes: list = [flat[a:b] for a, b in zip([0] + ends, ends)]
 
     commit_lens = ts_len[for_block]
     modal_ts_len = 0
@@ -321,18 +336,27 @@ def _sign_row_parts_vector(signatures, head_commit: bytes, head_nil: bytes,
             top = commit_lens[np.isin(commit_lens, top)]
         modal_ts_len = int(top[0])
     prefix = _shared_prefix(head_commit, modal_ts_len, tail)
-    exceptions: dict[int, bytes] = {}
-    fronts: dict = {}  # (for the block?, suffix length) -> length + head
-    others = np.flatnonzero(~(for_block & (ts_len == modal_ts_len)))
-    for i, commits in zip(others.tolist(), for_block[others].tolist()):
-        suffix, suffixes[i] = suffixes[i], None
-        front = fronts.get((commits, len(suffix)))
-        if front is None:
-            head = head_commit if commits else head_nil
-            front = fronts[commits, len(suffix)] = (
-                pb.encode_uvarint(len(head) + len(suffix)) + head)
-        exceptions[i] = front + suffix
-    return prefix, suffixes, exceptions
+
+    # a row's class key: (timestamp length, for the block?)
+    keys = ts_len.astype(np.intp) * 2 + for_block
+    shared = 2 * modal_ts_len + 1
+    fronts, bodies = [prefix], [None]
+    cls = np.zeros(n, dtype=np.intp)
+    pos = np.empty(n, dtype=np.intp)
+    for key in [shared] + [k for k in np.unique(keys).tolist()
+                           if k != shared]:
+        members = np.flatnonzero(keys == key)
+        width = 2 + key // 2 + len(tail)
+        body = cells[members][keep[members]].reshape(len(members), width)
+        pos[members] = np.arange(len(members))
+        if key == shared:
+            bodies[0] = body
+            continue
+        head = head_commit if key % 2 else head_nil
+        cls[members] = len(fronts)
+        fronts.append(pb.encode_uvarint(len(head) + width) + head)
+        bodies.append(body)
+    return prefix, None, None, MsgBlock(fronts, bodies, cls, pos)
 
 
 def _shared_prefix(head_commit: bytes, modal_ts_len: int,

@@ -18,12 +18,15 @@ Semantics preserved exactly:
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable
+from itertools import compress, islice
+
+import numpy as np
 
 from cometbft_tpu.crypto import batch as crypto_batch
 from cometbft_tpu.libs import trace
+from cometbft_tpu.libs.rowblock import RowBlock
 from cometbft_tpu.types.basic import BlockID, BlockIDFlag
-from cometbft_tpu.types.commit import Commit, CommitSig
+from cometbft_tpu.types.commit import ROW_BLOCK_MIN, Commit, CommitSig
 from cometbft_tpu.types.validator import ValidatorSet
 
 BATCH_VERIFY_THRESHOLD = 2  # types/validation.go:13
@@ -82,26 +85,41 @@ def _should_batch_verify(vals: ValidatorSet, commit: Commit) -> bool:
     )
 
 
+def _ignored(cs: CommitSig, commit_only: bool) -> bool:
+    """Which signatures a check leaves out: all but the COMMIT ones
+    (verify_commit_light and the trusting check, which tally every row
+    they take), or the ABSENT ones alone (verify_commit, which checks NIL
+    votes too and tallies the COMMIT ones). _select_block applies the
+    same two tests to the whole flag vector."""
+    if commit_only:
+        return cs.block_id_flag != BlockIDFlag.COMMIT
+    return cs.block_id_flag == BlockIDFlag.ABSENT
+
+
 def _commit_rows(
     chain_id: str,
     vals: ValidatorSet,
     commit: Commit,
     voting_power_needed: int,
-    ignore_sig: Callable[[CommitSig], bool],
-    count_sig: Callable[[CommitSig], bool],
+    commit_only: bool,
     count_all_signatures: bool,
     lookup_by_index: bool,
-) -> tuple[list, list[bytes], list[bytes], list[int]]:
+) -> tuple[RowBlock, "np.ndarray | list[int]"]:
     """The shared row-builder behind every batched commit verification
     (types/validation.go:153-257 loop body): select signatures, tally power,
-    enforce the threshold. Returns (pubkeys, sign_bytes, sigs, commit_idxs);
-    raises ErrNotEnoughVotingPowerSigned below threshold."""
-    with trace.span("commit.rows", cat="collect"):
-        seen_vals: dict[int, int] = {}
-        pubs: list = []
-        sigs: list[bytes] = []
-        idxs: list[int] = []
-        tallied = 0
+    enforce the threshold. Returns (the rows as a libs/rowblock.RowBlock,
+    their indices in the commit); raises ErrNotEnoughVotingPowerSigned
+    below threshold.
+
+    One selection, two ways to run it, chosen from what is at hand: a
+    commit of ROW_BLOCK_MIN rows or more whose validators are looked up
+    by index and whose sign-rows the array pass built is selected, tallied
+    and cut with index vectors (_select_block: no object a lane); every
+    other one walks its signatures (_select_lanes: small commits, the
+    trusting check's address lookups, stamps past int64) and turns its
+    lists into the same block with one lane loop. The span says which
+    ran (`path`) and how many rows it handed over (`rows`)."""
+    with trace.span("commit.rows", cat="collect") as sp:
         sign_rows = commit.vote_sign_bytes_all(chain_id)
         # epoch-keyed device residency (reduced-send protocol): announce the
         # active validator set so the kernels' resident key tables pin its
@@ -112,37 +130,102 @@ def _commit_rows(
             _residency.announce_validator_set(vals)
         except Exception:  # noqa: BLE001 - residency is an optimization layer
             pass
-        for idx, cs in enumerate(commit.signatures):
-            if ignore_sig(cs):
-                continue
-            if lookup_by_index:
-                val = vals.validators[idx]
-            else:
-                val_idx, val = vals.get_by_address(cs.validator_address)
-                if val is None:
-                    continue
-                if val_idx in seen_vals:
-                    raise ValueError(
-                        f"double vote from {val.address.hex()} ({seen_vals[val_idx]} and {idx})"
-                    )
-                seen_vals[val_idx] = idx
-            pubs.append(val.pub_key)
-            sigs.append(cs.signature)
-            idxs.append(idx)
-            if count_sig(cs):
-                tallied += val.voting_power
-            if not count_all_signatures and tallied > voting_power_needed:
-                break
-        if tallied <= voting_power_needed:
-            raise ErrNotEnoughVotingPowerSigned(got=tallied, needed=voting_power_needed)
-        # factored (shared-prefix) rows when the builder supports them: the
-        # staging fast path reassembles whole runs with one prefix broadcast
-        # instead of N per-row copies (libs/prefixrows.py)
-        if hasattr(sign_rows, "rows_for"):
-            msgs = sign_rows.rows_for(idxs)
+        if (lookup_by_index and sign_rows.block is not None
+                and len(commit.signatures) >= ROW_BLOCK_MIN):
+            path = "block"
+            block, idxs = _select_block(
+                vals, commit, sign_rows, voting_power_needed, commit_only,
+                count_all_signatures)
         else:
-            msgs = [sign_rows[i] for i in idxs]
-        return pubs, msgs, sigs, idxs
+            path = "lane"
+            block, idxs = _select_lanes(
+                vals, commit, sign_rows, voting_power_needed, commit_only,
+                count_all_signatures, lookup_by_index)
+        sp.set(path=path, rows=len(block))
+        return block, idxs
+
+
+def _select_lanes(vals, commit, sign_rows, voting_power_needed,
+                  commit_only, count_all_signatures, lookup_by_index):
+    """_commit_rows a lane at a time, as the reference's loop has it."""
+    seen_vals: dict[int, int] = {}
+    pubs: list = []
+    sigs: list[bytes] = []
+    idxs: list[int] = []
+    tallied = 0
+    for idx, cs in enumerate(commit.signatures):
+        if _ignored(cs, commit_only):
+            continue
+        if lookup_by_index:
+            val = vals.validators[idx]
+        else:
+            val_idx, val = vals.get_by_address(cs.validator_address)
+            if val is None:
+                continue
+            if val_idx in seen_vals:
+                raise ValueError(
+                    f"double vote from {val.address.hex()} ({seen_vals[val_idx]} and {idx})"
+                )
+            seen_vals[val_idx] = idx
+        pubs.append(val.pub_key)
+        sigs.append(cs.signature)
+        idxs.append(idx)
+        if commit_only or cs.block_id_flag == BlockIDFlag.COMMIT:
+            tallied += val.voting_power
+        if not count_all_signatures and tallied > voting_power_needed:
+            break
+    if tallied <= voting_power_needed:
+        raise ErrNotEnoughVotingPowerSigned(got=tallied, needed=voting_power_needed)
+    # factored (shared-prefix) rows: the staging fast path reassembles
+    # whole runs with one prefix broadcast instead of N per-row copies
+    # (libs/prefixrows.py)
+    return RowBlock.from_rows(pubs, sign_rows.take(idxs), sigs), idxs
+
+
+def _select_block(vals, commit, sign_rows, voting_power_needed,
+                  commit_only, count_all_signatures):
+    """_commit_rows over columns: the flags and the signatures are read
+    from the commit in one pass each (fresh every call: a commit's
+    signatures may be set after its sign-rows were built), the keys, key
+    types and powers come from the set's cached columns, and selection,
+    tally, threshold and the split by key type are index arithmetic. The
+    same rows, the same tally and the same errors as _select_lanes with
+    lookup_by_index."""
+    signatures = commit.signatures
+    n = len(signatures)
+    cols = vals.columns()
+    flags = [cs.block_id_flag for cs in signatures]
+    try:  # the three flags are small: one byte a row is the fastest way in
+        flags = np.frombuffer(bytes(flags), dtype=np.uint8)
+    except ValueError:  # a flag no commit should carry; the tests below
+        flags = np.fromiter(flags, np.int64, n)  # treat it as the loop does
+    if commit_only:
+        taken = flags == BlockIDFlag.COMMIT
+        idxs = np.flatnonzero(taken)
+        power = cols.powers[idxs]
+    else:
+        taken = flags != BlockIDFlag.ABSENT
+        idxs = np.flatnonzero(taken)
+        power = np.where(flags[idxs] == BlockIDFlag.COMMIT,
+                         cols.powers[idxs], 0)
+    if count_all_signatures:
+        tallied = int(power.sum())
+    else:
+        # the loop's `break`: the first row at which the running tally
+        # passes the threshold is the last one taken
+        running = np.cumsum(power)
+        stop = int(np.searchsorted(running, voting_power_needed,
+                                   side="right"))
+        if stop < len(idxs):
+            idxs = idxs[:stop + 1]
+        tallied = int(running[min(stop, len(running) - 1)]) if len(
+            running) else 0
+    if tallied <= voting_power_needed:
+        raise ErrNotEnoughVotingPowerSigned(got=tallied, needed=voting_power_needed)
+    sigs = [cs.signature for cs in signatures]
+    if len(idxs) < n:
+        sigs = list(islice(compress(sigs, taken.tolist()), len(idxs)))
+    return RowBlock.from_set(cols, idxs, sign_rows.block, sigs), idxs
 
 
 def _bls_aggregate_ok(pubs, msgs, sigs) -> bool | None:
@@ -198,14 +281,20 @@ def _bls_aggregate_agg_ok(pubs, msgs, agg_sig) -> bool | None:
         bytes(agg_sig))
 
 
-def _raise_first_bad(commit: Commit, idxs: list[int], mask) -> None:
+def _raise_first_bad(commit: Commit, idxs, mask) -> None:
     with trace.span("commit.verdict", cat="collect"):
-        for i, sig_ok in enumerate(mask):
-            if not sig_ok:
-                idx = idxs[i]
-                raise ErrInvalidCommitSignature(
-                    f"wrong signature (#{idx}): {commit.signatures[idx].signature.hex()}"
-                )
+        bad = np.flatnonzero(~np.asarray(mask, dtype=bool))
+        if len(bad):
+            idx = int(idxs[bad[0]])
+            raise ErrInvalidCommitSignature(
+                f"wrong signature (#{idx}): {commit.signatures[idx].signature.hex()}"
+            )
+
+
+def _bls_only(block: RowBlock) -> bool:
+    """Every row's key is bls12381: the commit may decide with one
+    aggregate check (_bls_aggregate_ok)."""
+    return set(block.parts) == {"bls12381"}
 
 
 def _verify_commit_batch(
@@ -213,27 +302,25 @@ def _verify_commit_batch(
     vals: ValidatorSet,
     commit: Commit,
     voting_power_needed: int,
-    ignore_sig: Callable[[CommitSig], bool],
-    count_sig: Callable[[CommitSig], bool],
+    commit_only: bool,
     count_all_signatures: bool,
     lookup_by_index: bool,
 ) -> None:
     """types/validation.go:153-257."""
-    pubs, msgs, sigs, idxs = _commit_rows(
+    block, idxs = _commit_rows(
         chain_id, vals, commit, voting_power_needed,
-        ignore_sig, count_sig, count_all_signatures, lookup_by_index,
+        commit_only, count_all_signatures, lookup_by_index,
     )
     # all-BLS validator set: one pairing-product check per commit; a
     # failed aggregate falls through to the per-lane path to pinpoint
-    if _bls_aggregate_ok(pubs, msgs, sigs):
+    if _bls_only(block) and _bls_aggregate_ok(*block.lists()):
         return
     # mixed-scheme coalescing: each key type becomes one device sub-batch
     # (BASELINE config 5 mega-commits mix ed25519 + sr25519 validators)
     bv = crypto_batch.create_mixed_batch_verifier()
     try:
         with trace.span("commit.rows", cat="collect"):
-            for pub, msg, sig in zip(pubs, msgs, sigs):
-                bv.add(pub, msg, sig)
+            bv.add_block(block)
     except Exception as e:  # noqa: BLE001 - unbatchable key type in the set
         from cometbft_tpu.libs import log as _log
 
@@ -241,7 +328,7 @@ def _verify_commit_batch(
             "commit verification falling back to serial", reason=str(e))
         return _verify_commit_single(
             chain_id, vals, commit, voting_power_needed,
-            ignore_sig, count_sig, count_all_signatures, lookup_by_index,
+            commit_only, count_all_signatures, lookup_by_index,
         )
     ok, valid_sigs = bv.verify()
     if ok:
@@ -255,8 +342,7 @@ def _verify_commit_single(
     vals: ValidatorSet,
     commit: Commit,
     voting_power_needed: int,
-    ignore_sig: Callable[[CommitSig], bool],
-    count_sig: Callable[[CommitSig], bool],
+    commit_only: bool,
     count_all_signatures: bool,
     lookup_by_index: bool,
 ) -> None:
@@ -267,7 +353,7 @@ def _verify_commit_single(
     # sign-bytes are encoded per index between the host verifications
     with trace.span("commit.sign_bytes", cat="signbytes", serial=True):
         for idx, cs in enumerate(commit.signatures):
-            if ignore_sig(cs):
+            if _ignored(cs, commit_only):
                 continue
             if lookup_by_index:
                 val = vals.validators[idx]
@@ -285,7 +371,7 @@ def _verify_commit_single(
                 raise ErrInvalidCommitSignature(
                     f"wrong signature (#{idx}): {cs.signature.hex()}"
                 )
-            if count_sig(cs):
+            if commit_only or cs.block_id_flag == BlockIDFlag.COMMIT:
                 tallied += val.voting_power
             if not count_all_signatures and tallied > voting_power_needed:
                 return
@@ -298,8 +384,7 @@ def _verify_commit_rows(
     vals: ValidatorSet,
     commit: Commit,
     voting_power_needed: int,
-    ignore_sig: Callable[[CommitSig], bool],
-    count_sig: Callable[[CommitSig], bool],
+    commit_only: bool,
     count_all_signatures: bool,
     lookup_by_index: bool,
 ) -> None:
@@ -307,7 +392,7 @@ def _verify_commit_rows(
     verify = (_verify_commit_batch if _should_batch_verify(vals, commit)
               else _verify_commit_single)
     verify(chain_id, vals, commit, voting_power_needed,
-           ignore_sig, count_sig, count_all_signatures, lookup_by_index)
+           commit_only, count_all_signatures, lookup_by_index)
 
 
 def verify_commit(
@@ -319,8 +404,7 @@ def verify_commit(
         needed = vals.total_voting_power() * 2 // 3
         _verify_commit_rows(
             chain_id, vals, commit, needed,
-            ignore_sig=lambda c: c.block_id_flag == BlockIDFlag.ABSENT,
-            count_sig=lambda c: c.block_id_flag == BlockIDFlag.COMMIT,
+            commit_only=False,
             count_all_signatures=True,
             lookup_by_index=True,
         )
@@ -335,8 +419,7 @@ def verify_commit_light(
         needed = vals.total_voting_power() * 2 // 3
         _verify_commit_rows(
             chain_id, vals, commit, needed,
-            ignore_sig=lambda c: c.block_id_flag != BlockIDFlag.COMMIT,
-            count_sig=lambda c: True,
+            commit_only=True,
             count_all_signatures=False,
             lookup_by_index=True,
         )
@@ -364,8 +447,7 @@ def verify_commit_light_trusting(
         needed = _trusting_needed(vals, commit, trust_level)
         _verify_commit_rows(
             chain_id, vals, commit, needed,
-            ignore_sig=lambda c: c.block_id_flag != BlockIDFlag.COMMIT,
-            count_sig=lambda c: True,
+            commit_only=True,
             count_all_signatures=False,
             lookup_by_index=False,
         )
@@ -386,26 +468,24 @@ def verify_commit_light_trusting(
 
 class StagedCommitVerification:
     """A staged-but-unresolved verify_commit: finish() raises exactly what
-    the sync path would. The rows (PubKey objects, sign-bytes, signatures:
-    one form whatever the scheme or backend) are NOT verified at staging
-    time — prefetch_staged coalesces every staged commit in a window into
-    ONE scheduler batch (one transfer, one kernel dispatch, one
-    device->host fetch on the device backend), which is what makes the
-    blocksync window pipeline device-bound instead of
+    the sync path would. The rows (one libs/rowblock.RowBlock, whatever
+    the scheme, the backend or the path that selected them) are NOT
+    verified at staging time — prefetch_staged coalesces every staged
+    commit in a window into ONE scheduler batch (one transfer, one kernel
+    dispatch, one device->host fetch on the device backend), which is
+    what makes the blocksync window pipeline device-bound instead of
     dispatch-overhead-bound."""
 
-    def __init__(self, commit: Commit, pubs: list, msgs: list,
-                 sigs: list[bytes], sig_idxs: list[int]):
+    def __init__(self, commit: Commit, rows: RowBlock, sig_idxs):
         """The rows as _commit_rows returns them."""
         self.commit = commit
         self.sig_idxs = sig_idxs
-        self._rows = (pubs, msgs, sigs)
+        self._rows = rows
         # every key is bls12381: finish() tries ONE aggregate
         # pairing-product check first (blocksync/light windows decide a
         # BLS commit with it); only a failed aggregate pays the per-lane
         # pinpoint pass
-        self._bls_rows = bool(pubs) and all(
-            p.type_() == "bls12381" for p in pubs)
+        self._bls_rows = _bls_only(rows)
         self._mask = None
         self._passed = False
 
@@ -422,9 +502,8 @@ class StagedCommitVerification:
     def _finish(self, mask) -> None:
         if mask is None:
             mask = self._mask
-        pubs, msgs, sigs = self._rows
         if mask is None and self._bls_rows and _bls_aggregate_ok(
-                pubs, msgs, sigs):
+                *self._rows.lists()):
             self._passed = True
             return
         if mask is None:
@@ -433,16 +512,13 @@ class StagedCommitVerification:
             # scheduler batch
             bv = crypto_batch.create_mixed_batch_verifier()
             try:
-                for p, m, s in zip(pubs, msgs, sigs):
-                    bv.add(p, m, s)
+                bv.add_block(self._rows)
                 _, mask = bv.verify()
             except Exception:  # noqa: BLE001 - unbatchable key type
-                from cometbft_tpu.libs.prefixrows import as_bytes
-
-                # materialize factored rows: schemes outside the
-                # batch registry (secp256k1) take raw bytes only
-                mask = [p.verify_signature(as_bytes(m), s)
-                        for p, m, s in zip(pubs, msgs, sigs)]
+                # schemes outside the batch registry (secp256k1) take
+                # raw bytes only
+                mask = [p.verify_signature(m, s)
+                        for p, m, s in zip(*self._rows.lists())]
         _raise_first_bad(self.commit, self.sig_idxs, mask)
         self._passed = True
 
@@ -459,8 +535,7 @@ def stage_verify_commit(
         needed = vals.total_voting_power() * 2 // 3
         rows = _commit_rows(
             chain_id, vals, commit, needed,
-            ignore_sig=lambda c: c.block_id_flag == BlockIDFlag.ABSENT,
-            count_sig=lambda c: c.block_id_flag == BlockIDFlag.COMMIT,
+            commit_only=False,
             count_all_signatures=True,
             lookup_by_index=True,
         )
@@ -478,8 +553,7 @@ def stage_verify_commit_light(
         needed = vals.total_voting_power() * 2 // 3
         rows = _commit_rows(
             chain_id, vals, commit, needed,
-            ignore_sig=lambda c: c.block_id_flag != BlockIDFlag.COMMIT,
-            count_sig=lambda c: True,
+            commit_only=True,
             count_all_signatures=False,
             lookup_by_index=True,
         )
@@ -496,8 +570,7 @@ def stage_verify_commit_light_trusting(
         needed = _trusting_needed(vals, commit, trust_level)
         rows = _commit_rows(
             chain_id, vals, commit, needed,
-            ignore_sig=lambda c: c.block_id_flag != BlockIDFlag.COMMIT,
-            count_sig=lambda c: True,
+            commit_only=True,
             count_all_signatures=False,
             lookup_by_index=False,
         )
@@ -523,9 +596,9 @@ def prefetch_staged(staged: list[StagedCommitVerification],
         with trace.span("commit.rows", cat="collect"):
             todo = [s for s in staged
                     if not (s._passed or s._mask is not None or s._bls_rows)]
-            rowlists = [list(zip(*s._rows)) for s in todo]
-        if rowlists:
-            masks = sched.get().verify_many(rowlists, klass or sched.SYNC)
+        if todo:
+            masks = sched.get().verify_many(
+                [s._rows for s in todo], klass or sched.SYNC)
             for s, mask in zip(todo, masks):
                 s._mask = mask
 

@@ -13,6 +13,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
 
+import numpy as np
+
 from cometbft_tpu import crypto
 from cometbft_tpu.crypto import merkle
 from cometbft_tpu.utils import protobuf as pb
@@ -153,9 +155,68 @@ def pub_key_from_proto(data: bytes) -> crypto.PubKey:
     raise ValueError("empty/unsupported PublicKey proto")
 
 
+class SetColumns:
+    """A validator set's keys and powers as columns, made once a set and
+    not once a commit (ValidatorSet.columns): what a commit's row block
+    (libs/rowblock.py) selects from with index vectors.
+
+      schemes    the key types in the set, in the order first met
+      code       (N,) the index into `schemes` of every validator
+      keys       (N,) object array of the PubKey objects (the host rung)
+      key_bytes  (N,) object array of their bytes (the residency lookup)
+      key_rows   (N, 32) uint8, the 32-byte keys as a matrix; zero rows
+                 where a key has another size (BLS: 48)
+      key_sizes  (N,) the key sizes
+      powers     (N,) int64 voting powers
+
+    `src` is the list the columns were read from: a set whose list was
+    replaced or changed in length reads them anew."""
+
+    __slots__ = ("src", "n", "schemes", "code", "keys", "key_bytes",
+                 "key_rows", "key_sizes", "powers")
+
+    def __init__(self, validators: list):
+        self.src = validators
+        self.n = n = len(validators)
+        keys = [v.pub_key for v in validators]
+        types = [k.type_() for k in keys]
+        self.schemes = tuple(dict.fromkeys(types))
+        lookup = {t: i for i, t in enumerate(self.schemes)}
+        self.code = np.fromiter((lookup[t] for t in types), np.intp, n)
+        raw = [k.bytes_() for k in keys]
+        self.keys = np.empty(n, dtype=object)
+        self.keys[:] = keys
+        self.key_bytes = np.empty(n, dtype=object)
+        self.key_bytes[:] = raw
+        self.key_sizes = np.fromiter(map(len, raw), np.intp, n)
+        self.key_rows = np.zeros((n, 32), dtype=np.uint8)
+        fits = self.key_sizes == 32
+        if fits.all():
+            self.key_rows[:] = np.frombuffer(
+                b"".join(raw), dtype=np.uint8).reshape(n, 32)
+        else:
+            for i in np.flatnonzero(fits).tolist():
+                self.key_rows[i] = np.frombuffer(raw[i], dtype=np.uint8)
+        self.powers = np.fromiter(
+            (v.voting_power for v in validators), np.int64, n)
+
+    def rebound(self, validators: list) -> "SetColumns":
+        """The same columns for a copy of the set (its list holds copies
+        of the same validators)."""
+        new = SetColumns.__new__(SetColumns)
+        for name in self.__slots__:
+            setattr(new, name, getattr(self, name))
+        new.src = validators
+        return new
+
+
 class ValidatorSet:
     """types/validator_set.go:55-66. Validators sorted by address; proposer
     tracked explicitly and rotated by priority."""
+
+    # the set's keys and powers as columns (columns()); a class default so
+    # that a set made with __new__ (copy, from_proto, the stores) has it
+    _columns: SetColumns | None = None
 
     def __init__(self, validators: list[Validator]):
         self.validators: list[Validator] = sorted(
@@ -180,7 +241,20 @@ class ValidatorSet:
         new.validators = [v.copy() for v in self.validators]
         new.proposer = self.proposer.copy() if self.proposer else None
         new._total_voting_power = self._total_voting_power
+        if self._columns is not None and self._columns.src is self.validators:
+            new._columns = self._columns.rebound(new.validators)
         return new
+
+    def columns(self) -> SetColumns:
+        """Keys, key types and powers as columns, read once from the set
+        and kept until update_with_change_set changes a key or a power
+        (priority moves touch neither; copy() carries them over). Nothing
+        else in the repo writes a Validator's key or power in place."""
+        cols = self._columns
+        if (cols is None or cols.src is not self.validators
+                or cols.n != len(self.validators)):
+            cols = self._columns = SetColumns(self.validators)
+        return cols
 
     def _update_total_voting_power(self) -> None:
         total = 0
@@ -323,6 +397,7 @@ class ValidatorSet:
         if new_total > MAX_TOTAL_VOTING_POWER:
             raise ValueError("total voting power would exceed maximum")
 
+        self._columns = None  # keys and powers change in place from here
         for u in updates:
             existing = by_addr.get(u.address)
             if existing is not None:

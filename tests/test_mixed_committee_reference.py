@@ -115,10 +115,30 @@ def _cases():
             ("both-schemes", 0, (("sr25519", 2), ("ed25519", 3)))]
 
 
+@pytest.fixture(params=["lane", "block"])
+def row_path(request, monkeypatch):
+    """Both ways types/validation._commit_rows selects a commit's rows
+    (PR 31): walking the signatures, as a commit of 12 rows is by itself,
+    and over columns, as the cell's 10,240 rows are, here forced. The
+    kernels get the same batches either way."""
+    from cometbft_tpu.types import commit as commit_mod
+
+    if request.param == "block":
+        monkeypatch.setattr(commit_mod, "VECTOR_SIGN_ROWS_MIN", 0)
+        monkeypatch.setattr(validation, "ROW_BLOCK_MIN", 0)
+    ran = []
+    real = validation._select_block
+    monkeypatch.setattr(validation, "_select_block", lambda *a, **kw: (
+        ran.append(1), real(*a, **kw))[1])
+    yield
+    assert bool(ran) == (request.param == "block")
+
+
 @pytest.mark.parametrize("name,ring_idx,corrupt", _cases(),
                          ids=[c[0] for c in _cases()])
 def test_verify_commit_answers_as_the_reference(committee, device_plane,
-                                                name, ring_idx, corrupt):
+                                                row_path, name, ring_idx,
+                                                corrupt):
     vals_spec, ring, vals, commits = committee
     lanes = [_lanes_of(vals_spec, scheme)[which] for scheme, which in corrupt]
     spec = ring[ring_idx]
@@ -153,7 +173,7 @@ def test_verify_commit_answers_as_the_reference(committee, device_plane,
 
 @pytest.mark.parametrize("which", ["ed25519_committee", "committee"])
 def test_solo_finish_enters_through_the_scheduler(request, device_plane,
-                                                  which):
+                                                  row_path, which):
     """stage_verify_commit + finish() with no window prefetch, backend
     "tpu": one scheduler batch, one device batch a scheme, and a flipped
     signature named, as through verify_commit."""

@@ -292,7 +292,7 @@ class TestAttribution:
         assert set(attr) == {
             "stage_us", "stage_share", "total_us", "rows", "wire_tx_bytes",
             "wire_rx_bytes", "bytes_per_sig_tx", "bytes_per_sig_rx",
-            "sign_rows", "gc_collections", "enabled"}
+            "sign_rows", "commit_rows", "gc_collections", "enabled"}
         assert tuple(attr["stage_us"]) == trace.STAGES
         assert {"node", "signbytes", "collect", "gc"} < set(trace.STAGES)
         us = attr["stage_us"]
