@@ -4,8 +4,11 @@ After the window has closed: every corrupt operation of the window and a
 sample of its clean ones, drawn from the seed, are compared with the plain
 reference's verdict on the same commit (benchmarks/reference/commit_ref.py
 over the benchmark's own records; the lanes are verified by plain worker
-processes and shared between commits). Every number compared is printed
-beside its limit.
+processes and shared between commits). A cell whose operations are not
+VerifyCommit's brings its own `reference_verdicts(cell, sample)` on its
+driver's module, over a plain reference of its own; the sample, the five
+numbers and their limits are the same for every cell. Every number compared
+is printed beside its limit.
 
   verdict_mismatches  compared answers that differ from the reference's 0
   errors              answers of the WHOLE window that are no verdict   0
@@ -65,7 +68,10 @@ def compare(cell, records: list, offchip_batches, host_rescued_lanes,
     The two counters are the program's, differenced over the window."""
     sample = draw_sample(records, int(cell.traffic["check_clean_sample"]),
                          seed)
-    expected, n_lanes = reference_verdicts(cell, sample)
+    # the cell's own reference where its driver's module brings one
+    # (run.seam), else VerifyCommit's
+    expected, n_lanes = getattr(cell.driver, "reference_verdicts",
+                                reference_verdicts)(cell, sample)
     wrong = [(r.k, r.verdict, expected[r.k]) for r in sample
              if r.verdict != expected[r.k]]
     errors = sum(r.verdict.startswith("error:") for r in records)

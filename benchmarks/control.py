@@ -5,6 +5,8 @@ more than 2/3 of the power has signed) in the place of the full ones. That
 breaks the guarantee every configuration states (every non-absent signature
 is checked), so the result has to read `"correct": false`: a corrupt
 signature behind the quorum is accepted where the reference rejects it.
+A cell whose driver module brings `control_entries()` is given those
+(run.seam): the control that breaks ITS configuration's guarantee.
 
     python3 benchmarks/control.py --workload <name> --seed <n> --seconds <s>
 
@@ -30,10 +32,10 @@ def main(argv: list[str]) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     args = ap.parse_args(argv)
-    from benchmarks import program, run
+    from benchmarks import run
 
     result = run.run_cell(ROOT, args.workload, args.seed, args.seconds, False,
-                          entries=program.control_entries())
+                          entries="control_entries")
     print(json.dumps(result), flush=True)
     return 0
 

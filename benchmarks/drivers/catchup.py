@@ -13,14 +13,17 @@ from __future__ import annotations
 import concurrent.futures
 import time
 
-from jax.profiler import TraceAnnotation
-
 from benchmarks import program
 from benchmarks.drivers import Record
 
 
 class Driver:
     def __init__(self, cell, entries: dict):
+        # not at the module's top: run.load_cell imports this module before
+        # the data is made, and nothing of JAX may be imported by then
+        from jax.profiler import TraceAnnotation
+
+        self.annotate = TraceAnnotation
         self.cell = cell
         self.stage_verify_commit = entries["stage_verify_commit"]
         self.prefetch_staged = entries["prefetch_staged"]
@@ -30,7 +33,7 @@ class Driver:
         """Stage window w: [(k, ring_idx, lane, staged | verdict, t0)]."""
         cell = self.cell
         out = []
-        with TraceAnnotation("bench.stage_window"):
+        with self.annotate("bench.stage_window"):
             for k in range(w * self.heights, (w + 1) * self.heights):
                 ring_idx, lane = cell.schedule.op(k)
                 block_id, commit = cell.commits[ring_idx]
@@ -46,7 +49,7 @@ class Driver:
         return out
 
     def _prefetch(self, staged: list) -> None:
-        with TraceAnnotation("bench.prefetch_staged"):
+        with self.annotate("bench.prefetch_staged"):
             self.prefetch_staged(
                 [s[3] for s in staged if not isinstance(s[3], str)],
                 klass="sync")
@@ -71,7 +74,7 @@ class Driver:
                     fetch.result()
                 except Exception as exc:  # noqa: BLE001 - answers say so
                     fetch_error = f"error:{type(exc).__name__}"
-                with TraceAnnotation("bench.finish_window"):
+                with self.annotate("bench.finish_window"):
                     for k, ring_idx, lane, item, t0 in staged:
                         verdict = (item if isinstance(item, str)
                                    else fetch_error

@@ -9,14 +9,17 @@ from __future__ import annotations
 
 import time
 
-from jax.profiler import TraceAnnotation
-
 from benchmarks import program
 from benchmarks.drivers import Record
 
 
 class Driver:
     def __init__(self, cell, entries: dict):
+        # not at the module's top: run.load_cell imports this module before
+        # the data is made, and nothing of JAX may be imported by then
+        from jax.profiler import TraceAnnotation
+
+        self.annotate = TraceAnnotation
         self.cell = cell
         self.verify_commit = entries["verify_commit"]
 
@@ -26,7 +29,7 @@ class Driver:
         block_id, commit = cell.commits[ring_idx]
         commit = program.fresh(commit, lane)
         t0 = time.perf_counter()
-        with TraceAnnotation("bench.verify_commit"):
+        with self.annotate("bench.verify_commit"):
             verdict = program.verdict_of(lambda: self.verify_commit(
                 cell.vals_spec.chain_id, cell.vals, block_id, commit.height,
                 commit))

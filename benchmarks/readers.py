@@ -53,9 +53,10 @@ def rung_deficit(obs, _p):
 
 def attribution(obs, p):
     """Host self time of one stage of libs/trace's attribution, in
-    microseconds a row (traced runs: the program's tracer is on)."""
+    microseconds a row (traced runs: the program's tracer is on). Nothing
+    where the program's tracer has no such stage (a parent's traced run)."""
     att = obs.get("attribution")
-    if not att or not att.get("rows"):
+    if not att or not att.get("rows") or p["stage"] not in att["stage_us"]:
         return None
     return att["stage_us"][p["stage"]] / att["rows"]
 

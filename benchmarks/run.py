@@ -8,10 +8,15 @@ looks the cell up in BENCHMARK.json, loads its configuration
 the mix names (benchmarks/drivers/), makes keys and presigned commits from
 the seed, boots the device plane as a node does, warms the cell's own
 shapes, measures for --seconds, and then compares a sample of the answers
-with the plain reference. --trace 0 reports the cell's end-to-end metrics;
---trace 1 wraps a slice of the window in a profiler trace and reports its
-per-layer metrics (benchmarks/metrics/<name>.json each). The last line of
-stdout is the result; everything else goes before it or to stderr.
+with the plain reference. Where the driver's module brings a function of
+its own for the cell's data, the program's objects, its entries, its
+reference or the signatures an operation verifies (`seam` below), that one
+runs; where it brings none, the functions of this file, program.py and
+check.py do, as for every cell before PR 32. --trace 0 reports the cell's
+end-to-end metrics; --trace 1 wraps a slice of the window in a profiler
+trace and reports its per-layer metrics (benchmarks/metrics/<name>.json
+each). The last line of stdout is the result; everything else goes before
+it or to stderr.
 
 There is no CPU mode, no size option and no environment switch: without a
 TPU the run fails and prints no result. benchmarks/tests/ rehearses the
@@ -25,6 +30,7 @@ import time
 PROCESS_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import importlib  # noqa: E402
@@ -55,6 +61,7 @@ class Cell:
     traffic: dict
     end_to_end: list[str]
     per_layer: list[str]
+    driver: object          # the module benchmarks/drivers/<traffic's driver>
     # filled by set-up
     vals_spec: object = None
     ring: list = None
@@ -82,14 +89,27 @@ def load_cell(root: str, name: str) -> Cell:
     # a per-layer metric without `workloads` is reported by every cell
     # that reports the end-to-end metric it moves
     end_to_end = [m["name"] for m in bench["end_to_end"] if reported(m)]
+    traffic = _read_json(os.path.join(
+        root, "benchmarks", "traffic", work["traffic"] + ".json"))
     return Cell(
         name=name, chips=int(work["chips"]),
         config=_read_json(os.path.join(root, conf["file"])),
-        traffic=_read_json(os.path.join(
-            root, "benchmarks", "traffic", work["traffic"] + ".json")),
-        end_to_end=end_to_end,
+        traffic=traffic, end_to_end=end_to_end,
         per_layer=[m["name"] for m in bench["per_layer"]
-                   if reported(m) and m["moves"] in end_to_end])
+                   if reported(m) and m["moves"] in end_to_end],
+        # imported before the data is made, so the module itself imports
+        # nothing of JAX or of the program (see make_data)
+        driver=importlib.import_module(
+            "benchmarks.drivers." + traffic["driver"]))
+
+
+def seam(cell: Cell, name: str, default):
+    """The cell's own `name` where its driver's module brings one, else
+    `default`: what ran for every cell before there were seams. The five:
+    make_data(cell, seed), build_program_objects(cell), entries() /
+    control_entries(), reference_verdicts(cell, sample) (check.py) and
+    sigs_of(cell, record)."""
+    return getattr(cell.driver, name, default)
 
 
 class TraceSlice:
@@ -137,21 +157,22 @@ class TraceSlice:
 def make_data(cell: Cell, seed: int) -> None:
     """Seeded keys, the ring of presigned commits and the order of the
     window's operations: the benchmark's own data, made before anything
-    of the program or of JAX is imported, and then kept out of Python's
-    collector (gc.freeze): it stays for the whole run, and no node holds
-    it. Everything made after this (the program's modules, its validator
-    set, caches and tables, the Commit objects a peer would hand over,
-    what warm-up leaves behind) stays in the collector's generations, so a
-    full collection inside the window costs what it costs a node."""
+    of the program or of JAX is imported; run_cell then keeps it out of
+    Python's collector (gc.freeze): it stays for the whole run, and no
+    node holds it. Everything made after this (the program's modules, its
+    validator set, caches and tables, the Commit objects a peer would hand
+    over, what warm-up leaves behind) stays in the collector's generations,
+    so a full collection inside the window costs what it costs a node."""
     from benchmarks import datagen
 
+    t0 = time.perf_counter()
     cell.vals_spec, signers = datagen.make_validators(cell.config, seed)
     cell.ring = datagen.make_ring(cell.config, cell.vals_spec, signers, seed)
     cell.schedule = datagen.Schedule(cell.traffic, len(cell.ring),
                                      len(cell.vals_spec.pubs), seed)
-    del signers
-    gc.collect()
-    gc.freeze()
+    say(f"[set-up] {len(cell.vals_spec.pubs)} validators, "
+        f"{len(cell.ring)} presigned commits from seed {seed}: "
+        f"{time.perf_counter() - t0:.1f} s")
 
 
 def build_program_objects(cell: Cell) -> None:
@@ -162,6 +183,21 @@ def build_program_objects(cell: Cell) -> None:
     cell.vals = program.build_validator_set(cell.vals_spec)
     cell.commits = [program.build_commit(cell.vals, spec)
                     for spec in cell.ring]
+
+
+def sigs_of(cell: Cell, _record) -> dict:
+    """{scheme: signatures} that one operation verifies: every validator
+    of the set (a full commit under VerifyCommit). What the rooflines
+    count as the work of the traced slice."""
+    return collections.Counter(cell.vals_spec.schemes)
+
+
+def sum_sigs(cell: Cell, records) -> dict:
+    count = seam(cell, "sigs_of", sigs_of)
+    total = collections.Counter()
+    for record in records:
+        total.update(count(cell, record))
+    return dict(total)
 
 
 def observe_trace(tracer: TraceSlice, obs: dict) -> dict | None:
@@ -175,33 +211,34 @@ def observe_trace(tracer: TraceSlice, obs: dict) -> dict | None:
     tr = reduce.reduce_trace_dir(TRACE_DIR)
     shutil.rmtree(TRACE_DIR, ignore_errors=True)
     (t_a, c_a), (t_b, c_b) = tracer.edges
-    in_slice = sum(t_a <= (r.t_start + r.t_end) / 2 < t_b
-                   for r in obs["records"])
+    in_slice = [r for r in obs["records"]
+                if t_a <= (r.t_start + r.t_end) / 2 < t_b]
     sliced = program.Counters.diff(c_a, c_b)
-    schemes = obs["cell"].vals_spec.schemes
     obs["trace"] = tr
-    obs["slice_sigs"] = {s: in_slice * schemes.count(s) for s in set(schemes)}
+    obs["slice_sigs"] = sum_sigs(obs["cell"], in_slice)
     obs["slice_wire_bytes"] = sum(
         sliced.get(f"staging.wire.{p}.bytes", 0)
         for p in ("indexed", "delta", "full"))
     obs["device"]["busy_s"] = tr["busy_s"]
     obs["device"]["window_s"] = tr["window_s"]
-    say(f"[trace] slice of {tr['window_s']:.3f} s, {in_slice} operations in "
-        f"it; modules: " + json.dumps(tr["modules"], sort_keys=True))
+    say(f"[trace] slice of {tr['window_s']:.3f} s, {len(in_slice)} "
+        f"operations in it; modules: "
+        + json.dumps(tr["modules"], sort_keys=True))
     return {"device_ops": tr["device_ops"], "idle_gaps": tr["idle_gaps"]}
 
 
 def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
-             *, entries: dict | None = None, on_chip: bool = True) -> dict:
-    """The whole of a run but the printing. `entries` puts other callables
-    in the program's place (the control, the tests' planted faults);
-    on_chip=False skips the look for a TPU (the tests' CPU rehearsal)."""
+             *, entries: dict | str = "entries",
+             on_chip: bool = True) -> dict:
+    """The whole of a run but the printing. `entries` names the seam that
+    gives the callables the window drives ("entries": the program's;
+    "control_entries": the control's), or is a dict of other callables in
+    their place (the tests' planted faults); on_chip=False skips the look
+    for a TPU (the tests' CPU rehearsal)."""
     cell = load_cell(root, name)
-    t0 = time.perf_counter()
-    make_data(cell, seed)
-    say(f"[set-up] {len(cell.vals_spec.pubs)} validators, "
-        f"{len(cell.ring)} presigned commits from seed {seed}: "
-        f"{time.perf_counter() - t0:.1f} s")
+    seam(cell, "make_data", make_data)(cell, seed)
+    gc.collect()
+    gc.freeze()
     from benchmarks import check, program, readers
 
     device = program.probe_device(cell.chips) if on_chip else {
@@ -210,10 +247,10 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     say(f"[set-up] device {device}; compile cache {cache_dir}")
     counters = program.Counters()
     try:
-        build_program_objects(cell)
-        driver_mod = importlib.import_module(
-            "benchmarks.drivers." + cell.traffic["driver"])
-        driver = driver_mod.Driver(cell, entries or program.entries())
+        seam(cell, "build_program_objects", build_program_objects)(cell)
+        if isinstance(entries, str):
+            entries = seam(cell, entries, getattr(program, entries))()
+        driver = cell.driver.Driver(cell, entries)
         t0 = time.perf_counter()
         with program.warmup_watchdog():
             warmed = driver.warm()
@@ -242,14 +279,13 @@ def run_cell(root: str, name: str, seed: int, seconds: float, trace: bool,
     finally:
         counters.close()
 
-    sigs_per_op = len(cell.vals_spec.pubs)
     obs = {"cell": cell, "device": device, "records": records,
            "window_s": window_s, "setup_s": setup_s,
            "attribution": attribution,
            "counters": program.Counters.diff(before, after), "trace": None}
-    say(f"[window] {len(records)} operations ({sigs_per_op} signatures "
-        f"each) in {window_s:.3f} s: "
-        f"{len(records) * sigs_per_op / window_s:.0f} signatures/s; "
+    sigs = sum(sum_sigs(cell, records).values())
+    say(f"[window] {len(records)} operations ({sigs} signatures) in "
+        f"{window_s:.3f} s: {sigs / window_s:.0f} signatures/s; "
         f"{sum(r.corrupt_lane is not None for r in records)} corrupt "
         f"operations offered; link model "
         f"{ {k[5:]: v for k, v in after.items() if k.startswith('link.')} }")

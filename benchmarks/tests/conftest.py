@@ -28,41 +28,13 @@ def _write(path, obj):
         json.dump(obj, fh)
 
 
-def with_waiting_cells(bench: dict) -> dict:
-    """BENCHMARK.json as the PR that brings the waiting cells back will
-    leave it (PERF.md section 7): new entries over files that are there,
-    and the cells' names added to the end-to-end metric they report."""
-    bench["configs"].append({
-        "name": "committee-10k-mixed",
-        "file": "benchmarks/configs/committee-10k-mixed.json"})
-    bench["workloads"] += [
-        {"name": "committee-10k-mixed.commit",
-         "config": "committee-10k-mixed",
-         "traffic": "commit-serial", "chips": 1},
-        {"name": "hub-150.catchup", "config": "hub-150",
-         "traffic": "catchup-window8", "chips": 1}]
-    for metric in bench["end_to_end"]:
-        if metric["name"] == "commit_verify_ms":
-            metric["workloads"].append("committee-10k-mixed.commit")
-    bench["end_to_end"].append({
-        "name": "catchup_blocks_per_s", "unit": "blocks/s",
-        "workloads": ["hub-150.catchup"]})
-    bench["per_layer"] += [
-        {"name": "sched_fill_pct.catchup", "unit": "%",
-         "moves": "catchup_blocks_per_s"},
-        {"name": "device_idle_pct.catchup", "unit": "%",
-         "moves": "catchup_blocks_per_s"}]
-    return bench
-
-
 @pytest.fixture(scope="session")
 def tiny_root(tmp_path_factory):
-    """A checkout-shaped directory: the repo's BENCHMARK.json with the
-    waiting cells added, its metrics and traffic files, with configurations
-    cut to 4 (+4) validators and a ring of 24, and every fifth operation
-    corrupt."""
+    """A checkout-shaped directory: the repo's BENCHMARK.json, its metrics
+    and traffic files, with configurations cut to 4 (+4) validators and a
+    ring of 24, and every fifth operation corrupt."""
     root = str(tmp_path_factory.mktemp("root"))
-    bench = with_waiting_cells(_read(os.path.join(ROOT, "BENCHMARK.json")))
+    bench = _read(os.path.join(ROOT, "BENCHMARK.json"))
     shutil.copytree(os.path.join(ROOT, "benchmarks", "metrics"),
                     os.path.join(root, "benchmarks", "metrics"))
     for conf in bench["configs"]:

@@ -26,7 +26,10 @@ def test_benchmark_json_names_only_files_that_exist():
     for metric in bench["end_to_end"] + bench["per_layer"]:
         spec = readers.load_metric(metrics_dir, metric["name"])
         assert spec["unit"] == metric["unit"]
-        assert spec["source"]["kind"] in readers.KINDS
+        # a kind of readers.py, or a reader of the metric's own beside it
+        assert spec["source"]["kind"] in readers.KINDS or os.path.exists(
+            os.path.join(metrics_dir, readers.reader_name(
+                metrics_dir, metric["name"]) + ".py"))
     moved = {m["name"] for m in bench["end_to_end"]}
     cells = [run.load_cell(ROOT, w["name"]) for w in bench["workloads"]]
     for metric in bench["per_layer"]:
@@ -38,21 +41,33 @@ def test_benchmark_json_names_only_files_that_exist():
 
 
 def test_a_later_cell_reports_the_family_readers_without_new_files(tiny_root):
-    """hub-150.catchup, added by entries alone: `sched_fill_pct.catchup`
-    finds the reader file sched_fill_pct.json, and a per-layer metric with
-    no `workloads` goes to every cell that reports what it moves."""
+    """hub-150.catchup came in by entries alone (PR 32):
+    `sched_fill_pct.catchup` finds the reader file sched_fill_pct.json, and
+    a per-layer metric with no `workloads` goes to every cell that reports
+    what it moves."""
+    bench = _read(os.path.join(tiny_root, "BENCHMARK.json"))
     commit = run.load_cell(tiny_root, "hub-150.commit")
     catchup = run.load_cell(tiny_root, "hub-150.catchup")
     mixed = run.load_cell(tiny_root, "committee-10k-mixed.commit")
-    assert commit.per_layer == mixed.per_layer
+    for family, moved in ((".commit", "commit_verify_ms"),
+                          (".catchup", "catchup_blocks_per_s")):
+        every_cell = [m["name"] for m in bench["per_layer"]
+                      if m["moves"] == moved and "workloads" not in m]
+        assert len(every_cell) == 10
+        assert all(name.endswith(family) for name in every_cell)
+        for cell in (commit, mixed, catchup):
+            assert set(every_cell) <= set(cell.per_layer) or (
+                moved not in cell.end_to_end
+                and not set(every_cell) & set(cell.per_layer))
     assert commit.end_to_end == mixed.end_to_end == ["commit_verify_ms",
                                                      "setup_s"]
-    assert catchup.end_to_end == ["setup_s", "catchup_blocks_per_s"]
-    assert catchup.per_layer == ["sched_fill_pct.catchup",
-                                 "device_idle_pct.catchup"]
+    assert catchup.end_to_end == ["catchup_blocks_per_s", "setup_s"]
+    assert all(name.endswith(".catchup") for name in catchup.per_layer)
     metrics_dir = os.path.join(tiny_root, "benchmarks", "metrics")
-    assert readers.reader_name(metrics_dir, "sched_fill_pct.catchup") == (
-        "sched_fill_pct")
+    for name in catchup.per_layer:  # no file of its own: the family's
+        assert not os.path.exists(os.path.join(metrics_dir, name + ".json"))
+        assert readers.reader_name(metrics_dir, name) == name[:-len(
+            ".catchup")]
     assert readers.reader_name(metrics_dir, "setup_s") == "setup_s"
     obs = {"counters": {"verify_sched.rows_total": 1200,
                         "verify_sched.lanes_total": 2048}}
@@ -106,6 +121,12 @@ def test_a_reader_with_nothing_to_read_returns_nothing():
     assert readers.trace_idle(obs, {}) is None
     assert readers.trace_roofline(obs, {"modules": ["x"]}) is None
     assert readers.attribution(obs, {"stage": "stage"}) is None
+    # a stage the program's tracer lacks (a parent's traced run)
+    parent = {"rows": 15000, "stage_us": {"stage": 1.0}}
+    assert readers.attribution(
+        {"attribution": parent}, {"stage": "stage"}) == 1.0 / 15000
+    assert readers.attribution(
+        {"attribution": parent}, {"stage": "signbytes"}) is None
     assert readers.counter_ratio(obs, {"num": ["a"], "den": ["b"]}) is None
 
 

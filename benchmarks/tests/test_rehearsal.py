@@ -7,7 +7,7 @@ what these tests read: they look at the verdict numbers and at
 
 import pytest
 
-from benchmarks import program, run
+from benchmarks import run
 
 CELLS = ["hub-150.commit", "committee-10k-mixed.commit", "hub-150.catchup"]
 
@@ -16,7 +16,7 @@ def _numbers(result):
     return {k: v["value"] for k, v in result["compared"].items()}
 
 
-def _rehearse(root, cell, seed, entries=None, seconds=10.0):
+def _rehearse(root, cell, seed, entries="entries", seconds=10.0):
     return run.run_cell(root, cell, seed, seconds, False, entries=entries,
                         on_chip=False)
 
@@ -37,9 +37,9 @@ def test_verdicts_equal_the_references(tiny_root, device_plane, cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_the_control_is_not_correct(tiny_root, device_plane, cell):
-    """The program's own quorum-only path in the full one's place."""
-    result = _rehearse(tiny_root, cell, seed=11,
-                       entries=program.control_entries())
+    """The program's own quorum-only path in the full one's place, found
+    as control.py finds it."""
+    result = _rehearse(tiny_root, cell, seed=11, entries="control_entries")
     assert not result["correct"]
     assert _numbers(result)["verdict_mismatches"] >= 1
 
