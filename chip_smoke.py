@@ -14,7 +14,10 @@ any rung, so a smoke that only checked verdicts would pass on the CPU.
 `python3 chip_smoke.py --mesh` (four chips; the builder runs it, the driver
 never does) runs ONLY the 10,240-validator commit and the mixed
 5,120+5,120 mega-commit through VerifyMesh over every chip, and the same
-two commits on one chip of the same process as the comparison.
+two commits on one chip of the same process as the comparison. An ed25519
+shard is the one-chip trip on its own chip (device challenge, the Pallas
+program): every one must show as a pallas.ed25519 success; sr25519 shards
+keep the XLA ladder.
 
 The last line of stdout is one JSON object,
 {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}};
@@ -379,7 +382,7 @@ class Accounting:
             },
             "mesh": {k: mesh.get(k) for k in (
                 "active", "devices", "live", "evictions", "readmissions",
-                "redispatched_batches", "fallbacks")} | {
+                "redispatched_batches", "fallbacks", "shard_program")} | {
                 "chips": {i: {"successes": c["successes"],
                               "failures": c["failures"],
                               "shards": (c["shards_total"]
@@ -400,9 +403,11 @@ def check_rungs(snap: dict, *, aligned_ed: int, aligned_sr: int,
     each must show as one pallas.<scheme> success. want_challenge: the
     phase's batches are wide enough that the device-challenge planner
     must have taken some (whatever it took must have been derived on the
-    device either way). mesh_chips > 0 checks the mesh plane instead (its
-    shards run the XLA ladder; pallas successes are then expected to be
-    what aligned_* say, normally 0)."""
+    device either way). mesh_chips > 0 checks the mesh plane instead:
+    aligned_ed is then the phase's ed25519 SHARDS on 128-aligned buckets
+    (each the one-chip trip on its own chip: one pallas.ed25519 success a
+    shard, and the mesh must say its ed25519 shards run Pallas); sr25519
+    shards run the XLA ladder, so aligned_sr is 0 there."""
     bad: list[str] = []
 
     def need(cond: bool, what: str) -> None:
@@ -468,6 +473,11 @@ def check_rungs(snap: dict, *, aligned_ed: int, aligned_sr: int,
     mesh = snap["mesh"]
     if mesh_chips:
         need(mesh.get("active") is True, "mesh not active")
+        if aligned_ed:
+            program = (mesh.get("shard_program") or {}).get("ed25519")
+            need(program == "pallas",
+                 f"the mesh reports ed25519 shards on {program!r}, not on "
+                 "the Pallas program")
         need(mesh.get("devices") == mesh_chips
              and mesh.get("live") == mesh_chips,
              f"mesh devices={mesh.get('devices')} live={mesh.get('live')}, "
@@ -576,15 +586,32 @@ def warm_up(workloads: list, net_validators: int, seed: int) -> float:
     return time.perf_counter() - t0
 
 
-def _aligned(n: int, batches: int, mesh_chips: int) -> int:
-    """How many of a phase's `batches` per-scheme device batches are
-    Pallas's to serve: all of them when the scheme's n rows pad to a
-    128-aligned bucket on the single-chip plane, none otherwise (small
-    buckets and mesh shards run the XLA ladder by design)."""
+def mesh_shard_lanes(n: int) -> list[int]:
+    """The lane counts of the shards the mesh cuts n rows of one scheme
+    into (its own plan over its live chips, under the class a commit's
+    verification runs in)."""
+    from cometbft_tpu import sched
+    from cometbft_tpu.ops import ed25519_kernel as EK
+    from cometbft_tpu.parallel import mesh as verify_mesh
+
+    vm = verify_mesh.get()
+    return [EK.bucket_size(hi - lo) for _chip, lo, hi in vm._plan(
+        n, sched.current_class(), vm.live_chips())] if n else []
+
+
+def _aligned(scheme: str, n: int, batches: int, mesh_chips: int) -> int:
+    """How many of a phase's device batches of one scheme are Pallas's to
+    serve. One chip: each of the `batches` verifies is one batch, Pallas's
+    when the scheme's n rows pad to a 128-aligned bucket. The mesh: every
+    ed25519 SHARD is a batch of the one-chip trip, Pallas's when its own
+    bucket is 128-aligned; sr25519 shards run the XLA ladder by design."""
     from cometbft_tpu.ops import ed25519_kernel as EK
     from cometbft_tpu.ops import pallas_verify as PV
 
-    if not n or mesh_chips or EK.bucket_size(n) % PV.LANES:
+    if mesh_chips:
+        lanes = mesh_shard_lanes(n) if scheme == "ed25519" else []
+        return batches * sum(b % PV.LANES == 0 for b in lanes)
+    if not n or EK.bucket_size(n) % PV.LANES:
         return 0
     return batches
 
@@ -652,9 +679,7 @@ def phase_a(workloads: list, acct: Accounting, warmed: set[int],
                 f"{sorted({b for c in chips for b in c['shard_lanes']})}")
         assert_rungs(
             f"{label} {name}", acct, warmed=warmed, mesh_chips=mesh_chips,
-            # mesh shards stage host-side challenges (K.stage_batch)
-            want_challenge=not mesh_chips,
-            **{f"aligned_{s[:2]}": _aligned(lanes[s], batches,
+            **{f"aligned_{s[:2]}": _aligned(s, lanes[s], batches,
                                             mesh_chips)
                for s in ("ed25519", "sr25519")})
     return readings
@@ -783,16 +808,21 @@ def run_one_chip() -> dict:
 def mesh_phase(workloads: list, acct: Accounting, repeats: int) -> dict:
     """The commits through VerifyMesh over MESH_CHIPS devices (default
     config: mesh active, class_aware)."""
-    say("[mesh] shard program: the XLA ladder (ed25519_kernel."
-        "_verify_kernel_ok / sr25519_kernel._verify_kernel_ok), one "
-        "executable per chip and lane shape — NOT the Pallas kernel and "
-        "not through PallasGate; pallas.* successes of 0 are expected in "
-        "the mesh part")
+    from cometbft_tpu.ops import dispatch
+
+    programs = dispatch.health_snapshot()["mesh"].get("shard_program")
+    say(f"[mesh] shard programs: {programs}: an ed25519 shard is the "
+        "one-chip trip on its own chip (device challenge, the Pallas "
+        "verify program through PallasGate: one pallas.ed25519 success a "
+        "shard); an sr25519 shard the XLA ladder "
+        "(sr25519_kernel._verify_kernel_ok), pallas.sr25519 successes 0")
     set_up = warm_up(workloads, 0, SEED)
-    say(f"[mesh warm-up] {set_up:.1f} s of set-up (every chip instantiates "
-        "its own executable per shard shape); " + acct.cache_report())
-    # mesh shards never enter ed25519_kernel's shape log: nothing warmed
-    return phase_a(workloads, acct, set(), repeats, mesh_chips=MESH_CHIPS,
+    say(f"[mesh warm-up] {set_up:.1f} s of set-up (a program is compiled "
+        "once and loaded on every chip); " + acct.cache_report())
+    # the ed25519 shards' buckets enter ed25519_kernel's shape log
+    warmed = {b for _name, vals, *_ in workloads for b in mesh_shard_lanes(
+        sum(v.pub_key.type_() == "ed25519" for v in vals.validators))}
+    return phase_a(workloads, acct, warmed, repeats, mesh_chips=MESH_CHIPS,
                    label="mesh")
 
 
@@ -810,7 +840,7 @@ def one_chip_comparison(workloads: list, acct: Accounting, repeats: int,
     for name in mesh_readings:
         say(f"[mesh vs one chip] {name}: smoke readings "
             f"{mesh_readings[name]['wall_ms_median']:.3f} ms on "
-            f"{MESH_CHIPS} chips (XLA shards), "
+            f"{MESH_CHIPS} chips, "
             f"{one_readings[name]['wall_ms_median']:.3f} ms on one chip; "
             "verdicts identical (both equal the host oracle lane for lane)")
 

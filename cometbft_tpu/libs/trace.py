@@ -55,6 +55,10 @@ Stage categories (the attribution model), from the entry down:
              fallback rungs of ops/challenge.py)
   compute    device dispatch / host-oracle verification
   fetch      device->host result bytes (reduced-fetch headers, payloads)
+  join       the multi-chip mesh's caller blocked on its shards (`mesh.join`,
+             parallel/mesh.py) until every chip's dispatch closure has
+             handed its programs over; the per-shard fetches inside are
+             `fetch`
   resolve    mask decode, integrity checks, host re-checks, slicing
   gc         a full (generation 2) collection's pause (`gc.full`), child
              of whatever span was open on the thread it stopped
@@ -90,7 +94,7 @@ from typing import Any, Callable, Optional
 # cat ("sched", "consensus", "sync", "mempool", "device", ...) appear in
 # the trace but never in stage shares — they are containers, not stages.
 STAGES = ("node", "signbytes", "collect", "queue", "stage", "transfer",
-          "challenge", "compute", "fetch", "resolve", "gc")
+          "challenge", "compute", "fetch", "join", "resolve", "gc")
 
 # The builders of a commit's sign-rows (types/commit.py): a finished
 # `commit.sign_bytes` span that built rows says which one ran (`path`) and

@@ -483,19 +483,28 @@ class PrefixTable:
             self.counters["hits"] += lanes - 1
             return row
 
+    def _empty(self):
+        """The all-zero table, on this table's device (a mesh chip's
+        replica) or JAX's default one."""
+        import jax
+        import jax.numpy as jnp
+
+        zeros = jnp.zeros((TABLE_ROWS, PREFIX_CAP), dtype=jnp.uint8)
+        if self._device is None:
+            return zeros
+        return jax.device_put(zeros, self._device)
+
     def sync(self):
         """Upload dirty rows (checksummed scatter, one retry) and return
         the device table snapshot, or None when the upload cannot be
-        trusted (rows stay dirty; the batch takes the host path)."""
-        import jax.numpy as jnp
-
+        trusted (rows stay dirty; the batch takes the host path). The
+        scatter runs where its committed argument, the table, lies."""
         with self._lock:
             dirty = sorted(self._dirty)
             if not dirty and self._tab is not None:
                 return self._tab
             if not dirty:  # empty table, first use
-                self._tab = jnp.zeros((TABLE_ROWS, PREFIX_CAP),
-                                      dtype=jnp.uint8)
+                self._tab = self._empty()
                 return self._tab
             db = _pow2(len(dirty))
             idx = np.full(db, dirty[-1], dtype=np.int32)
@@ -503,7 +512,7 @@ class PrefixTable:
             vals = self._host[idx]  # padding repeats the last row: idempotent
             base = self._tab
             if base is None:
-                base = jnp.zeros((TABLE_ROWS, PREFIX_CAP), dtype=jnp.uint8)
+                base = self._empty()
             want = _host_tab_chk(idx, vals)
             fn = _tab_scatter_fn(db)
             from cometbft_tpu.ops import residency as _residency
@@ -524,7 +533,10 @@ class PrefixTable:
         with self._lock:
             return dict(self.counters, rows=len(self._rows),
                         capacity=TABLE_ROWS, version=self.version,
-                        dirty=len(self._dirty))
+                        dirty=len(self._dirty),
+                        # where the snapshot lies (None until synced)
+                        devices=(sorted(str(d) for d in self._tab.devices())
+                                 if self._tab is not None else None))
 
 
 _tables_lock = threading.Lock()
@@ -543,6 +555,13 @@ def table(put_key: str = "", device=None) -> PrefixTable:
 def table_stats() -> dict:
     with _tables_lock:
         return {k or "default": t.stats() for k, t in _tables.items()}
+
+
+def invalidate(put_key: str) -> None:
+    """Forget one replica (a mesh chip readmitted after a fault must not
+    serve a snapshot from before it): the next plan builds it anew."""
+    with _tables_lock:
+        _tables.pop(put_key, None)
 
 
 def reset() -> None:

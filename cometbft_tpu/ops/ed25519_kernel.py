@@ -62,6 +62,14 @@ def _staging_rung() -> str:
 
 _ID_ENC32 = (1).to_bytes(32, "little")  # y=1: the identity point encoding
 
+
+def warmup_rows(b: int) -> tuple[list, list, list]:
+    """(pubs, msgs, sigs) of b identity-point rows for a warm-up batch:
+    pub = the identity encoding, s = 0 — structurally valid, decompress
+    trivially, verify cheap (the scheduler's and the mesh's warm-ups)."""
+    return ([_ID_ENC32] * b, [b"sched-warmup"] * b,
+            [_ID_ENC32 + b"\x00" * 32] * b)
+
 _default_dev_id: int | None = None
 
 
@@ -76,6 +84,28 @@ def default_device_index() -> int:
         except Exception:  # noqa: BLE001 - tracing must never break dispatch
             _default_dev_id = 0
     return _default_dev_id
+
+
+class Target:
+    """Where a batch's trip runs. The default: JAX's first device, the
+    process-wide key and prefix tables, the "device" supervisor, and every
+    device fault resolved on the host oracle inside the thunk. A mesh shard
+    (parallel/mesh.py) aims the same trip at one chip: that chip's device,
+    its replicas of the tables (put_key "devN"), its own supervisor and
+    in-flight gate, its chaos site beside the scheme's, and `strict`:
+    device trouble is raised to the mesh, which redispatches the shard
+    over the surviving chips."""
+
+    __slots__ = ("device", "index", "put_key", "supervisor", "strict")
+
+    def __init__(self, device=None, index: int | None = None,
+                 put_key: str = "", supervisor: str = "device",
+                 strict: bool = False):
+        self.device = device
+        self.index = default_device_index() if index is None else index
+        self.put_key = put_key
+        self.supervisor = supervisor
+        self.strict = strict
 
 
 _POW2_CAP = 2048  # above this, buckets are multiples of _POW2_CAP
@@ -133,8 +163,6 @@ def verify_math_ok(ax, ay, az, at, r_words, s_words, k_words):
     mask = verify_math(ax, ay, az, at, r_words, s_words, k_words)
     return mask, mask.all()
 
-
-_verify_kernel_ok = jax.jit(verify_math_ok)
 
 # Pallas path: the fused-VMEM ladder (pallas_verify.py) is ~2.5x the
 # XLA-compiled program on real TPU (HBM-bound vs VMEM-resident). Enabled
@@ -448,13 +476,16 @@ def reset_shape_log() -> None:
     _dispatched_shapes.clear()
 
 
-@functools.lru_cache(maxsize=None)
-def _verify_programs(hostk: bool):
+@functools.lru_cache(maxsize=8)
+def _verify_programs(hostk: bool, ladder=None):
     """The verify program of a batch's trip as the PallasGate's
     (pallas_fn, xla_fn) couple: the ladder and the integrity header and
     payload in ONE program, -> ((2,) header, (2B+1,) payload), built per
-    bucket. The Pallas one keeps `verify_pallas` in its name (the
-    benchmark's roofline reader finds the module by it).
+    bucket (and instantiated per device the trip is aimed at). The Pallas
+    one keeps `verify_pallas` in its name (the benchmark's roofline reader
+    finds the module by it). `ladder` stands in for the curve math of
+    both rungs, (ax, ay, az, at, rw, sw, kw) -> (mask, allok): the mesh
+    tests' seam (VerifyMesh._scheme_ops()["kernel"]).
 
     hostk=False, after a device derive (challenge.derive_fn), all
     arguments its outputs but the host's checksum:
@@ -480,14 +511,15 @@ def _verify_programs(hostk: bool):
         return jax.jit(fn)
 
     # both ladders un-jitted: no program of the trip nests a jit
-    return (build(PV.verify_pallas_ok_traced, "pallas"),
-            build(verify_math_ok, "xla"))
+    return (build(ladder or PV.verify_pallas_ok_traced, "pallas"),
+            build(ladder or verify_math_ok, "xla"))
 
 
-def _dispatch_verify(hostk: bool, args: tuple, lanes: int):
-    """-> ((2,) header, (2B+1,) payload), both device-resident. Host
-    arrays among args are uploaded by the call, un-awaited."""
-    pallas_fn, xla_fn = _verify_programs(hostk)
+def _dispatch_verify(hostk: bool, args: tuple, lanes: int, ladder=None):
+    """-> ((2,) header, (2B+1,) payload), both device-resident, on the
+    device the committed arrays among args lie on. Host arrays among args
+    are uploaded by the call, un-awaited."""
+    pallas_fn, xla_fn = _verify_programs(hostk, ladder)
     _dispatched_shapes.add(lanes)
     with _dispatch_lock:
         parts = _pallas_gate.run(pallas_fn, xla_fn, args, lanes)
@@ -495,7 +527,8 @@ def _dispatch_verify(hostk: bool, args: tuple, lanes: int):
     return parts
 
 
-def _dispatch_hostk(idx, planes, words, expected, path: str, sigs: int):
+def _dispatch_hostk(idx, planes, words, expected, path: str, sigs: int,
+                    target: Target, ladder=None):
     """The ONE program of a host-challenge batch: the index vector and the
     whole (3, 8, B) r/s/k block go up as its arguments, un-awaited;
     gather, ladder, checksum and integrity run inside it. Nothing waits
@@ -504,8 +537,9 @@ def _dispatch_hostk(idx, planes, words, expected, path: str, sigs: int):
     holds its own reference to a host argument while it reads it."""
     b = words.shape[2]
     with _trace.span("ed25519.dispatch", cat="compute", lanes=b,
-                     device=default_device_index()) as sp:
-        parts = _dispatch_verify(True, (idx, *planes, words, expected), b)
+                     device=target.index) as sp:
+        parts = _dispatch_verify(True, (idx, *planes, words, expected), b,
+                                 ladder)
         nbytes = idx.nbytes + words.nbytes
         sp.add_bytes(tx=nbytes)
     _residency.record_send(path, nbytes, sigs=sigs)
@@ -725,7 +759,7 @@ def _full_key_index(cache: "PubKeyCache", pubs: list[bytes], bucket: int,
 
 
 def _stage_index(cache: "PubKeyCache", pubs: list[bytes],
-                 bucket: int) -> tuple:
+                 bucket: int, put_key: str = "", device=None) -> tuple:
     """Pubkey staging for a batch whose program gathers for itself:
     (ok_a (N,), idx host index vector (bucket,), (tx, ty, tz, tt)
     device coordinate planes to gather from, te the resident
@@ -740,12 +774,16 @@ def _stage_index(cache: "PubKeyCache", pubs: list[bytes],
     tables keep raw key bytes on device; a None te is one of the
     device-challenge degradation rungs (non-resident A).
 
-    Full-key path otherwise (_full_key_index). path="full"."""
-    got = _residency.index(cache, pubs, bucket)
+    Full-key path otherwise (_full_key_index). path="full".
+
+    put_key / device: the chip's own replica of the table where the trip
+    is aimed at one (Target)."""
+    got = _residency.index(cache, pubs, bucket, put_key=put_key,
+                           device=device)
     if got is not None:
         ok_a, idx, dev = got
         return ok_a, idx, dev[:4], dev[4], "indexed"
-    ok_a, idx, dev_u = _full_key_index(cache, pubs, bucket)
+    ok_a, idx, dev_u = _full_key_index(cache, pubs, bucket, put_key, device)
     return ok_a, idx, dev_u, None, "full"
 
 
@@ -1108,7 +1146,7 @@ def _to_host(dev_arr) -> np.ndarray:
 
 def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
                             n, pre_ok, ok_a, rows, info,
-                            expected=0, lease=None):
+                            expected=0, lease=None, strict: bool = False):
     """The shared thunk shape for a supervised device batch (ed25519 and
     sr25519 build their dispatch closure, this builds the rest): dispatch
     runs on the transfer pool under the supervisor; fetches are
@@ -1127,7 +1165,13 @@ def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
     dispatch closure scopes it (acquire before the first program's call,
     release in a finally after the verify dispatch), so an abandoned thunk
     — a caller that takes device_parts() and never resolves, exactly like
-    an unreleased pool block — can never leak a slot and wedge the gate."""
+    an unreleased pool block — can never leak a slot and wedge the gate.
+
+    strict (a mesh shard, Target.strict): a failed dispatch or fetch is
+    raised (DeviceOpFailed / DeviceUnavailable, recorded) instead of
+    resolved on the host oracle: the mesh has other chips to ask first.
+    A payload that fails its integrity checks still retries and then
+    resolves on the host oracle, as everywhere."""
     # wrap_ctx carries the caller's trace context onto the pool thread so
     # the dispatch's transfer/compute spans land inside this batch's tree
     fut = _xfer_pool().submit(_trace.wrap_ctx(sup.run), submit_fn)
@@ -1202,6 +1246,8 @@ def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
             header = _fetch_np(header_dev)
         except (_dispatch.DeviceOpFailed, _dispatch.DeviceUnavailable):
             _release()
+            if strict:
+                raise
             return host_oracle_mask(n, pre_ok, _ok_arr(ok_a), rows, info)
         ok = _ok_arr(ok_a)  # staging completed: the cell is resolved
         verdict = decode_header(header, expected)
@@ -1220,6 +1266,8 @@ def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
             payload = _fetch_np(payload_dev, pure_transfer=True)
         except (_dispatch.DeviceOpFailed, _dispatch.DeviceUnavailable):
             _release()
+            if strict:
+                raise
             return host_oracle_mask(n, pre_ok, ok, rows, info)
         _count_fetch(False, header.nbytes + payload.nbytes)
         try:
@@ -1232,6 +1280,8 @@ def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
     result.device_parts = lambda: (
         _acquire, n, pre_ok, ok_a, rows, info, _redo)
     result.release_staging = _release
+    # where the batch's programs ran (after a result: the dispatch is done)
+    result.placed = lambda: {str(d) for a in _acquire() for d in a.devices()}
     return result
 
 
@@ -1242,6 +1292,8 @@ def verify_batch_async(
     cache: PubKeyCache | None = None,
     recheck_groups: list[tuple[int, int]] | None = None,
     pub_rows: np.ndarray | None = None,
+    target: Target | None = None,
+    ladder=None,
 ):
     """Stage + dispatch without blocking on the device: returns a thunk that
     materializes the (N,) bool mask. Lets callers (blocksync streaming,
@@ -1255,10 +1307,17 @@ def verify_batch_async(
     list. The columns are staged as they are and kept, unread and
     uncopied, for the host oracle.
 
+    target: the chip the trip is aimed at (Target; a mesh shard), by
+    default JAX's first device. Everything the trip keeps on a device is
+    that chip's: its replica of the key table and of the prefix table, the
+    un-awaited upload, both programs, the in-flight gate, the fetch.
+    ladder: see _verify_programs.
+
     Device faults never escape the thunk: dispatch runs under the "device"
     supervisor (transient retry + breaker, ops/dispatch.py), fetches are
     watchdog-bounded, and any failure resolves the batch on the exact host
-    oracle — a hung or dead device costs latency, not a consensus round."""
+    oracle — a hung or dead device costs latency, not a consensus round.
+    Only a strict target's thunk raises them (Target)."""
     n = len(sigs)
     assert len(pubs) == n and len(msgs) == n
     if n == 0:
@@ -1268,23 +1327,30 @@ def verify_batch_async(
             (oracle.verify_zip215, "ed25519", None), None)
         return empty
     cache = cache or _default_cache
+    target = target or Target()
     from cometbft_tpu.libs.prefixrows import MsgBlock
 
     b = bucket_size(n)
+    sup = _dispatch.supervisor(target.supervisor)
+    allowed = sup.breaker.peek()
+    if target.strict and not allowed:
+        raise _dispatch.DeviceUnavailable(sup.name)
     # sig_rows: THE attribution row-counting site for this batch (one
     # stage span per dispatched batch; everything else is informational)
     with _trace.span("ed25519.stage", cat="stage", sig_rows=n, lanes=b,
-                     hash_rung=_staging_rung()):
+                     hash_rung=_staging_rung(), device=target.index):
         pre_ok, safe_pubs, sig_rows, pub_rows = _structural_stage(
             pubs, sigs, pub_rows)
         # the messages as columns from here on: a list's one lane loop
         msgs = MsgBlock.of(msgs)
         plan = None
-        if _dispatch.device_allowed():
+        if allowed:
             try:
                 from cometbft_tpu.ops import challenge as _challenge
 
-                plan = _challenge.plan_batch(msgs, pre_ok)
+                plan = _challenge.plan_batch(
+                    msgs, pre_ok, put_key=target.put_key,
+                    device=target.device)
             except Exception:  # noqa: BLE001 - planning never breaks staging
                 plan = None
         if plan is None:
@@ -1298,20 +1364,25 @@ def verify_batch_async(
             _pack_device_block(sig_rows, b, plan, block)
     rows = (safe_pubs, msgs, sigs)
     info = (oracle.verify_zip215, "ed25519", recheck_groups)
-    sup = _dispatch.supervisor("device")
 
-    if not _dispatch.device_allowed():
+    if not allowed:
         L.POOL.release(block)
         return make_host_thunk(n, pre_ok, rows, info)
     ok_cell = _LateOkA(n)
+    gate = f"dev{target.index}"
+
+    def _fire_dispatch_sites() -> None:
+        from cometbft_tpu.libs import chaos
+
+        chaos.fire("ed25519.dispatch")
+        if target.put_key:  # a mesh chip's own site: one fault domain
+            chaos.fire(f"ed25519.dispatch.{target.put_key}")
 
     if plan is None:
         expected = np.uint32(_host_checksum(r_words, s_words, k_words))
 
         def _transfer_and_dispatch():
-            from cometbft_tpu.libs import chaos
-
-            chaos.fire("ed25519.dispatch")
+            _fire_dispatch_sites()
             # pubkey staging rides the transfer pool too (reduced-send
             # pipeline): the caller thread never waits on the residency
             # lookup (or a delta upload), so host staging of batch N+1
@@ -1319,19 +1390,19 @@ def verify_batch_async(
             # supervisor/breaker exactly like a dispatch failure (the
             # batch lands on the host oracle).
             with _trace.span("ed25519.stage_pubkeys", cat="transfer",
-                             lanes=b):
+                             lanes=b, device=target.index):
                 ok_a, idx, planes, _enc, path = _stage_index(
-                    cache, safe_pubs, b)
+                    cache, safe_pubs, b, target.put_key, target.device)
             ok_cell.value = ok_a
             # in-flight slot, scoped to the verify dispatch (a _redo
             # retry or an abandoned thunk can never leak it): batch N's
             # upload overlaps batch N-1's compute, batch N+1 queues
             # until a slot frees
             with _trace.span("ed25519.slot", cat="queue", lanes=b):
-                rel = _dispatch.doublebuffer(
-                    f"dev{default_device_index()}").acquire()
+                rel = _dispatch.doublebuffer(gate).acquire()
             try:
-                parts = _dispatch_hostk(idx, planes, block, expected, path, n)
+                parts = _dispatch_hostk(idx, planes, block, expected, path,
+                                        n, target, ladder)
             finally:
                 rel()
             _count_device_batch("ed25519", b)
@@ -1342,7 +1413,8 @@ def verify_batch_async(
         # can stage batch i+1 while batch i is on its way.
         return supervised_device_thunk(
             "ed25519", sup, _transfer_and_dispatch, "ed25519.fetch",
-            n, pre_ok, ok_cell, rows, info, expected=expected, lease=block)
+            n, pre_ok, ok_cell, rows, info, expected=expected, lease=block,
+            strict=target.strict)
 
     # ---- device-challenge path: the wire carries R/s + descriptors; k is
     # derived on-chip (ops/challenge.py) with per-lane host fallbacks for
@@ -1373,15 +1445,14 @@ def verify_batch_async(
     expected_cell = _LateExpected(expected_dc)
 
     def _transfer_and_dispatch_dc():
-        from cometbft_tpu.libs import chaos
-
-        chaos.fire("ed25519.dispatch")
-        with _trace.span("ed25519.stage_pubkeys", cat="transfer", lanes=b):
-            ok_a, idx, planes, enc, path = _stage_index(cache, safe_pubs, b)
+        _fire_dispatch_sites()
+        with _trace.span("ed25519.stage_pubkeys", cat="transfer", lanes=b,
+                         device=target.index):
+            ok_a, idx, planes, enc, path = _stage_index(
+                cache, safe_pubs, b, target.put_key, target.device)
         ok_cell.value = ok_a
         with _trace.span("ed25519.slot", cat="queue", lanes=b):
-            rel = _dispatch.doublebuffer(
-                f"dev{default_device_index()}").acquire()
+            rel = _dispatch.doublebuffer(gate).acquire()
         try:
             return _challenge_rungs_and_dispatch(idx, planes, enc, path)
         finally:
@@ -1404,8 +1475,7 @@ def verify_batch_async(
                 # un-awaited (the block stays leased until the batch
                 # resolves)
                 with _trace.span("ed25519.challenge", cat="challenge",
-                                 lanes=b,
-                                 device=default_device_index()) as sp:
+                                 lanes=b, device=target.index) as sp:
                     with _dispatch_lock:
                         out = run(block, idx, *planes, enc, plan.dev_tab,
                                   *fk)
@@ -1444,22 +1514,24 @@ def verify_batch_async(
             expected_cell.value = _host_checksum(words)
             _challenge.count("batch_host_fallback")
             parts = _dispatch_hostk(
-                idx, planes, words, np.uint32(expected_cell.value), path, n)
+                idx, planes, words, np.uint32(expected_cell.value), path, n,
+                target, ladder)
         else:
             expected_cell.value = expected_dc  # a _redo after a fallback
             rw, sw, kw, chk, *a_dev = derived
             with _trace.span("ed25519.dispatch", cat="compute", lanes=b,
-                             device=default_device_index()):
+                             device=target.index):
                 parts = _dispatch_verify(
                     False, (*a_dev, rw, sw, kw, chk,
-                            np.uint32(expected_dc)), b)
+                            np.uint32(expected_dc)), b, ladder)
         _count_device_batch("ed25519", b)
         _residency.count_trip(batches=1)
         return parts
 
     return supervised_device_thunk(
         "ed25519", sup, _transfer_and_dispatch_dc, "ed25519.fetch",
-        n, pre_ok, ok_cell, rows, info, expected=expected_cell, lease=block)
+        n, pre_ok, ok_cell, rows, info, expected=expected_cell, lease=block,
+        strict=target.strict)
 
 
 def resolve_batches(thunks) -> list[np.ndarray]:

@@ -27,6 +27,15 @@ Two layers live here:
                  single-chip TPU->XLA->CPU ladder (ops/ed25519_kernel /
                  ops/sr25519_kernel), which carries its own supervisor
 
+   An ed25519 shard IS the one-chip trip (ed25519_kernel.verify_batch_async)
+   aimed at its chip (ed25519_kernel.Target): a slice of the batch's
+   columns, that chip's replicas of the key table and the prefix table, one
+   un-awaited upload, the derive program and the Pallas verify program on
+   that chip, one blocking wait. Every shard of a batch is staged and
+   dispatched from the caller's thread before the first is fetched.
+   sr25519 and BLS shards keep the path they had (host staging, awaited
+   uploads, the XLA ladder, one pool thread a shard).
+
    Placement is class-aware (the VerifyScheduler passes its batch class):
    consensus batches pin to the least-loaded chip (one dispatch, lowest
    latency — a vote flush must not pay an 8-way scatter/gather), while
@@ -37,11 +46,12 @@ Two layers live here:
    scheme site, so a CBFT_CHAOS schedule can kill or flap exactly one
    fault domain deterministically.
 
-Compile economics: each (chip, bucket) pair compiles its own executable
-(the persistent compilation cache dedupes across processes). Shard
-planning therefore keeps every shard on the shared bucket ladder — the
-compiled-shape count is bounded by ladder-length x mesh-size, not by
-traffic.
+Compile economics: a program is traced and lowered once a process and
+compiled once: the persistent compilation cache keys a one-device program
+without its device (ops/compile_cache.py), so the first chip's compile is
+every other chip's load. Shard planning keeps every shard on the shared
+bucket ladder — the compiled-shape count is bounded by the ladder's
+length, not by traffic or mesh size.
 """
 
 from __future__ import annotations
@@ -78,10 +88,15 @@ MIN_SHARD_ROWS = K.MIN_BUCKET
 PIN_MAX_ROWS = 2048
 
 # spread shards are capped too: every shard stays on the power-of-two
-# end of the bucket ladder, so each chip compiles at most the 9 small
+# end of the bucket ladder, so the mesh compiles at most the 9 small
 # ladder shapes instead of one giant program per mega-commit size —
 # chips take multiple shards round-robin (a 100k-row commit becomes ~49
-# pipelined 2048-lane shards, not 8 one-off 14336-lane executables)
+# pipelined 2048-lane shards, not 8 one-off 14336-lane executables).
+# Read on four v5e chips at 10,240 ed25519 rows (tools/mesh_plan_crossover,
+# PR 33; PERF.md section 6): five shards of 2,048, one chip twice, 39.7 ms
+# a verify_commit; one shard a chip (2,560 rows in a 4,096-lane bucket)
+# 41.7; ten of 1,024 55.0 — a shard costs the caller ~3 ms whatever its
+# size, a lane of padding costs too: full 2,048-lane shards win both ways
 MAX_SHARD_ROWS = 2048
 
 
@@ -190,13 +205,18 @@ class _Chip:
     """One fault domain: a device plus its dedicated supervisor/breaker
     and the load counters placement reads."""
 
-    __slots__ = ("index", "device", "name", "inflight_lanes", "lanes_total",
-                 "shards_total", "shard_lanes", "array_devices")
+    __slots__ = ("index", "device", "name", "target", "inflight_lanes",
+                 "lanes_total", "shards_total", "shard_lanes",
+                 "array_devices")
 
     def __init__(self, index: int, device):
         self.index = index
         self.device = device
         self.name = f"mesh.dev{index}"
+        # what aims the one-chip ed25519 trip at this chip
+        self.target = K.Target(device=device, index=index,
+                               put_key=f"dev{index}", supervisor=self.name,
+                               strict=True)
         self.inflight_lanes = 0
         self.lanes_total = 0
         self.shards_total = 0
@@ -276,19 +296,20 @@ class VerifyMesh:
 
     @staticmethod
     def _scheme_ops(scheme: str) -> dict:
-        # kernels: the *_ok variants are the SAME compiled programs the
-        # single-chip path traces, so a mesh chip's first shard is a
-        # compilation-cache hit, not a fresh per-device compile
         if scheme == "ed25519":
             from cometbft_tpu.crypto import ed25519_math as _oracle
 
             return {
-                "stage": K.stage_batch,
-                "kernel": K._verify_kernel_ok,
-                "cache": lambda: K._default_cache,
+                # a shard rides the one-chip trip, aimed at its chip
+                "trip": K.verify_batch_async,
+                # the curve math of the trip's verify program; None: the
+                # trip's own (Pallas, behind it XLA). The tests' seam.
+                "kernel": None,
                 "verify_fn": _oracle.verify_zip215,
                 "fallback_async": K.verify_batch_async,
             }
+        # sr25519: the *_ok variant is the SAME compiled program the
+        # single-chip path traces
         if scheme == "sr25519":
             from cometbft_tpu.crypto import sr25519_math as _srm
             from cometbft_tpu.ops import sr25519_kernel as SRK
@@ -363,6 +384,9 @@ class VerifyMesh:
                         from cometbft_tpu.ops import residency
 
                         residency.invalidate_device(chip.index)
+                        from cometbft_tpu.ops import challenge
+
+                        challenge.invalidate(f"dev{chip.index}")
                     except Exception:  # noqa: BLE001 - never block healing
                         pass
                     if mm is not None:
@@ -428,22 +452,34 @@ class VerifyMesh:
 
     # ------------------------------------------------------------ dispatch
 
+    def _count_shard(self, chip: _Chip, lanes: int, placed=()) -> None:
+        """A shard has run on `chip`: the load counters placement reads,
+        the lane shapes and devices crypto_health shows, /metrics."""
+        mm = _mesh_metrics()
+        if mm is not None:
+            try:
+                mm.mesh_shard_lanes.labels(str(chip.index)).inc(lanes)
+            except Exception:  # noqa: BLE001
+                pass
+        with self._lock:
+            chip.lanes_total += lanes
+            chip.shards_total += 1
+            chip.shard_lanes.add(lanes)
+            chip.array_devices |= set(placed)
+
     def _shard_op(self, ops: dict, scheme: str, chip: _Chip,
                   pubs: list, msgs: list, sigs: list):
-        """One chip's shard: stage host-side, place on the chip, run the
-        scheme's verify program, fetch the mask. Runs under the chip's
-        supervisor (transient retry in place; failures feed its breaker).
-        Returns (mask (n,), eligible (n,)).
+        """One chip's sr25519 or BLS shard: stage host-side, place on the
+        chip, run the scheme's verify program, fetch the mask. Runs under
+        the chip's supervisor (transient retry in place; failures feed its
+        breaker). Returns (mask (n,), eligible (n,)). (An ed25519 shard is
+        the one-chip trip: _trip_shard.)
 
-        Known gap vs the single-chip plane: shards reuse the exact
-        _verify_kernel_ok executables (a compilation-cache hit per chip)
-        and therefore do NOT carry the staged-word transfer checksum of
-        _integrity_parts — the host-oracle recheck still catches
-        reject-direction corruption, but an accept-direction h2d bit
-        flip is undetected on this path. Folding the checksum in means a
-        distinct per-chip program (one executable instantiation per chip
-        per shape, tens of seconds each); do it when the mesh runs over
-        a real link-attached pod."""
+        Known gap vs the single-chip plane: these shards reuse the exact
+        _verify_kernel_ok executable and therefore do NOT carry the
+        staged-word transfer checksum of _integrity_parts — the
+        host-oracle recheck still catches reject-direction corruption,
+        but an accept-direction h2d bit flip is undetected on this path."""
         from cometbft_tpu.libs import chaos
         from cometbft_tpu.libs import linkmodel as _linkmodel
         from cometbft_tpu.ops.dispatch import KERNEL_DISPATCH_LOCK
@@ -461,15 +497,7 @@ class VerifyMesh:
                              lanes=b, device=chip.index):
                 mask, eligible = shard_verify(chip.device, pubs, msgs, sigs)
             K._count_device_batch(scheme, b)
-            mm = _mesh_metrics()
-            if mm is not None:
-                try:
-                    mm.mesh_shard_lanes.labels(str(chip.index)).inc(b)
-                except Exception:  # noqa: BLE001
-                    pass
-            with self._lock:
-                chip.lanes_total += b
-                chip.shards_total += 1
+            self._count_shard(chip, b)
             return mask, eligible
         with _trace.span(f"{scheme}.stage", cat="stage", sig_rows=n,
                          lanes=b, device=chip.index):
@@ -536,38 +564,98 @@ class VerifyMesh:
             mask = np.asarray(mask_dev)
             sp.add_bytes(rx=mask.nbytes)
         K._count_device_batch(scheme, b)
-        mm = _mesh_metrics()
-        if mm is not None:
-            try:
-                mm.mesh_shard_lanes.labels(str(chip.index)).inc(b)
-            except Exception:  # noqa: BLE001
-                pass
-        with self._lock:
-            chip.lanes_total += b
-            chip.shards_total += 1
-            chip.shard_lanes.add(b)
-            chip.array_devices |= placed
+        self._count_shard(chip, b, placed)
         eligible = pre_ok & ok_a
         return mask[:n] & eligible, eligible
 
+    @staticmethod
+    def _cut(rows: tuple, idx: np.ndarray) -> tuple:
+        """The rows `idx` (ascending) of a batch, column for column:
+        slices where idx is one run (a first round's shard: views of the
+        scheduler's matrices, no per-lane object), index vectors where it
+        is not (a redispatch's leftovers). A column is a list, an array
+        (signatures, key rows), a prefixrows.MsgBlock, or None."""
+        from cometbft_tpu.libs.prefixrows import MsgBlock
+
+        run = None
+        if len(idx) and int(idx[-1]) - int(idx[0]) + 1 == len(idx):
+            run = slice(int(idx[0]), int(idx[-1]) + 1)
+
+        def cut(col):
+            if col is None:
+                return None
+            if run is not None:
+                return col[run]
+            if isinstance(col, np.ndarray):
+                return col[idx]
+            if isinstance(col, MsgBlock):
+                return col.take(idx)
+            return [col[i] for i in idx]
+
+        return tuple(cut(col) for col in rows)
+
+    def _trip_shard(self, ops: dict, chip: _Chip, rows: tuple,
+                    sub_idx: np.ndarray, recheck_groups):
+        """One chip's ed25519 shard: the one-chip trip aimed at the chip,
+        staged and dispatched here, on the caller's thread, un-awaited.
+        Returns the waiter _join calls: () -> (mask (n,), None), raising
+        what the chip's supervisor recorded (the trip is strict). The
+        trip applies the host-oracle recheck itself, under the producers'
+        group budgets remapped onto the shard: None for `eligible` keeps
+        the mesh-level recheck off these rows."""
+        lanes = K.bucket_size(len(sub_idx))
+        with _trace.span("mesh.shard", cat="stage", device=chip.index,
+                         rows=len(sub_idx), lanes=lanes):
+            pubs, msgs, sigs, pub_rows = self._cut(rows, sub_idx)
+            try:
+                thunk = ops["trip"](
+                    pubs, msgs, sigs, pub_rows=pub_rows,
+                    recheck_groups=self._remap_groups(
+                        recheck_groups, sub_idx),
+                    target=chip.target, ladder=ops["kernel"])
+            except Exception as exc:  # noqa: BLE001 - the waiter raises it
+                failed = exc
+
+                def refused():
+                    raise failed
+
+                return refused
+
+        def wait():
+            mask = thunk()
+            self._count_shard(chip, lanes, thunk.placed())
+            return mask, None
+
+        return wait
+
     def _submit_round(self, ops: dict, scheme: str, rows: tuple,
-                      idx: np.ndarray, klass: str, chips: list[_Chip]):
-        """Shard idx's rows over `chips` and submit every shard to the
-        mesh pool. Returns [(chip, sub_idx, future)]."""
-        pubs, msgs, sigs = rows
+                      idx: np.ndarray, klass: str, chips: list[_Chip],
+                      recheck_groups=None):
+        """Shard idx's rows over `chips` and dispatch every shard: an
+        ed25519 shard from this thread (_trip_shard), an sr25519 or BLS
+        one on the mesh pool. Returns [(chip, sub_idx, waiter)]."""
+        from cometbft_tpu.ops import dispatch as D
+
+        with _trace.span("mesh.plan", cat="stage", rows=len(idx),
+                         chips=len(chips)):
+            plan = self._plan(len(idx), klass, chips)
         submitted = []
-        for chip, lo, hi in self._plan(len(idx), klass, chips):
+        for chip, lo, hi in plan:
             sub_idx = idx[lo:hi]
-            sub_pubs = [pubs[i] for i in sub_idx]
-            sub_msgs = [msgs[i] for i in sub_idx]
-            sub_sigs = [sigs[i] for i in sub_idx]
             with self._lock:
                 chip.inflight_lanes += K.bucket_size(len(sub_idx))
-            fut = self._executor().submit(
-                _trace.wrap_ctx(chip.supervisor.run),
-                functools.partial(self._shard_op, ops, scheme, chip,
-                                  sub_pubs, sub_msgs, sub_sigs))
-            submitted.append((chip, sub_idx, fut))
+            if "trip" in ops:
+                wait = self._trip_shard(ops, chip, rows, sub_idx,
+                                        recheck_groups)
+            else:
+                fut = self._executor().submit(
+                    _trace.wrap_ctx(chip.supervisor.run),
+                    functools.partial(self._shard_op, ops, scheme, chip,
+                                      *self._cut(rows[:3], sub_idx)))
+
+                def wait(fut=fut):
+                    return fut.result(timeout=D.watchdog_timeout())
+            submitted.append((chip, sub_idx, wait))
         return submitted
 
     @staticmethod
@@ -610,35 +698,40 @@ class VerifyMesh:
                 "single-chip ladder", scheme=scheme, rows=str(len(idx)))
         except Exception:  # noqa: BLE001
             pass
-        pubs, msgs, sigs = rows
+        pubs, msgs, sigs, pub_rows = self._cut(rows, idx)
         kwargs = {}
-        sub_groups = self._remap_groups(recheck_groups, idx)
-        if sub_groups is not None and scheme == "ed25519":
+        if scheme == "ed25519":
             # sr25519's async path has no recheck_groups parameter (its
             # single-chip recheck is budgeted whole-batch)
-            kwargs["recheck_groups"] = sub_groups
-        fb_mask = ops["fallback_async"](
-            [pubs[i] for i in idx], [msgs[i] for i in idx],
-            [sigs[i] for i in idx], **kwargs)()
-        mask[idx] = fb_mask
+            kwargs = {"pub_rows": pub_rows, "recheck_groups":
+                      self._remap_groups(recheck_groups, idx)}
+        mask[idx] = ops["fallback_async"](pubs, msgs, sigs, **kwargs)()
         eligible[idx] = False
 
-    def verify_async(self, scheme: str, pubs: list[bytes], msgs: list[bytes],
-                     sigs: list[bytes], klass: str = "sync",
-                     recheck_groups: list[tuple[int, int]] | None = None):
+    def verify_async(self, scheme: str, pubs: list[bytes], msgs, sigs,
+                     klass: str = "sync",
+                     recheck_groups: list[tuple[int, int]] | None = None,
+                     pub_rows: np.ndarray | None = None):
         """Shard + dispatch across the live mesh without blocking; returns
         a thunk materializing the (N,) bool mask. A shard whose chip dies
         mid-flight is re-dispatched over the survivors inside the thunk —
-        the caller's futures always resolve."""
+        the caller's futures always resolve.
+
+        The rows as lists of bytes, or, for ed25519, as the scheduler has
+        them (libs/rowblock.SigColumns, the arguments of
+        ed25519_kernel.verify_batch_async): msgs a prefixrows.MsgBlock,
+        sigs the (N, 64) matrix, pub_rows the (N, 32) key matrix beside
+        the key list. A shard is a slice of them."""
         n = len(sigs)
         assert len(pubs) == n and len(msgs) == n
         ops = self._scheme_ops(scheme)
         if n == 0:
             return lambda: np.zeros(0, dtype=bool)
-        rows = (list(pubs), list(msgs), list(sigs))
+        rows = (pubs, msgs, sigs, pub_rows)
         idx = np.arange(n)
         chips = self.live_chips()
-        pending = (self._submit_round(ops, scheme, rows, idx, klass, chips)
+        pending = (self._submit_round(ops, scheme, rows, idx, klass, chips,
+                                      recheck_groups)
                    if chips else [])
 
         def thunk() -> np.ndarray:
@@ -648,13 +741,27 @@ class VerifyMesh:
         return thunk
 
     def verify(self, scheme: str, pubs, msgs, sigs, klass: str = "sync",
-               recheck_groups=None) -> np.ndarray:
+               recheck_groups=None, pub_rows=None) -> np.ndarray:
         return self.verify_async(
-            scheme, pubs, msgs, sigs, klass, recheck_groups)()
+            scheme, pubs, msgs, sigs, klass, recheck_groups, pub_rows)()
 
     def _join(self, ops: dict, scheme: str, rows: tuple, n: int,
               idx0: np.ndarray, pending: list, klass: str,
               recheck_groups) -> np.ndarray:
+        # the caller blocked on its shards, the slowest decides: a stage
+        # of its own (`join`), so that the wait for the chips' dispatch
+        # closures is not read as `fetch` (the per-shard d2h spans inside
+        # are, and are taken off this span's self time)
+        with _trace.span("mesh.join", cat="join", scheme=scheme,
+                         shards=len(pending)):
+            mask, eligible = self._join_rounds(
+                ops, scheme, rows, n, idx0, pending, klass, recheck_groups)
+        info = (ops["verify_fn"], scheme, recheck_groups)
+        return K.apply_recheck(mask, eligible, rows[:3], info)
+
+    def _join_rounds(self, ops: dict, scheme: str, rows: tuple, n: int,
+                     idx0: np.ndarray, pending: list, klass: str,
+                     recheck_groups) -> tuple[np.ndarray, np.ndarray]:
         from cometbft_tpu.ops import dispatch as D
 
         mask = np.zeros(n, dtype=bool)
@@ -670,11 +777,12 @@ class VerifyMesh:
         while pending:
             failed_idx: list[np.ndarray] = []
             reasons: list[str] = []
-            for chip, sub_idx, fut in pending:
+            for chip, sub_idx, wait in pending:
                 try:
-                    m, el = fut.result(timeout=D.watchdog_timeout())
+                    m, el = wait()
                     mask[sub_idx] = m
-                    eligible[sub_idx] = el
+                    # None: the shard rechecked its own lanes (the trip)
+                    eligible[sub_idx] = False if el is None else el
                 except (D.DeviceUnavailable, D.DeviceOpFailed) as exc:
                     cause = exc.__cause__ or exc
                     reason = ("unavailable"
@@ -716,7 +824,7 @@ class VerifyMesh:
                                recheck_groups=recheck_groups)
                 break
             pending = self._submit_round(
-                ops, scheme, rows, retry_idx, klass, chips)
+                ops, scheme, rows, retry_idx, klass, chips, recheck_groups)
         with self._lock:
             self.batches += 1
             self.rows_total += n
@@ -724,9 +832,28 @@ class VerifyMesh:
         # this batch just re-closed its breaker, and the readmission (and
         # the mesh-size gauge) must be visible before the next flush
         self.live_chips()
-        info = (ops["verify_fn"], scheme, recheck_groups)
-        pubs, msgs, sigs = rows
-        return K.apply_recheck(mask, eligible, (pubs, msgs, sigs), info)
+        return mask, eligible
+
+    # -------------------------------------------------------------- warmup
+
+    def warmup(self, buckets: list[int]) -> list[int]:
+        """Instantiate the ed25519 shard programs of the given lane counts
+        on every live chip, one chip after the other: the first chip's
+        compile is every other chip's load from the compilation cache
+        (ops/compile_cache.py). Rows as the scheduler's own warm-up makes
+        them; no batch or shard is counted. Returns the lane counts every
+        live chip ran."""
+        ops = self._scheme_ops("ed25519")
+        done: list[int] = []
+        for b in buckets:
+            try:
+                for chip in self.live_chips():
+                    ops["trip"](*K.warmup_rows(b), target=chip.target,
+                                ladder=ops["kernel"])()
+                done.append(b)
+            except Exception:  # noqa: BLE001 - the chip's supervisor owns it
+                break
+        return done
 
     # -------------------------------------------------------------- health
 
@@ -763,8 +890,23 @@ class VerifyMesh:
                 "fallbacks": self.fallbacks,
                 "batches": self.batches,
                 "rows_total": self.rows_total,
+                # over every chip: rows_total / lanes_total is the fill of
+                # the shards' buckets, shards_total / batches the split
+                "shards_total": sum(c["shards_total"]
+                                    for c in chips.values()),
+                "lanes_total": sum(c["lanes_total"] for c in chips.values()),
+                # the verify program a shard of each scheme runs
+                "shard_program": self.shard_programs(),
                 "chips": chips,
             }
+
+    @staticmethod
+    def shard_programs() -> dict:
+        """ed25519: the one-chip trip's, so Pallas on a TPU for a
+        128-aligned bucket (behind its gate the XLA ladder); sr25519 and
+        BLS shards: their XLA programs."""
+        return {"ed25519": "pallas" if K._pallas_available() else "xla",
+                "sr25519": "xla", "bls12381": "xla"}
 
 
 # ---------------------------------------------------------------------------

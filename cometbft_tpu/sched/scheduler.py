@@ -626,10 +626,15 @@ class VerifyScheduler:
         with trace.span("sched.dispatch", cat="compute",
                         schemes=len(per)):
             for scheme, (cols, bounds, _where) in per.items():
-                if mesh is not None and scheme in (
-                        "ed25519", "sr25519", "bls12381"):
+                if mesh is not None and scheme == "ed25519":
                     # mesh shards dispatch eagerly inside verify_async;
-                    # both schemes' shards are in flight before any join
+                    # every scheme's shards are in flight before any join.
+                    # An ed25519 shard is a slice of the columns
+                    mesh_thunks.append((scheme, mesh.verify_async(
+                        scheme, cols.pubs, cols.msgs, cols.sigs,
+                        klass=batch_klass, recheck_groups=bounds,
+                        pub_rows=cols.pub_rows)))
+                elif mesh is not None and scheme in ("sr25519", "bls12381"):
                     mesh_thunks.append((scheme, mesh.verify_async(
                         scheme, cols.pubs, cols.msgs.tolist(),
                         cols.sig_list(), klass=batch_klass,
@@ -770,9 +775,11 @@ class VerifyScheduler:
 
     def warmup(self, max_lanes: int | None = None) -> list[int]:
         """Pre-trace the bucket ladder on the device so the first real
-        consensus flush doesn't pay a cold compile mid-round. No-op off
-        the TPU backend (CPU programs compile in milliseconds and tests
-        pin the CPU backend). Returns the lane counts traced."""
+        consensus flush doesn't pay a cold compile mid-round: on every
+        live chip of an active mesh (VerifyMesh.warmup: its shards are
+        what a flush rides there), else on the one chip. No-op off the
+        TPU backend (CPU programs compile in milliseconds and tests pin
+        the CPU backend). Returns the lane counts traced."""
         from cometbft_tpu.crypto import batch as crypto_batch
 
         if crypto_batch.resolve_backend() != "tpu":
@@ -780,8 +787,14 @@ class VerifyScheduler:
         from cometbft_tpu.ops import ed25519_kernel as EK
         from cometbft_tpu.ops import limbs as _limbs
 
+        ladder = self.bucket_ladder(max_lanes or 2048)
+        mesh = self._mesh(build=True)
+        if mesh is not None:
+            for b in ladder:
+                _limbs.POOL.warm(b)
+            return mesh.warmup(ladder)
         traced: list[int] = []
-        for b in self.bucket_ladder(max_lanes or 2048):
+        for b in ladder:
             # double-buffer pair per rung: the first real flushes must
             # not allocate staging blocks on the hot path
             _limbs.POOL.warm(b)
@@ -795,13 +808,9 @@ class VerifyScheduler:
                         _challenge.block_words(b, _challenge.MAX_VAR))
             except Exception:  # noqa: BLE001 - warmup is best-effort
                 pass
-            # identity-point rows: pub = the identity encoding, s = 0 —
-            # structurally valid, decompress trivially, verify cheap
-            pubs = [EK._ID_ENC32] * b
-            msgs = [b"sched-warmup"] * b
-            sigs = [EK._ID_ENC32 + b"\x00" * 32] * b
             try:
-                EK.resolve_batches([EK.verify_batch_async(pubs, msgs, sigs)])
+                EK.resolve_batches(
+                    [EK.verify_batch_async(*EK.warmup_rows(b))])
                 traced.append(b)
             except Exception:  # noqa: BLE001 - device trouble: supervisor owns it
                 break

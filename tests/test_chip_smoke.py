@@ -171,13 +171,21 @@ def test_rung_accounting_of_a_vote_flush_phase():
 
 
 def _mesh_snapshot() -> dict:
+    """Sixteen ed25519 shards on 2,048-lane buckets, each the one-chip
+    trip on its own chip (a Pallas success, a plan, a derive); sr25519
+    shards on the XLA ladder."""
     s = _clean_snapshot()
-    for name in ("pallas.ed25519", "pallas.sr25519"):
-        del s["supervisors"][name]
-    s["dispatched_shapes"] = []
+    del s["supervisors"]["pallas.sr25519"]
+    s["supervisors"]["pallas.ed25519"] = _sup(16)
+    s["supervisors"]["ed25519.challenge"] = _sup(16)
+    s["challenge"]["plans"] = 16
+    s["counters"]["device_batches_ed25519"] = 16
+    s["dispatched_shapes"] = [2048]
     s["mesh"] = {"active": True, "devices": 4, "live": 4, "evictions": 0,
                  "readmissions": 0, "redispatched_batches": 0,
                  "fallbacks": 0,
+                 "shard_program": {"ed25519": "pallas", "sr25519": "xla",
+                                   "bls12381": "xla"},
                  "chips": {str(i): {"successes": 4, "failures": 0,
                                     "shards": 4, "shard_lanes": [2048],
                                     "array_devices": [f"TPU_{i}"]}
@@ -188,7 +196,7 @@ def _mesh_snapshot() -> dict:
     return s
 
 
-MESH_EXPECT = dict(aligned_ed=0, aligned_sr=0, warmed={2048}, mesh_chips=4)
+MESH_EXPECT = dict(aligned_ed=16, aligned_sr=0, warmed={2048}, mesh_chips=4)
 
 
 def test_mesh_accounting_passes_four_live_chips():
@@ -221,9 +229,24 @@ def _redispatched(s):
     s["mesh"]["redispatched_batches"] = 1
 
 
+def _shards_on_the_xla_ladder(s):  # the mesh of before PR 33
+    s["supervisors"]["pallas.ed25519"] = _sup(0)
+
+
+def _mesh_does_not_say_pallas(s):
+    s["mesh"]["shard_program"]["ed25519"] = "xla"
+
+
+def _shards_with_host_challenges(s):
+    s["challenge"]["plans"] = 0
+    s["supervisors"]["ed25519.challenge"] = _sup(0)
+
+
 @pytest.mark.parametrize("spoil", [
     _chip_idle, _chip_evicted, _mesh_fell_back, _redispatched,
     _everything_on_the_first_device, _tables_on_the_first_device,
+    _shards_on_the_xla_ladder, _mesh_does_not_say_pallas,
+    _shards_with_host_challenges,
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_mesh_accounting_fails_on_a_missing_chip(spoil):
     snap = _mesh_snapshot()
@@ -343,10 +366,18 @@ def test_mesh_phase_rehearses_on_four_virtual_devices(device_plane):
 
     acct = device_plane
     mesh = verify_mesh.VerifyMesh(devices=jax.devices()[:4])
-    mesh._device_cache = True
+    mesh._device_cache = True  # the sr25519 shards' branch on a chip
     verify_mesh._set_for_testing(mesh)
-    workloads = chip_smoke.build_workloads(
-        (("ed-32", 32, 0), ("mixed-32+32", 32, 32)), seed=6)
+    # every stamp in one encoding length: a shard of 8 rows then plans the
+    # geometry every other shard plans (at 2,048 rows a shard the common
+    # stamps dominate by themselves), and the warm-up's two commits build
+    # every program the phase runs
+    workloads = []
+    for k, (name, n_ed, n_sr) in enumerate(
+            (("ed-32", 32, 0), ("mixed-32+32", 32, 32))):
+        nanos = [(500 + i) * 1_000_000 for i in range(n_ed + n_sr)]
+        workloads.append((name, *chip_smoke.make_commit(
+            n_ed, n_sr, 6 + k, nanos=nanos), 5 + 36 * k))
     # a consensus-class batch this small is pinned to one chip
     with sched.work_class("sync"):
         readings = chip_smoke.mesh_phase(workloads, acct, repeats=1)

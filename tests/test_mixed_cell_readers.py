@@ -171,10 +171,17 @@ def test_benchmark_json_brings_the_cell_with_entries_alone():
     assert len(hub.per_layer) >= 17
     assert set(hub.per_layer) <= set(cell.per_layer)
     assert set(EXPECTED) <= set(cell.per_layer)
-    assert not set(EXPECTED) & set(hub.per_layer)
+    # the sr25519 kernel's own roofline is this cell's alone; the three
+    # of the trip may be given to any cell that has something for them
+    assert "sr25519_kernel_roofline.commit" not in hub.per_layer
     assert cell.config["validators"] == {"ed25519": 5120, "sr25519": 5120}
     assert cell.config["ring_heights"] == 2
     for name in EXPECTED:
         entry = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL]
+        # the cell they came with first; later cells that have something
+        # for them to read come behind it
+        assert entry["workloads"][0] == CELL
         assert entry["moves"] == "commit_verify_ms"
+    sr = next(m for m in bench["per_layer"]
+              if m["name"] == "sr25519_kernel_roofline.commit")
+    assert sr["workloads"] == [CELL]  # no other cell runs the sr25519 kernel

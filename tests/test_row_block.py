@@ -888,10 +888,19 @@ def test_the_reader_of_commit_rows_block_pct(block, lane, want):
 
 
 def test_benchmark_json_lists_the_metric_for_both_cells_at_the_end():
+    """Appended behind PR 29's metric, as a PR's entries go at the end of
+    their list (later PRs' come behind it), with its reader's unit; its
+    `workloads` hold the two cells it was written for."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
-    assert bench["per_layer"][-1] == {
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names.count(METRIC) == 1
+    assert names.index(METRIC) == names.index(
+        "sign_rows_vector_pct.commit") + 1
+    entry = dict(bench["per_layer"][names.index(METRIC)])
+    cells = entry.pop("workloads")
+    assert entry == {
         "name": METRIC, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "node path",
-        "moves": "commit_verify_ms",
-        "workloads": ["hub-150.commit", "committee-10k-mixed.commit"]}
+        "moves": "commit_verify_ms"}
+    assert cells[:2] == ["hub-150.commit", "committee-10k-mixed.commit"]

@@ -305,13 +305,16 @@ def test_the_reader_of_sign_rows_vector_pct(vector, scalar, want):
 
 
 def test_benchmark_json_lists_the_metric_for_both_cells():
+    """The entry is there under its name, with its reader's unit, and its
+    `workloads` hold the two cells it was written for; later cells may be
+    appended behind them."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
-    # appended by PR 29; PR 31's commit_rows_block_pct.commit came behind it
-    assert [m for m in bench["per_layer"] if m["name"] == METRIC] == [{
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == METRIC]
+    cells = entry.pop("workloads")
+    assert entry == {
         "name": METRIC, "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "node path",
-        "moves": "commit_verify_ms",
-        "workloads": ["hub-150.commit", "committee-10k-mixed.commit"]}]
-    assert [w["name"] for w in bench["workloads"]] == [
-        "hub-150.commit", "committee-10k-mixed.commit"]
+        "moves": "commit_verify_ms"}
+    assert cells[:2] == ["hub-150.commit", "committee-10k-mixed.commit"]
+    assert set(cells) <= {w["name"] for w in bench["workloads"]}
