@@ -18,11 +18,19 @@ host challenge pipeline in ops/hashvec.py:
                             emitting the packed (8, N) challenge words
                             the verify grid consumes
   prefix/tail table         a 256-row device-resident table of
-                            prefix||tail byte rows, content-keyed and
-                            delta-synced like the residency key tables,
-                            so a vote lane's message descriptor is a
-                            2-byte (flag|prefix-id) plus only the
-                            ~10-24 variable suffix bytes
+                            prefix||tail byte rows, content-keyed, so a
+                            vote lane's message descriptor is a 2-byte
+                            (flag|prefix-id) plus only the ~10-24
+                            variable suffix bytes. A new row (a new
+                            height) rides the batch's own upload: the
+                            wire block carries up to CARRY_ROWS dirty
+                            rows at its tail, the derive program sets
+                            them and hands the new table back; the
+                            batch's checksum covers them, and the table
+                            adopts the result once that batch has
+                            resolved intact. Only first use and an
+                            overflow take the awaited scatter
+                            (PrefixTable.sync)
 
 Both cores are oracled bit-for-bit against hashvec.sha512_rows /
 reduce512_mod_l (tests/test_challenge.py fuzzes every rung); the wire
@@ -39,9 +47,13 @@ Wire layout (one flat uint32 block, ed25519_kernel stages it):
                         LE descriptors (bit15 = device-derive flag, low
                         15 bits = prefix-table row), then b lanes of
                         `var` variable suffix bytes, lane-contiguous
+  words[W      : W+C]   C = CARRY_WORDS: the prefix table's carried rows,
+                        CARRY_ROWS row indices, then as many rows of
+                        PREFIX_CAP bytes as LE words
 
 giving 64 + 2 + var wire bytes per signature (plus the 2-byte residency
-index) — ~66-82 B/sig against the 98 of host-computed challenges.
+index) — ~66-82 B/sig against the 98 of host-computed challenges — and
+CARRY_BYTES a batch for the table's rows.
 """
 
 from __future__ import annotations
@@ -66,6 +78,12 @@ MAX_VAR = 24      # variable suffix bytes shipped per lane; 2 + var must
 MAX_MLEN = 192    # message bytes (prefix+var+tail): 64+192 pads to <= 3
                   # SHA-512 blocks, the static compile ladder's ceiling
 MIN_LANES = 4     # below this the classic path's fixed cost wins
+CARRY_ROWS = 8    # dirty table rows ONE derive call carries: a catch-up
+                  # window's new heights (blocksync VERIFY_WINDOW). One
+                  # static width, so the derive family stays as it was;
+                  # more dirty rows than this take the awaited sync()
+CARRY_WORDS = CARRY_ROWS * (1 + PREFIX_CAP // 4)  # indices, then rows
+CARRY_BYTES = 4 * CARRY_WORDS
 MIN_ELIGIBLE_FRAC = 0.5  # mostly-fallback batches take the classic path
 
 # ------------------------------------------------------------------ config
@@ -379,11 +397,17 @@ def reduce512_mod_l_device(digests: np.ndarray) -> np.ndarray:
 #
 # The device-resident message dictionary: each row is prefix||tail bytes
 # (a vote flush's shared sign-bytes prefix plus the batch-common suffix
-# tail — chain-id trailer etc.), content-keyed host-side, LRU-evicted,
-# delta-synced to the device with the same checksummed-scatter contract
-# as the residency key tables. plan_batch captures the device snapshot
-# AT PLAN TIME: scatters are functional, so in-flight batches keep their
-# immutable table even if later plans evict their rows.
+# tail — chain-id trailer etc.), content-keyed host-side, LRU-evicted.
+# Rows the device has not confirmed yet are DIRTY. plan_batch captures,
+# AT PLAN TIME, the last confirmed device snapshot and a copy of the
+# dirty rows (PrefixTable.carry); they go up at the tail of the batch's
+# wire block, the derive program sets them and returns the new table,
+# and the batch's checksum covers them. Scatters are functional and the
+# plan's copies are its own, so an in-flight batch keeps its immutable
+# table even if later plans evict its rows. The table adopts a derive's
+# output (and cleans the rows) only once that batch resolved with its
+# integrity intact; until then every plan carries them again. The
+# awaited checksummed scatter (sync) serves first use and an overflow.
 
 _CHK_MULT = np.uint32(2654435761)  # Knuth multiplicative; position-weighted
 
@@ -432,11 +456,13 @@ class PrefixTable:
         self._row_key: dict[int, tuple[bytes, bytes]] = {}
         self._lru: dict[tuple[bytes, bytes], None] = {}  # dict order = LRU
         self._host = np.zeros((TABLE_ROWS, PREFIX_CAP), dtype=np.uint8)
-        self._dirty: set[int] = set()
-        self._tab = None  # device snapshot after last successful sync
+        # row -> the version of its insert: what the device has not
+        # confirmed (a row re-used since a plan carried it stays dirty)
+        self._dirty: dict[int, int] = {}
+        self._tab = None  # the last CONFIRMED device snapshot
         self.version = 0
         self.counters = {"inserts": 0, "hits": 0, "evictions": 0,
-                         "upload_failures": 0, "syncs": 0}
+                         "upload_failures": 0, "syncs": 0, "adoptions": 0}
 
     def ensure(self, prefix: bytes, tail: bytes,
                protect: set[int] | None = None,
@@ -477,8 +503,8 @@ class PrefixTable:
             self._host[row] = 0
             body = key[0] + key[1]
             self._host[row, :len(body)] = np.frombuffer(body, dtype=np.uint8)
-            self._dirty.add(row)
             self.version += 1
+            self._dirty[row] = self.version
             self.counters["inserts"] += 1
             self.counters["hits"] += lanes - 1
             return row
@@ -495,39 +521,85 @@ class PrefixTable:
         return jax.device_put(zeros, self._device)
 
     def sync(self):
-        """Upload dirty rows (checksummed scatter, one retry) and return
-        the device table snapshot, or None when the upload cannot be
-        trusted (rows stay dirty; the batch takes the host path). The
-        scatter runs where its committed argument, the table, lies."""
+        """Upload dirty rows (checksummed scatter, one retry), AWAITED,
+        and return the device table snapshot, or None when the upload
+        cannot be trusted (rows stay dirty; the batch takes the host
+        path). The scatter runs where its committed argument, the table,
+        lies. First use and overflow only: see carry()."""
         with self._lock:
-            dirty = sorted(self._dirty)
-            if not dirty and self._tab is not None:
-                return self._tab
-            if not dirty:  # empty table, first use
-                self._tab = self._empty()
-                return self._tab
-            db = _pow2(len(dirty))
-            idx = np.full(db, dirty[-1], dtype=np.int32)
-            idx[:len(dirty)] = dirty
-            vals = self._host[idx]  # padding repeats the last row: idempotent
-            base = self._tab
-            if base is None:
-                base = self._empty()
-            want = _host_tab_chk(idx, vals)
-            fn = _tab_scatter_fn(db)
-            from cometbft_tpu.ops import residency as _residency
+            return self._sync_locked()
 
-            for _ in range(2):  # one retry on checksum mismatch
-                new, chk = fn(base, idx, vals)
-                _residency.record_send("delta", vals.nbytes + idx.nbytes)
-                _residency.count_trip(programs=1, waits=1)  # int(chk) blocks
-                if int(chk) == want:
-                    self._tab = new
-                    self._dirty.clear()
-                    self.counters["syncs"] += 1
-                    return self._tab
-            self.counters["upload_failures"] += 1
-            return None
+    def _sync_locked(self):
+        dirty = sorted(self._dirty)
+        if not dirty and self._tab is not None:
+            return self._tab
+        if not dirty:  # empty table, first use
+            self._tab = self._empty()
+            return self._tab
+        db = _pow2(len(dirty))
+        idx = np.full(db, dirty[-1], dtype=np.int32)
+        idx[:len(dirty)] = dirty
+        vals = self._host[idx]  # padding repeats the last row: idempotent
+        base = self._tab
+        if base is None:
+            base = self._empty()
+        want = _host_tab_chk(idx, vals)
+        fn = _tab_scatter_fn(db)
+        from cometbft_tpu.ops import residency as _residency
+
+        count("table_rows_awaited", len(dirty))
+        for _ in range(2):  # one retry on checksum mismatch
+            new, chk = fn(base, idx, vals)
+            _residency.record_send("delta", vals.nbytes + idx.nbytes)
+            _residency.count_trip(programs=1, waits=1)  # int(chk) blocks
+            if int(chk) == want:
+                self._tab = new
+                self._dirty.clear()
+                self.counters["syncs"] += 1
+                return self._tab
+        self.counters["upload_failures"] += 1
+        return None
+
+    def carry(self, pad_row: int):
+        """What a plan's wire block takes of the table, or None when no
+        snapshot can be trusted: (snapshot, words, gens). snapshot is the
+        last confirmed device table; words (CARRY_WORDS,) uint32 is a
+        HOST copy of the dirty rows, CARRY_ROWS indices and then the rows'
+        bytes as LE words, padded by repeating a row — the in-program
+        scatter is idempotent; with nothing dirty, `pad_row` (a row the
+        plan reads, clean, so the snapshot holds the same bytes) fills
+        them. gens is {row: insert version} of the rows really carried,
+        for adopt(). No wait and no program: only a table's first use,
+        and more dirty rows than one block carries, go through sync()."""
+        with self._lock:
+            if self._tab is None or len(self._dirty) > CARRY_ROWS:
+                if self._sync_locked() is None:
+                    return None
+            gens = dict(self._dirty)
+            rows = sorted(gens) or [pad_row]
+            words = np.empty(CARRY_WORDS, dtype=np.uint32)
+            idx = words[:CARRY_ROWS]
+            idx[:] = rows[-1]
+            idx[:len(rows)] = rows
+            words[CARRY_ROWS:] = self._host[idx].view(np.uint32).ravel()
+            return self._tab, words, gens
+
+    def adopt(self, snapshot, new_tab, gens: dict[int, int]) -> bool:
+        """A batch that carried `gens` over `snapshot` resolved with its
+        integrity intact: its derive's output is the confirmed table now,
+        and the carried rows are clean unless re-used since. Not when the
+        confirmed table has moved on meanwhile (another batch's adoption,
+        a sync): the other snapshot may hold rows this output lacks, and
+        whatever stays dirty is carried again."""
+        with self._lock:
+            if self._tab is not snapshot:
+                return False
+            self._tab = new_tab
+            for row, gen in gens.items():
+                if self._dirty.get(row) == gen:
+                    del self._dirty[row]
+            self.counters["adoptions"] += 1
+            return True
 
     def stats(self) -> dict:
         with self._lock:
@@ -577,15 +649,18 @@ def reset() -> None:
 class Plan:
     """One batch's device-challenge shape, frozen at plan time: the
     static message geometry the derive program compiles against, the
-    per-lane descriptor assignment, and the immutable device table
-    snapshot the in-flight batch gathers from."""
+    per-lane descriptor assignment, and the table the in-flight batch
+    gathers from: the immutable confirmed snapshot `dev_tab` with the
+    plan's own copy of the dirty rows (`carry`, the block's tail;
+    `n_carried` of the CARRY_ROWS are real, the rest padding) set over it
+    in-program."""
 
     __slots__ = ("plen", "tlen", "var", "slen", "pids", "eligible",
-                 "vbytes", "dev_tab", "n", "n_eligible", "n_fallback",
-                 "put_key")
+                 "vbytes", "dev_tab", "carry", "n_carried", "n",
+                 "n_eligible", "n_fallback", "put_key", "_table", "_gens")
 
     def __init__(self, *, plen, tlen, var, slen, pids, eligible, vbytes,
-                 dev_tab, n, n_eligible, n_fallback, put_key):
+                 table, carried, n, n_eligible, n_fallback, put_key):
         self.plen = plen
         self.tlen = tlen
         self.var = var
@@ -593,19 +668,29 @@ class Plan:
         self.pids = pids
         self.eligible = eligible
         self.vbytes = vbytes
-        self.dev_tab = dev_tab
+        self._table = table
+        self.dev_tab, self.carry, self._gens = carried
+        self.n_carried = len(self._gens)
         self.n = n
         self.n_eligible = n_eligible
         self.n_fallback = n_fallback
         self.put_key = put_key
+
+    def adopt(self, new_tab) -> bool:
+        """The batch resolved with its integrity intact (its checksum
+        covered the block, `carry` in it): hand the derive's table to the
+        PrefixTable. Nothing to do for a plan that carried padding."""
+        if not self._gens:
+            return False
+        return self._table.adopt(self.dev_tab, new_tab, self._gens)
 
 
 def plan_batch(msgs, pre_ok, put_key: str = "", device=None) -> Plan | None:
     """Decide the degradation rung for one batch: a Plan when device
     challenge derivation wins (dominant (prefix-len, suffix-len) combo
     covers most live lanes, messages fit the static compile ladder, the
-    challenge breaker admits, the table syncs), else None — the caller
-    stays on the bit-identical host-challenge path. Lanes outside the
+    challenge breaker admits, the table has a snapshot), else None — the
+    caller stays on the bit-identical host-challenge path. Lanes outside the
     dominant combo or missing a table row become per-lane host
     fallbacks inside the Plan, never verdict changes."""
     n = len(msgs)
@@ -692,8 +777,9 @@ def plan_batch(msgs, pre_ok, put_key: str = "", device=None) -> Plan | None:
     if ne < MIN_LANES or ne < MIN_ELIGIBLE_FRAC * n_ok:
         count("plan_low_eligibility")
         return None
-    dev_tab = tab.sync()
-    if dev_tab is None:
+    # no upload and no wait here: the dirty rows ride the wire block
+    carried = tab.carry(int(pids[eligible][0]))
+    if carried is None:
         count("plan_upload_failed")
         return None
     vbytes = np.zeros((n, var), dtype=np.uint8)
@@ -703,8 +789,8 @@ def plan_batch(msgs, pre_ok, put_key: str = "", device=None) -> Plan | None:
     count("lanes_device", ne)
     count("lanes_host_fallback", n_ok - ne)
     return Plan(plen=plen, tlen=tlen, var=var, slen=slen, pids=pids,
-                eligible=eligible, vbytes=vbytes, dev_tab=dev_tab, n=n,
-                n_eligible=ne, n_fallback=n_ok - ne, put_key=put_key)
+                eligible=eligible, vbytes=vbytes, table=tab, carried=carried,
+                n=n, n_eligible=ne, n_fallback=n_ok - ne, put_key=put_key)
 
 
 # ------------------------------------------------------------- wire packing
@@ -718,16 +804,18 @@ def stream_words(bucket: int, var: int) -> int:
 
 def block_words(bucket: int, var: int) -> int:
     """Total uint32 words of one flat device-challenge staging block:
-    R words, s words, descriptor stream."""
-    return 16 * bucket + stream_words(bucket, var)
+    R words, s words, descriptor stream, the table's carried rows."""
+    return 16 * bucket + stream_words(bucket, var) + CARRY_WORDS
 
 
 def fill_stream(block: np.ndarray, bucket: int, plan: Plan) -> None:
     """Pack the descriptor stream of a leased flat block in place:
     per-lane uint16 LE descriptors (bit15 = derive-on-device, low 15
     bits = prefix-table row; 0 for padding/fallback lanes), then the
-    lane-contiguous variable suffix bytes."""
+    lane-contiguous variable suffix bytes; and behind it the plan's
+    carried table rows."""
     sw = stream_words(bucket, plan.var)
+    block[16 * bucket + sw:] = plan.carry
     sb = block[16 * bucket:16 * bucket + sw].view(np.uint8)
     sb[:] = 0
     n = plan.n
@@ -742,6 +830,14 @@ def fill_stream(block: np.ndarray, bucket: int, plan: Plan) -> None:
 
 
 # ----------------------------------------------------- the derive program
+
+
+def _le_bytes(w):
+    """(n,) uint32 words -> (4n,) uint8, their little-endian bytes."""
+    import jax.numpy as jnp
+
+    return jnp.stack([(w >> (8 * k)) & 0xFF for k in range(4)],
+                     axis=-1).reshape(-1).astype(jnp.uint8)
 
 
 def _words_to_bytes(w):
@@ -761,18 +857,25 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int):
     and the one its upload rides in on. Signature:
 
       run(flat, idx, tx, ty, tz, tt, te, ptab[, fkw, fidx])
-          -> (rw, sw, kw, chk, ax, ay, az, at)
+          -> (rw, sw, kw, chk, ax, ay, az, at, ntab)
 
     flat   (block_words,) uint32 — the staged wire block (R words, s
-           words, descriptor stream), handed over as the HOST array: the
-           call uploads it, un-awaited.
+           words, descriptor stream, the prefix table's carried rows),
+           handed over as the HOST array: the call uploads it, un-awaited.
      idx   (bucket,) uint16 — the lanes' rows in the resident key table
            (residency.KeyTable.index), host array too.
     tx..tt (20, cap) int32 resident A-coordinate planes, te (8, cap)
            uint32 the resident pubkey-encoding words: the table's device
            snapshot. The lanes' rows are gathered here: encodings into
            the challenge preimage, coordinates out as ax..at.
-    ptab   (TABLE_ROWS, PREFIX_CAP) uint8 — the Plan's table snapshot.
+    ptab   (TABLE_ROWS, PREFIX_CAP) uint8 — the Plan's confirmed table
+           snapshot (never donated: batches in flight share it). The
+           block's carried rows (Plan.carry: the dirty rows the device
+           has not confirmed, a new height's) are set over it HERE, the
+           lanes' prefixes are gathered from the result, and the result
+           goes out as ntab, which the PrefixTable adopts once the batch
+           resolved intact. A pure hit carries padding (a clean row's
+           own bytes): ntab equals ptab.
      fkw   (8, fb) uint32 host-computed challenge words for fallback
            lanes, fidx (fb,) int32 their lane indices (padded with a
            repeated real index — the scatter is idempotent). fb == 0
@@ -784,7 +887,8 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int):
     are the block's (8, bucket) R and s planes, and chk is the
     position-weighted checksum of all this call uploaded (flat, fkw,
     fidx), which the verify program holds against the host's value
-    (ed25519_kernel._integrity_parts_chk_expr).
+    (ed25519_kernel._integrity_parts_chk_expr): no lane is derived from
+    a table row whose bytes no checksum covered.
 
     kw is zero for padding/fallback/ineligible lanes before the fkw
     scatter: padded lanes carry identity R / s=0 / k=0, which the verify
@@ -805,12 +909,15 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int):
     sw = stream_words(bucket, var)
 
     def derive_challenge(flat, idx, tx, ty, tz, tt, te, ptab, *fk):
+        with jax.named_scope("prefix_rows"):
+            carry = flat[16 * bucket + sw:]
+            ptab = ptab.at[carry[:CARRY_ROWS].astype(jnp.int32)].set(
+                _le_bytes(carry[CARRY_ROWS:]).reshape(CARRY_ROWS, PREFIX_CAP))
         rows = idx.astype(jnp.int32)
         a_dev = tuple(jnp.take(c, rows, axis=1) for c in (tx, ty, tz, tt))
         aw = jnp.take(te, rows, axis=1)
         stream = flat[16 * bucket:16 * bucket + sw]
-        sb = jnp.stack([(stream >> (8 * k)) & 0xFF for k in range(4)],
-                       axis=-1).reshape(-1).astype(jnp.uint8)
+        sb = _le_bytes(stream)
         dlo = sb[0:2 * bucket:2].astype(jnp.uint32)
         dhi = sb[1:2 * bucket:2].astype(jnp.uint32)
         desc = dlo | (dhi << 8)
@@ -843,6 +950,6 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int):
         s_w = flat[8 * bucket:16 * bucket].reshape(8, bucket)
         with jax.named_scope("integrity"):
             chk = EK._device_checksum_expr((flat,) + fk)
-        return (rw, s_w, kw, chk) + a_dev
+        return (rw, s_w, kw, chk) + a_dev + (ptab,)
 
     return jax.jit(derive_challenge)

@@ -333,6 +333,9 @@ def decode_header(header: np.ndarray, expected) -> str:
     return "chk_mismatch"
 
 
+_INTACT = ("happy", "full")  # verdicts of a header whose checksum matched
+
+
 # happy/full fetch accounting (bench emits fetch_bytes_happy_path from
 # this; crypto_health surfaces it next to the hashvec rung counters)
 _fetch_lock = threading.Lock()
@@ -1146,7 +1149,8 @@ def _to_host(dev_arr) -> np.ndarray:
 
 def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
                             n, pre_ok, ok_a, rows, info,
-                            expected=0, lease=None, strict: bool = False):
+                            expected=0, lease=None, strict: bool = False,
+                            on_intact=None):
     """The shared thunk shape for a supervised device batch (ed25519 and
     sr25519 build their dispatch closure, this builds the rest): dispatch
     runs on the transfer pool under the supervisor; fetches are
@@ -1171,7 +1175,12 @@ def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
     raised (DeviceOpFailed / DeviceUnavailable, recorded) instead of
     resolved on the host oracle: the mesh has other chips to ask first.
     A payload that fails its integrity checks still retries and then
-    resolves on the host oracle, as everywhere."""
+    resolves on the host oracle, as everywhere.
+
+    on_intact: called once a header has shown that the device checksummed
+    the very bytes the host staged (verdict happy or full), here or in
+    resolve_batches: what else rode the upload under that checksum (the
+    prefix table's rows) is confirmed then."""
     # wrap_ctx carries the caller's trace context onto the pool thread so
     # the dispatch's transfer/compute spans land inside this batch's tree
     fut = _xfer_pool().submit(_trace.wrap_ctx(sup.run), submit_fn)
@@ -1195,6 +1204,7 @@ def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
             raise _dispatch.DeviceOpFailed(f"{scheme} dispatch wait") from exc
 
     _acquire.expected = expected  # resolve_batches decodes headers itself
+    _acquire.on_intact = on_intact
 
     def _fetch_np(dev_arr, pure_transfer: bool = False) -> np.ndarray:
         """Device->host fetch (header or full payload): chaos site +
@@ -1251,6 +1261,8 @@ def supervised_device_thunk(scheme: str, sup, submit_fn, fetch_site: str,
             return host_oracle_mask(n, pre_ok, _ok_arr(ok_a), rows, info)
         ok = _ok_arr(ok_a)  # staging completed: the cell is resolved
         verdict = decode_header(header, expected)
+        if on_intact is not None and verdict in _INTACT:
+            on_intact()
         if verdict == "happy":
             _count_fetch(True, header.nbytes)
             _release()
@@ -1441,8 +1453,17 @@ def verify_batch_async(
             fkw = np.tile(k_fb[-1:].T, (1, fb)).astype(np.uint32)
             fkw[:, :fb_lanes.size] = k_fb.T
     fk = (fkw, fidx) if fb else ()
+    # the block holds the prefix table's dirty rows too (a new height's
+    # row: challenge.fill_stream), so this checksum covers them
     expected_dc = _host_checksum(block, *fk)
     expected_cell = _LateExpected(expected_dc)
+    new_tab = [None]  # the derive's table, while a derive served the batch
+
+    def _intact() -> None:
+        """The header said the device saw the bytes the host sent, the
+        carried rows among them: the prefix table may adopt them."""
+        if new_tab[0] is not None:
+            plan.adopt(new_tab[0])
 
     def _transfer_and_dispatch_dc():
         _fire_dispatch_sites()
@@ -1470,7 +1491,8 @@ def verify_batch_async(
                 chaos.fire(_challenge.SITE)
                 run = _challenge.derive_fn(
                     b, plan.var, plan.plen, plan.tlen, fb)
-                # the batch's ONE upload: block, index vector and the
+                # the batch's ONE upload: block (the prefix table's
+                # dirty rows at its tail), index vector and the
                 # fallback-k arrays are this call's host arguments,
                 # un-awaited (the block stays leased until the batch
                 # resolves)
@@ -1483,7 +1505,14 @@ def verify_batch_async(
                               + sum(a.nbytes for a in fk))
                     sp.add_bytes(tx=nbytes)
                 _residency.count_trip(programs=1)
-                _residency.record_send(path, nbytes, sigs=n)
+                # the block's table rows are table maintenance on the
+                # wire's books (padding too: it went up all the same),
+                # the rest is what the signatures cost
+                carried = _challenge.CARRY_BYTES
+                _residency.record_send(path, nbytes - carried, sigs=n)
+                _residency.record_send("delta", carried)
+                if plan.n_carried:
+                    _challenge.count("table_rows_carried", plan.n_carried)
                 return out
 
             try:
@@ -1498,6 +1527,7 @@ def verify_batch_async(
                 _challenge.count("derive_failed")
         else:
             _challenge.count("enc_not_resident")
+        new_tab[0] = None
         if derived is None:
             # whole-batch host-k rung: compute k here on the transfer
             # pool and send the block's R and s planes with it as the
@@ -1518,7 +1548,7 @@ def verify_batch_async(
                 target, ladder)
         else:
             expected_cell.value = expected_dc  # a _redo after a fallback
-            rw, sw, kw, chk, *a_dev = derived
+            rw, sw, kw, chk, *a_dev, new_tab[0] = derived
             with _trace.span("ed25519.dispatch", cat="compute", lanes=b,
                              device=target.index):
                 parts = _dispatch_verify(
@@ -1531,7 +1561,7 @@ def verify_batch_async(
     return supervised_device_thunk(
         "ed25519", sup, _transfer_and_dispatch_dc, "ed25519.fetch",
         n, pre_ok, ok_cell, rows, info, expected=expected_cell, lease=block,
-        strict=target.strict)
+        strict=target.strict, on_intact=_intact)
 
 
 def resolve_batches(thunks) -> list[np.ndarray]:
@@ -1597,6 +1627,8 @@ def resolve_batches(thunks) -> list[np.ndarray]:
             continue
         v = decode_header(headers[2 * li:2 * li + 2], p[0].expected)
         li += 1
+        if v in _INTACT and p[0].on_intact is not None:
+            p[0].on_intact()
         if v == "echo_corrupt":
             _count_integrity("mask_echo_mismatch")
         if v != "happy":

@@ -288,7 +288,7 @@ def test_derive_fn_matches_host_challenges():
     idx = np.arange(bucket - 1, -1, -1, dtype=np.uint16)
     coords = tuple(jnp.asarray(rng.integers(
         0, 1 << 13, size=(20, bucket), dtype=np.int32)) for _ in range(4))
-    rw_dev, sw_dev, kw, chk, *a_dev = run(
+    rw_dev, sw_dev, kw, chk, *a_dev, _ntab = run(
         block, idx, *coords, jnp.asarray(aw[:, ::-1]), plan.dev_tab,
         fkw, fidx)
     planes = block[:16 * bucket].reshape(2, 8, bucket)
@@ -307,3 +307,203 @@ def test_derive_fn_matches_host_challenges():
         assert kw[:, i].tobytes() == want[i].tobytes(), i
     for i in range(n, bucket):  # padding lanes stay zero (happy header)
         assert not kw[:, i].any(), i
+
+
+# ------------------------------------- the table's rows ride the derive call
+
+
+def _derive_by_hand(plan, msgs, n, bucket, seed):
+    """Run the plan's derive program as the dispatch closure does (no
+    fallback lanes) -> (kw (8, bucket), chk, ntab, host's k words (n, 8),
+    the host's checksum of all the call uploaded)."""
+    import jax.numpy as jnp
+
+    from cometbft_tpu.ops import ed25519_kernel as EK
+
+    rng = np.random.default_rng(seed)
+    sigs = rng.integers(0, 256, size=(n, 32), dtype=np.uint8)
+    pubs = rng.integers(0, 256, size=(n, 32), dtype=np.uint8)
+    block = np.zeros(challenge.block_words(bucket, plan.var),
+                     dtype=np.uint32)
+    block[:8 * bucket].reshape(8, bucket)[:, :n] = (
+        _limbs.bytes_to_words(sigs).T)
+    challenge.fill_stream(block, bucket, plan)
+    aw = np.zeros((8, bucket), dtype=np.uint32)
+    aw[:, :n] = _limbs.bytes_to_words(pubs).T
+    coords = (jnp.zeros((20, bucket), jnp.int32),) * 4
+    run = challenge.derive_fn(bucket, plan.var, plan.plen, plan.tlen, 0)
+    _rw, _sw, kw, chk, *_a, ntab = run(
+        block, np.arange(bucket, dtype=np.uint16), *coords, jnp.asarray(aw),
+        plan.dev_tab)
+    want = hashvec.sha512_mod_l_words(
+        [sigs[i].tobytes() + pubs[i].tobytes() + bytes(msgs[i])
+         for i in range(n)])
+    assert np.array_equal(block[-challenge.CARRY_WORDS:], plan.carry)
+    return np.asarray(kw), int(chk), ntab, want, EK._host_checksum(block)
+
+
+def _height_batch(n: int, height: int):
+    """_vote_batch under another shared prefix of the same length: a new
+    height's votes, the same derive geometry."""
+    return [PrefixedMsg(b"\x08\x02\x11" + b"%0100d" % height, m.suffix)
+            for m in _vote_batch(n)]
+
+
+@pytest.mark.parametrize("case", ["miss", "hit", "evicted_since"])
+def test_derive_with_carried_rows_matches_host_challenges(case):
+    """A new height's row is not on the device when the plan is made: it
+    rides the derive call, which sets it over the snapshot, derives from
+    the result and hands the new table back (`miss`). A pure hit carries
+    padding, a clean row's own bytes, and returns the table it got, and
+    the challenges today's program returns (`hit`). The plan's copies are
+    its own: rows evicted and re-used by later plans do not change what
+    the batch in flight reads (`evicted_since`)."""
+    challenge.reset()
+    n = bucket = 32
+    ok = np.ones(n, dtype=bool)
+    first = challenge.plan_batch(_vote_batch(n), ok, put_key="carry")
+    assert first is not None and first.n_carried == 0  # first use: sync()
+    assert challenge.stats()["table_rows_awaited"] == 1
+    tab = challenge.table("carry")
+    msgs = _vote_batch(n) if case == "hit" else _height_batch(n, 7)
+    plan = challenge.plan_batch(msgs, ok, put_key="carry")
+    assert plan is not None and plan.dev_tab is first.dev_tab
+    assert plan.carry.shape == (challenge.CARRY_WORDS,)
+    assert plan.carry.dtype == np.uint32
+    assert plan.carry.nbytes == challenge.CARRY_BYTES == 8 * (4 + 160)
+    assert challenge.block_words(bucket, plan.var) == (
+        16 * bucket + challenge.stream_words(bucket, plan.var)
+        + challenge.CARRY_WORDS)
+    assert challenge.stats()["table_rows_awaited"] == 1  # no second sync
+    didx = set(plan.carry[:challenge.CARRY_ROWS])
+    if case == "hit":
+        assert plan.n_carried == 0 and tab.stats()["dirty"] == 0
+        assert didx == {0}  # padding: the row the lanes read
+    else:
+        assert plan.n_carried == 1 and tab.stats()["dirty"] == 1
+        assert didx == {1}  # one real row, repeated
+        assert not np.asarray(plan.dev_tab)[1].any()  # not on the device
+    if case == "evicted_since":
+        for i in range(challenge.TABLE_ROWS):  # every row re-used
+            tab.ensure(b"later-%06d" % i, b"")
+        assert tab.stats()["evictions"] >= 2
+        assert not tab._host[1].tobytes().startswith(b"\x08\x02\x11")
+    kw, chk, ntab, want, host_chk = _derive_by_hand(plan, msgs, n, bucket, 34)
+    assert chk == host_chk  # the carried rows are under the checksum
+    for i in range(n):
+        assert kw[:, i].tobytes() == want[i].tobytes(), i
+    body = (msgs[0].prefix + msgs[0].suffix[plan.var:])
+    row = int(plan.pids[0])
+    assert np.asarray(ntab)[row, :len(body)].tobytes() == body
+    if case == "hit":
+        assert np.array_equal(np.asarray(ntab), np.asarray(plan.dev_tab))
+        assert not plan.adopt(ntab)  # nothing to confirm
+    # the confirmed snapshot itself is never written (not donated)
+    assert np.array_equal(np.asarray(first.dev_tab)[0],
+                          np.asarray(plan.dev_tab)[0])
+
+
+def test_table_adopts_only_what_a_batch_confirmed():
+    """adopt(): the derive's output becomes the confirmed snapshot and
+    the carried rows clean; a row re-used since stays dirty; an output
+    over a snapshot that is no longer the confirmed one is not taken; more
+    dirty rows than a call carries go through the awaited sync()."""
+    import jax.numpy as jnp
+
+    challenge.reset()
+    tab = challenge.PrefixTable("adopt")
+    rows = challenge.CARRY_ROWS
+    r0 = tab.ensure(b"h0", b"T")
+    base, words, gens = tab.carry(r0)  # first use: awaited
+    assert gens == {} and tab.stats()["syncs"] == 1
+    r1 = tab.ensure(b"h1", b"T")
+    base1, words, gens = tab.carry(r1)
+    assert base1 is base and list(gens) == [r1]
+    assert set(words[:rows]) == {r1}
+    assert words[rows:].view(np.uint8)[:3].tobytes() == b"h1T"
+    # a second plan before the first resolved carries the row again
+    r2 = tab.ensure(b"h2", b"T")
+    _b, words2, gens2 = tab.carry(r2)
+    assert sorted(gens2) == [r1, r2] and words2[:2].tolist() == [r1, r2]
+    out1 = jnp.asarray(tab._host.copy())  # what the first derive returns
+    assert tab.adopt(base, out1, gens)
+    assert tab.stats()["dirty"] == 1 and tab.stats()["adoptions"] == 1
+    # the second plan's output was made over the OLD snapshot: not taken,
+    # its row stays dirty and is carried by the next plan
+    assert not tab.adopt(base, out1, gens2)
+    base3, _w, gens3 = tab.carry(r2)
+    assert base3 is out1 and list(gens3) == [r2]
+    # r2 re-used (evicted, inserted anew) while its batch is in flight:
+    # adopting that batch's output must not clean the new content
+    tab._rows.pop((b"h2", b"T"))
+    tab._lru.pop((b"h2", b"T"))
+    tab._rows[(b"h2'", b"T")] = r2
+    tab._lru[(b"h2'", b"T")] = None
+    tab.version += 1
+    tab._dirty[r2] = tab.version
+    assert tab.adopt(base3, jnp.asarray(tab._host.copy()), gens3)
+    assert tab.stats()["dirty"] == 1
+    # overflow: nine dirty rows take the awaited scatter, all at once
+    for i in range(challenge.CARRY_ROWS):
+        tab.ensure(b"more-%d" % i, b"T")
+    assert tab.stats()["dirty"] == challenge.CARRY_ROWS + 1
+    before = challenge.stats().get("table_rows_awaited", 0)
+    _b, words, gens = tab.carry(r0)
+    assert gens == {} and set(words[:rows]) == {r0}
+    assert tab.stats()["dirty"] == 0 and tab.stats()["syncs"] == 2
+    assert challenge.stats()["table_rows_awaited"] - before == (
+        challenge.CARRY_ROWS + 1)
+
+
+def test_table_stays_true_under_concurrent_plans_and_adoptions():
+    """Plans (ensure, carry) and resolutions (adopt, or none: a batch that
+    failed) from more threads than cores, rows evicted and re-used all the
+    while, overflows taking the awaited sync(): whatever the order, a row
+    that is not dirty reads on the confirmed snapshot what the host mirror
+    holds (a lost or wrongly cleaned row would derive from stale bytes)."""
+    import sys
+    import threading
+
+    challenge.reset()
+    tab = challenge.PrefixTable("stress")
+    rows_n, cap = challenge.CARRY_ROWS, challenge.PREFIX_CAP
+    errors: list = []
+
+    def clean_rows_are_true() -> int:
+        with tab._lock:
+            confirmed = np.asarray(tab._tab)
+            clean = [r for r in range(challenge.TABLE_ROWS)
+                     if r not in tab._dirty]
+            assert np.array_equal(confirmed[clean], tab._host[clean])
+            return len(clean)
+
+    def worker(seed: int) -> None:
+        rng = np.random.default_rng(seed)
+        try:
+            for _ in range(100):
+                pid = tab.ensure(b"height-%04d" % rng.integers(400), b"T")
+                snapshot, words, gens = tab.carry(pid)
+                new = np.asarray(snapshot).copy()  # what the derive does
+                new[words[:rows_n]] = words[rows_n:].view(np.uint8).reshape(
+                    rows_n, cap)
+                if rng.random() < 0.7:  # else: the batch did not resolve
+                    tab.adopt(snapshot, new, gens)
+                clean_rows_are_true()
+        except Exception as exc:  # noqa: BLE001 - the test reports it
+            errors.append(exc)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(k,))
+                   for k in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert errors == []
+    assert clean_rows_are_true() >= challenge.TABLE_ROWS - rows_n - 16
+    assert tab.counters["adoptions"] > 0 and tab.counters["evictions"] > 0

@@ -271,7 +271,10 @@ def test_chip_killed_mid_flush_loses_no_lane_of_a_commit(committee400,
     corrupt = program.fresh(commit, 250)  # a lane of the dying chip's shard
     _stub_curve_math(monkeypatch, {int(_first_r_words(corrupt)[250])})
     vm = _mesh(4)
-    D.configure(failure_threshold=1)
+    # the chip stays dead for the whole test: a cooldown no compile under
+    # load can outlast (at 30 s a slow first call half-opened the breaker
+    # and the mesh counted four live chips again)
+    D.configure(failure_threshold=1, cooldown=3600.0)
     chaos.arm("ed25519.dispatch.dev2", "permanent")
     assert _verify(vals_spec, vals, block_id, program.fresh(commit)) == (
         "accept")

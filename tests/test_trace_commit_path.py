@@ -293,7 +293,8 @@ class TestProgramNames:
                 (challenge.TABLE_ROWS, challenge.PREFIX_CAP), jnp.uint8))
         text = lowered.as_text(debug_info=True)
         assert "module @jit_derive_challenge" in text
-        for scope in ("sha512_schedule", "sha512_rounds", "barrett_mod_l"):
+        for scope in ("prefix_rows", "sha512_schedule", "sha512_rounds",
+                      "barrett_mod_l"):
             assert f"jit(derive_challenge)/{scope}/" in text, scope
 
     @pytest.mark.parametrize("builder,name", [

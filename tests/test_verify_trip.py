@@ -41,20 +41,42 @@ def _fresh_planes():
 
 
 @pytest.fixture(scope="module")
-def rows():
-    """150 vote-shaped rows (shared prefix, 8 varying bytes, common
-    trailer), and the same with one signature that does not verify."""
-    keys = [ed25519.gen_priv_key() for _ in range(N)]
-    prefix = b"trip-vote-prefix|" + b"h" * 71
+def keys():
+    return [ed25519.gen_priv_key() for _ in range(N)]
+
+
+def _signed(keys, prefixes: list[bytes]):
+    """150 vote-shaped rows (a shared prefix, 8 varying bytes, a common
+    trailer), lane i under prefixes[i % len(prefixes)]: one prefix a
+    commit's rows, eight a catch-up window's."""
     pubs, msgs, sigs = [], [], []
     for i, key in enumerate(keys):
-        msg = PrefixedMsg(prefix, b"%08d" % i + b"|trip-chain")
+        msg = PrefixedMsg(prefixes[i % len(prefixes)],
+                          b"%08d" % i + b"|trip-chain")
         pubs.append(key.pub_key().bytes_())
         msgs.append(msg)
         sigs.append(key.sign(as_bytes(msg)))
+    return pubs, msgs, sigs
+
+
+def _with_a_bad_lane(sigs: list) -> list:
     bad = list(sigs)
     bad[BAD_LANE] = bad[BAD_LANE][:32] + bad[BAD_LANE + 1][32:]
-    return pubs, msgs, sigs, bad
+    return bad
+
+
+def _height(k: int) -> bytes:
+    """Another height's shared prefix, of the first one's length: the
+    same derive geometry, a row the prefix table has not seen."""
+    return b"trip-vote-prefix|" + b"%071d" % k
+
+
+@pytest.fixture(scope="module")
+def rows(keys):
+    """One height's rows, and the same with one signature that does not
+    verify."""
+    pubs, msgs, sigs = _signed(keys, [b"trip-vote-prefix|" + b"h" * 71])
+    return pubs, msgs, sigs, _with_a_bad_lane(sigs)
 
 
 class _Spies:
@@ -151,7 +173,8 @@ def test_happy_batch_is_two_programs_and_one_wait(rows, how, monkeypatch):
     # the same bytes as ever: the wire block and the 2 B/lane index
     sends = residency.send_stats()["indexed"]
     assert sends["sends"] == 1 and sends["sigs"] == N
-    assert sends["bytes"] == 4 * challenge.block_words(BUCKET, 8) + 2 * BUCKET
+    assert sends["bytes"] + challenge.CARRY_BYTES == (
+        4 * challenge.block_words(BUCKET, 8) + 2 * BUCKET)
 
 
 @pytest.mark.parametrize("how", ["thunk", "resolve_batches"])
@@ -192,6 +215,206 @@ def test_failing_lane_same_programs_two_waits_right_lane(
     assert (spies.awaited, spies.fetches, spies.concatenates) == (0, 2, 0)
     trip = {"batches": 1, "device_programs": 2, "blocking_waits": 2}
     assert residency.trip_stats() == trip == _health_trip()
+
+
+def _prefix_table(put_key: str = "default") -> dict:
+    return D.health_snapshot()["staging"]["challenge"]["tables"][put_key]
+
+
+@pytest.mark.parametrize("how", ["thunk", "resolve_batches"])
+@pytest.mark.parametrize("new_rows", [1, 8])
+def test_prefix_miss_rides_the_upload_two_programs_and_one_wait(
+        keys, rows, how, new_rows, monkeypatch):
+    """A new height (a commit: one new prefix; a catch-up window: eight)
+    misses the prefix table, and its rows ride the derive call: no
+    program and no wait of the table's own (ISSUE 34)."""
+    pubs, msgs, sigs, _bad = rows
+    assert K.verify_batch(pubs, msgs, sigs)[0]  # tables resident, warm
+    assert _prefix_table()["syncs"] == 1  # the table's first use
+    pubs, msgs, sigs = _signed(
+        keys, [_height(100 + k) for k in range(new_rows)])
+    residency.reset_send_stats()
+    challenge.reset_stats()
+    spies = _Spies(monkeypatch)
+
+    mask = _resolve(how, pubs, msgs, sigs)
+
+    assert mask.all() and mask.shape == (N,)
+    assert spies.programs == ["derive_challenge", "verify_xla_derived"]
+    assert spies.eager == []
+    assert (spies.awaited, spies.fetches, spies.concatenates) == (0, 1, 0)
+    trip = {"batches": 1, "device_programs": 2, "blocking_waits": 1}
+    assert residency.trip_stats() == trip == _health_trip()
+    counters = D.health_snapshot()["staging"]["challenge"]["counters"]
+    assert counters["table_rows_carried"] == new_rows
+    assert "table_rows_awaited" not in counters
+    assert counters["lanes_device"] == N
+    # the batch resolved intact: the table took the derive's output
+    table = _prefix_table()
+    assert (table["syncs"], table["adoptions"], table["dirty"]) == (1, 1, 0)
+    assert table["rows"] == 1 + new_rows
+    # on the wire: the block and the index as ever; the table's rows are
+    # the block's tail, counted as table maintenance
+    sends = residency.send_stats()
+    assert sends["indexed"]["sends"] == 1 and sends["indexed"]["sigs"] == N
+    assert sends["indexed"]["bytes"] + challenge.CARRY_BYTES == (
+        4 * challenge.block_words(BUCKET, 8) + 2 * BUCKET)
+    assert sends["delta"] == {"sends": 1, "sigs": 0,
+                              "bytes": challenge.CARRY_BYTES}
+    assert challenge.CARRY_BYTES == 8 * 160 + 8 * 4
+    # and the next batch of the same height is a pure hit
+    assert _resolve(how, pubs, msgs, sigs).all()
+    assert _prefix_table()["adoptions"] == 1
+    assert challenge.stats()["table_rows_carried"] == new_rows
+
+
+def test_nine_new_prefixes_overflow_to_the_awaited_sync(
+        keys, rows, monkeypatch):
+    """More new rows than one derive call carries: the awaited scatter
+    takes them all (a third program, a second wait), counted apart, and
+    the verdicts are the oracle's."""
+    from cometbft_tpu.crypto.ed25519_math import verify_zip215
+
+    pubs, msgs, sigs, _bad = rows
+    assert K.verify_batch(pubs, msgs, sigs)[0]
+    pubs, msgs, sigs = _signed(keys, [_height(300 + k) for k in range(9)])
+    sigs = _with_a_bad_lane(sigs)
+    want = [verify_zip215(p, as_bytes(m), s)
+            for p, m, s in zip(pubs, msgs, sigs)]
+    assert want.count(False) == 1
+    residency.reset_send_stats()
+    challenge.reset_stats()
+    spies = _Spies(monkeypatch)
+
+    mask = _resolve("thunk", pubs, msgs, sigs)
+
+    assert mask.tolist() == want
+    assert spies.programs == ["derive_challenge", "verify_xla_derived"]
+    assert spies.eager == [] and spies.fetches == 2  # header, payload
+    assert residency.trip_stats() == {  # + the scatter and its wait
+        "batches": 1, "device_programs": 3, "blocking_waits": 3}
+    counters = challenge.stats()
+    assert counters["table_rows_awaited"] == 9
+    assert "table_rows_carried" not in counters
+    table = _prefix_table()
+    assert (table["syncs"], table["adoptions"], table["dirty"]) == (2, 0, 0)
+
+
+@pytest.mark.parametrize("how", ["thunk", "resolve_batches"])
+def test_corrupted_carried_row_takes_the_integrity_ladder(
+        keys, rows, how, monkeypatch):
+    """A carried row that arrives other than it left (a bit flipped
+    between the host's checksum and the device: the seam of
+    test_in_program_integrity_equals_the_expression, on the live path):
+    the checksum the derive takes covers it, so the header refuses, the
+    payload is pulled, the batch retries and the mask is the oracle's.
+    The table does not adopt the refused snapshot, the row stays dirty
+    and the next batch carries it again."""
+    from cometbft_tpu.libs import metrics
+
+    pubs, msgs, sigs, _bad = rows
+    assert K.verify_batch(pubs, msgs, sigs)[0]
+    pubs, msgs, sigs = _signed(keys, [_height(200)])
+    derive_fn = challenge.derive_fn
+    flips = [True]
+
+    def flipping(*geometry):
+        run = derive_fn(*geometry)
+
+        def call(flat, *args):
+            if flips and flips.pop():
+                flat = flat.copy()  # the block's tail: indices, then rows
+                flat[-challenge.CARRY_WORDS + challenge.CARRY_ROWS + 3] ^= (
+                    np.uint32(1 << 5))  # a byte of the new row's prefix
+            return run(flat, *args)
+        return call
+
+    monkeypatch.setattr(challenge, "derive_fn", flipping)
+    challenge.reset_stats()
+    mismatches = metrics.crypto_metrics().transfer_checksum_mismatch
+    before = mismatches.total()
+
+    mask = _resolve(how, pubs, msgs, sigs)
+
+    assert mask.all() and not flips  # every signature is good
+    assert mismatches.total() - before == 1
+    table = _prefix_table()
+    assert (table["adoptions"], table["dirty"]) == (0, 1)
+    # the first try and the integrity retry both carried the row
+    assert challenge.stats()["table_rows_carried"] == 2
+    assert "table_rows_awaited" not in challenge.stats()
+    residency.reset_send_stats()
+
+    assert _resolve(how, pubs, msgs, sigs).all()
+
+    assert residency.trip_stats() == {
+        "batches": 1, "device_programs": 2, "blocking_waits": 1}
+    assert challenge.stats()["table_rows_carried"] == 3
+    table = _prefix_table()
+    assert (table["adoptions"], table["dirty"]) == (1, 0)
+
+
+def test_failed_derive_leaves_the_rows_dirty_and_host_k_answers(keys, rows):
+    from cometbft_tpu.libs import chaos
+
+    pubs, msgs, sigs, _bad = rows
+    assert K.verify_batch(pubs, msgs, sigs)[0]
+    pubs, msgs, sigs = _signed(keys, [_height(400)])
+    challenge.reset_stats()
+    chaos.arm(challenge.SITE, "permanent", 1)
+    try:
+        mask = _resolve("thunk", pubs, msgs, sigs)
+    finally:
+        chaos.reset()
+        D.reset_supervision()  # the challenge breaker opened
+
+    assert mask.all()
+    counters = challenge.stats()
+    assert counters["derive_failed"] == counters["batch_host_fallback"] == 1
+    assert "table_rows_carried" not in counters
+    table = _prefix_table()
+    assert (table["adoptions"], table["dirty"]) == (0, 1)
+
+    assert _resolve("thunk", pubs, msgs, sigs).all()
+
+    assert challenge.stats()["table_rows_carried"] == 1
+    table = _prefix_table()
+    assert (table["adoptions"], table["dirty"]) == (1, 0)
+
+
+def test_a_mesh_targets_prefix_table_stays_on_its_chip(keys, rows):
+    """A mesh shard's trip is this trip aimed at a chip (Target): that
+    chip's replica of the prefix table takes the carried rows, on that
+    chip, and the process-wide table is not touched."""
+    chip = jax.devices("cpu")[2]  # tests/conftest.py forces 8 host devices
+    target = K.Target(device=chip, index=2, put_key="dev2")
+
+    def ladder(ax, ay, az, at, rw, sw, kw):  # the mesh tests' seam
+        mask = jnp.ones(rw.shape[1], dtype=bool)
+        return mask, mask.all()
+
+    def verify(pubs, msgs, sigs):
+        thunk = K.verify_batch_async(pubs, msgs, sigs, target=target,
+                                     ladder=ladder)
+        mask = thunk()
+        assert thunk.placed() == {str(chip)}
+        return mask
+
+    pubs, msgs, sigs, _bad = rows
+    assert verify(pubs, msgs, sigs).all()  # first use: the awaited sync
+    assert _prefix_table("dev2")["syncs"] == 1
+    challenge.reset_stats()
+    assert verify(*_signed(keys, [_height(500)])).all()
+    assert challenge.stats()["table_rows_carried"] == 1
+    table = _prefix_table("dev2")
+    assert (table["syncs"], table["adoptions"], table["dirty"]) == (1, 1, 0)
+    assert table["devices"] == [str(chip)]  # the adopted snapshot's place
+    assert list(challenge.table_stats()) == ["dev2"]
+    # a readmitted chip starts its replica anew (parallel/mesh.py)
+    challenge.invalidate("dev2")
+    assert verify(pubs, msgs, sigs).all()
+    table = _prefix_table("dev2")
+    assert (table["syncs"], table["adoptions"], table["rows"]) == (1, 0, 1)
 
 
 def test_derive_failure_falls_to_one_host_k_program(rows, monkeypatch):
@@ -314,7 +537,7 @@ def test_in_program_integrity_equals_the_expression(staged, case, hostk):
         if case == "flipped_word":  # suffix bytes of a lane not derived
             flat[16 * BUCKET + (2 * BUCKET + pad_lane * var) // 4] ^= (
                 np.uint32(1 << 9))
-        rw, sw, kw, chk, *a_dev = run(flat, *table)
+        rw, sw, kw, chk, *a_dev, _ntab = run(flat, *table)
         header, payload = K._verify_programs(False)[1](
             *a_dev, rw, sw, kw, chk, expected)
         arrived = flat
@@ -397,3 +620,60 @@ def test_benchmark_reads_programs_per_batch_and_nothing_on_a_parent(rows):
                if not k.startswith("staging.trip.")}
     assert readers.read_metric(metrics_dir, entry["name"],
                                {"counters": parents}) is None
+
+
+def test_benchmark_reads_the_share_of_prefix_rows_carried(keys, rows):
+    """benchmarks/metrics/prefix_rows_carried_pct.json over the flattened
+    crypto_health snapshot, differenced as a run differences it: 100 for a
+    window whose new heights all rode their derive calls, a share where
+    the awaited sync() took some (an overflow), nothing (and no error) on
+    a program without the counters, as the parent is, and in a window
+    with no new row."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from benchmarks import program, readers
+
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        per_layer = json.load(fh)["per_layer"]
+    for family, moves, cell in (
+            ("commit", "commit_verify_ms", "hub-150.commit"),
+            ("catchup", "catchup_blocks_per_s", "hub-150.catchup")):
+        entry = dict(next(m for m in per_layer if m["name"]
+                          == f"prefix_rows_carried_pct.{family}"))
+        assert entry.pop("workloads")[0] == cell  # written for it first
+        assert entry == {
+            "name": f"prefix_rows_carried_pct.{family}", "unit": "%",
+            "better": "higher", "source": "program_counter",
+            "layer": "residency and wire", "moves": moves}
+    metrics_dir = os.path.join(root, "benchmarks", "metrics")
+    name = "prefix_rows_carried_pct.commit"
+
+    def flat() -> dict:
+        out: dict = {}
+        program._flatten(D.health_snapshot(), "", out)
+        return out
+
+    def read(counters):
+        return readers.read_metric(metrics_dir, name, {"counters": counters})
+
+    pubs, msgs, sigs, _bad = rows
+    assert K.verify_batch(pubs, msgs, sigs)[0]  # first use: awaited
+    before = flat()
+    assert K.verify_batch(pubs, msgs, sigs)[0]  # a hit: no new row
+    assert read(program.Counters.diff(before, flat())) is None
+    for k in range(3):  # three new heights, a call each
+        assert K.verify_batch(*_signed(keys, [_height(600 + k)]))[0]
+    counters = program.Counters.diff(before, flat())
+    assert read(counters) == {"value": 100.0, "unit": "%"}
+    # an overflow: nine new rows at once take the awaited scatter
+    assert K.verify_batch(
+        *_signed(keys, [_height(700 + k) for k in range(9)]))[0]
+    counters = program.Counters.diff(before, flat())
+    assert read(counters) == {"value": 100.0 * 3 / (3 + 9), "unit": "%"}
+    parents = {k: v for k, v in counters.items()
+               if ".table_rows_" not in k}
+    assert parents and read(parents) is None
