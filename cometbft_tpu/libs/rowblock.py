@@ -149,13 +149,18 @@ class RowBlock:
         return cls(n, parts)
 
     @classmethod
-    def from_set(cls, cols, idxs: np.ndarray, msgs: MsgBlock,
-                 sigs: list) -> "RowBlock":
-        """No lane loop: the rows of the validators `idxs` (ascending) of
-        a set whose keys are columns already (types/validator.SetColumns),
-        with `msgs` the sign-rows of ALL the set's validators and `sigs`
+    def from_set(cls, cols, idxs: np.ndarray, msgs: MsgBlock, sigs: list,
+                 msg_idxs: np.ndarray | None = None) -> "RowBlock":
+        """No lane loop: the rows of the validators `idxs` of a set whose
+        keys are columns already (types/validator.SetColumns), with
+        `msgs` the sign-rows of ALL the commit's signatures and `sigs`
         the signatures of the chosen ones. Keys and messages are taken
-        with index vectors, a key type at a time."""
+        with index vectors, a key type at a time. A commit of the set
+        itself has a validator's vote at the validator's index; where the
+        commit is another set's (the trusting check), `msg_idxs` says at
+        which signature each chosen validator's vote stands."""
+        if msg_idxs is None:
+            msg_idxs = idxs
         if len(cols.schemes) == 1:
             split = [(0, np.arange(len(idxs)))]
         else:
@@ -170,7 +175,7 @@ class RowBlock:
         for s, lanes in split:
             at = idxs[lanes]
             parts[cols.schemes[s]] = (lanes, SigColumns(
-                cols.keys[at].tolist(), msgs.take(at),
+                cols.keys[at].tolist(), msgs.take(msg_idxs[lanes]),
                 sigs[lanes] if isinstance(sigs, np.ndarray)
                 else sig_column([sigs[i] for i in lanes.tolist()]),
                 pubs=cols.key_bytes[at].tolist(),

@@ -39,9 +39,15 @@ Design constraints, in priority order:
 Stage categories (the attribution model), from the entry down:
 
   node       a call's root (`commit.verify`, `commit.stage_verify`,
-             `commit.prefetch`, `commit.resolve`): its SELF time is what
-             no finer span below covers — the unattributed host time of
-             the commit path
+             `commit.prefetch`, `commit.resolve`; `light.verify` around a
+             light client's hop): its SELF time is what no finer span
+             below covers — the unattributed host time of the commit path
+  header     a light client's checks of a new header before any
+             signature (`light.header`: light/verifier.py
+             _verify_new_header_and_vals, with the Merkle roots of the
+             header and of the validator set handed over) and the Merkle
+             root residency pays to announce a validator set object it
+             has not seen (`residency.announce`)
   signbytes  CanonicalVote sign-bytes encoding (`commit.sign_bytes`; on
              the serial path, attr `serial`, one span around the loop
              that encodes and verifies a signature at a time) and the
@@ -93,8 +99,9 @@ from typing import Any, Callable, Optional
 # Stage categories counted by the attribution model. Spans with any other
 # cat ("sched", "consensus", "sync", "mempool", "device", ...) appear in
 # the trace but never in stage shares — they are containers, not stages.
-STAGES = ("node", "signbytes", "collect", "queue", "stage", "transfer",
-          "challenge", "compute", "fetch", "join", "resolve", "gc")
+STAGES = ("node", "header", "signbytes", "collect", "queue", "stage",
+          "transfer", "challenge", "compute", "fetch", "join", "resolve",
+          "gc")
 
 # The builders of a commit's sign-rows (types/commit.py): a finished
 # `commit.sign_bytes` span that built rows says which one ran (`path`) and
@@ -105,17 +112,34 @@ SIGN_ROW_PATHS = ("vector", "scalar")
 # finished `commit.rows` span of its says which ran (`path`: over columns,
 # or a lane at a time) and how many rows it handed over (`rows`); the
 # other `commit.rows` spans (the verifier's add, a window's selection)
-# say neither.
+# say neither. The trusting check's span adds `lookup` = `address` (its
+# block path joins the commit's addresses to the set's indices).
 COMMIT_ROW_PATHS = ("block", "lane")
 
 # span name -> (the attribution key its rows are summed under, its paths)
 _ROW_PATHS = {"commit.sign_bytes": ("sign_rows", SIGN_ROW_PATHS),
               "commit.rows": ("commit_rows", COMMIT_ROW_PATHS)}
 
+# Plain counts kept beside the stage times while the tracer is on
+# (count(); `attribution.<group>.<name>` in crypto_health): a light
+# client's hops and those of them answered "cannot be trusted"
+# (light/verifier.verify; the span's `answer` has the rest), the rows the
+# trusting check selected by the address join or by get_by_address a
+# signature (types/validation._commit_rows), the Merkle roots of
+# validator sets computed (ValidatorSet.hash).
+COUNTS = {"light": ("hops", "hops_untrusted"),
+          "trusting_rows": ("joined", "scanned"),
+          "valset": ("hashes",)}
+
 
 def _no_rows_by_path() -> dict:
     return {key: dict.fromkeys(paths, 0)
             for key, paths in _ROW_PATHS.values()}
+
+
+def _no_counts() -> dict:
+    return {group: dict.fromkeys(names, 0)
+            for group, names in COUNTS.items()}
 
 _enabled = False  # module-global fast path: read before anything else
 
@@ -253,6 +277,7 @@ class Tracer:
         self._attr_tx = 0
         self._attr_rx = 0
         self._rows_by_path = _no_rows_by_path()
+        self._counts = _no_counts()
         # collector pauses: the gc hook stamps them here lock-free (it
         # runs wherever an allocation triggers a collection, also inside
         # _finish under self._lock); the next _finish or attribution()
@@ -449,15 +474,21 @@ class Tracer:
             self._attr_tx += tx_bytes
             self._attr_rx += rx_bytes
 
+    def count(self, group: str, name: str, n: int) -> None:
+        with self._lock:
+            self._counts[group][name] += n
+
     def attribution(self) -> dict:
         with self._lock:
             self._fold_gc()
             ns = dict(self._attr_ns)
             rows, tx, rx = self._attr_rows, self._attr_tx, self._attr_rx
             by_path = {k: dict(v) for k, v in self._rows_by_path.items()}
+            counts = {k: dict(v) for k, v in self._counts.items()}
             gen0, gen1, gen2 = self._gc_counts
         out = _attribution_dict(ns, rows, tx, rx, by_path)
         out["gc_collections"] = {"gen0": gen0, "gen1": gen1, "gen2": gen2}
+        out.update(counts)
         return out
 
     def reset_attribution(self) -> None:
@@ -467,6 +498,7 @@ class Tracer:
             self._attr_tx = 0
             self._attr_rx = 0
             self._rows_by_path = _no_rows_by_path()
+            self._counts = _no_counts()
             self._gc_pending.clear()
             self._gc_counts = [0, 0, 0]
 
@@ -583,6 +615,13 @@ def account(stage: str, seconds: float, rows: int = 0,
     if _enabled and t is not None:
         t.account(stage, seconds, rows=rows, tx_bytes=tx_bytes,
                   rx_bytes=rx_bytes)
+
+
+def count(group: str, name: str, n: int = 1) -> None:
+    """Add n to one of COUNTS while the tracer is on; nothing when off."""
+    t = _T
+    if _enabled and t is not None:
+        t.count(group, name, n)
 
 
 def add_bytes(tx: int = 0, rx: int = 0) -> None:

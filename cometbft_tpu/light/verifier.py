@@ -19,6 +19,7 @@ rather than per-signature host speed.
 
 from __future__ import annotations
 
+from cometbft_tpu.libs import trace
 from cometbft_tpu.types.light import LightBlock, SignedHeader
 from cometbft_tpu.types.validation import (
     ErrNotEnoughVotingPowerSigned,
@@ -65,6 +66,20 @@ def _verify_new_header_and_vals(
     max_clock_drift_ns: int,
 ) -> None:
     """light/verifier.go:153-193."""
+    with trace.span("light.header", cat="header",
+                    height=untrusted_header.height):
+        _check_new_header_and_vals(
+            untrusted_header, untrusted_vals, trusted_header, now,
+            max_clock_drift_ns)
+
+
+def _check_new_header_and_vals(
+    untrusted_header: SignedHeader,
+    untrusted_vals: ValidatorSet,
+    trusted_header: SignedHeader,
+    now: cmttime.Timestamp,
+    max_clock_drift_ns: int,
+) -> None:
     try:
         untrusted_header.validate_basic(trusted_header.chain_id)
     except ValueError as e:
@@ -217,17 +232,37 @@ def verify(
     max_clock_drift_ns: int,
     trust_level: Fraction = DEFAULT_TRUST_LEVEL,
 ) -> None:
-    """light/verifier.go:138-151."""
-    if untrusted_header.height != trusted_header.height + 1:
-        verify_non_adjacent(
-            trusted_header, trusted_vals, untrusted_header, untrusted_vals,
-            trusting_period_ns, now, max_clock_drift_ns, trust_level,
-        )
-    else:
-        verify_adjacent(
-            trusted_header, untrusted_header, untrusted_vals,
-            trusting_period_ns, now, max_clock_drift_ns,
-        )
+    """light/verifier.go:138-151. One hop of a light client: the root
+    span `light.verify` says between which heights, whether they are
+    adjacent, and how the hop was answered (`accepted`; `untrusted`: the
+    trusted set's share of the new commit is too small, a bisecting
+    client's cue; `rejected`: anything else raised)."""
+    adjacent = untrusted_header.height == trusted_header.height + 1
+    answer = "rejected"
+    with trace.span("light.verify", cat="node", adjacent=adjacent,
+                    trusted_height=trusted_header.height,
+                    height=untrusted_header.height) as sp:
+        try:
+            if adjacent:
+                verify_adjacent(
+                    trusted_header, untrusted_header, untrusted_vals,
+                    trusting_period_ns, now, max_clock_drift_ns,
+                )
+            else:
+                verify_non_adjacent(
+                    trusted_header, trusted_vals, untrusted_header,
+                    untrusted_vals, trusting_period_ns, now,
+                    max_clock_drift_ns, trust_level,
+                )
+            answer = "accepted"
+        except ErrNewValSetCantBeTrusted:
+            answer = "untrusted"
+            raise
+        finally:
+            sp.set(answer=answer)
+            trace.count("light", "hops")
+            if answer == "untrusted":
+                trace.count("light", "hops_untrusted")
 
 
 def verify_with_certificate(

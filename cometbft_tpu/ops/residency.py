@@ -27,7 +27,9 @@ Design:
                host round trip.
   delta update unseen keys (validator-set churn, mempool riders) are
                decompressed host-side and scattered into free/LRU rows
-               — the wire carries only the NEW rows, never the table.
+               — the wire carries only the NEW rows, never the table,
+               in blocks of DELTA_ROWS columns through ONE compiled
+               program whatever their number (no compile per key count).
                Scatters are FUNCTIONAL (jnp .at[].set returns a fresh
                array): an in-flight batch keeps gathering from its own
                immutable snapshot, so concurrent churn can never
@@ -225,19 +227,39 @@ def _init_table_fn():
     return init
 
 
+# columns a delta program takes: ONE compiled geometry a table, whatever
+# the number of new keys. A light client's drifting sets bring anything
+# from 8 to 500 a hop, and a geometry per size would compile on a caller's
+# path again and again. 128 is one lane tile: a delta of 8 keys ships one
+# block of 45.6 kB (2.9 kB of it keys), a whole set of 500 ships four.
+DELTA_ROWS = 128
+# a block's planes: the X, Y, Z, T limbs (4 x 20), the 8 encoding words
+# and the table row each column goes to
+_DELTA_PLANES = 4 * 20 + 8 + 1
+
+
 @functools.lru_cache(maxsize=1)
-def _scatter_fn():
+def _delta_fn():
     jax = _jax()
     jnp = _jnp()
+    from cometbft_tpu.ops import ed25519_kernel as EK
 
     @jax.jit
-    def scatter(tx, ty, tz, tt, te, idx, vals, enc):
-        i = idx.astype(jnp.int32)
+    def key_table_delta(tx, ty, tz, tt, te, block, acc):
+        """One (_DELTA_PLANES, DELTA_ROWS) int32 block scattered into
+        the table, and the block's checksum added to `acc`: a delta of
+        several blocks chains through one accumulator, so the host
+        awaits one word for all of them."""
+        vals = block[:80].reshape(4, 20, DELTA_ROWS)
+        enc = jax.lax.bitcast_convert_type(block[80:88], jnp.uint32)
+        i = block[88]
+        with jax.named_scope("integrity"):
+            acc = acc + EK._device_checksum_expr((block,))
         return (tx.at[:, i].set(vals[0]), ty.at[:, i].set(vals[1]),
                 tz.at[:, i].set(vals[2]), tt.at[:, i].set(vals[3]),
-                te.at[:, i].set(enc))
+                te.at[:, i].set(enc), acc)
 
-    return scatter
+    return key_table_delta
 
 
 class _NoRoom(Exception):
@@ -360,38 +382,40 @@ class KeyTable:
         from cometbft_tpu.libs import trace as _trace
         from cometbft_tpu.ops import ed25519_kernel as EK
 
-        db = EK.bucket_size(len(missing))
-        # batch-minor (4, 20, db) upload block, identity-padded; padding
-        # scatters rewrite the identity row with identity coords — a
-        # deliberate idempotent no-op that keeps the scatter on the
-        # shared bucket ladder (bounded compiled shapes)
-        vals = np.zeros((4, 20, db), dtype=np.int32)
-        vals[1, 0, :] = 1  # Y = 1
-        vals[2, 0, :] = 1  # Z = 1
-        vals[:, :, :len(missing)] = coords.transpose(1, 2, 0)
+        n = len(missing)
+        lanes = -(-n // DELTA_ROWS) * DELTA_ROWS
+        # identity-padded: a padding column rewrites the identity row
+        # with the identity's own coordinates and encoding — a deliberate
+        # idempotent no-op that keeps the delta at its one geometry
+        flat = np.zeros((_DELTA_PLANES, lanes), dtype=np.int32)
+        flat[20] = flat[40] = flat[80] = 1  # Y = 1, Z = 1, word0 = 1
+        flat[88] = self.id_row
+        flat[:80, :n] = coords.transpose(1, 2, 0).reshape(80, n)
         # the compressed-encoding plane rides the same delta: the rows'
-        # raw 32 key bytes as 8 LE words (identity word0=1 padding)
-        enc = np.zeros((8, db), dtype=np.uint32)
-        enc[0, :] = 1
-        enc[:, :len(missing)] = np.frombuffer(
+        # raw 32 key bytes as 8 LE words
+        flat.view(np.uint32)[80:88, :n] = np.frombuffer(
             b"".join(missing), dtype=np.uint8).reshape(-1, 32).view("<u4").T
-        idx = np.full(db, self.id_row, dtype=np.int32)
-        idx[:len(missing)] = rows
-        expected = EK._host_checksum(vals, enc)
-        dev = self._build()
-        scatter = _scatter_fn()
+        flat[88, :n] = rows
+        blocks = [np.ascontiguousarray(flat[:, at:at + DELTA_ROWS])
+                  for at in range(0, lanes, DELTA_ROWS)]
+        expected = sum(EK._host_checksum(b) for b in blocks) & 0xFFFFFFFF
+        nbytes = flat.nbytes
+        delta = _delta_fn()
         for attempt in (1, 2):
             t0 = _time.perf_counter()
-            vals_dev = self._put(vals)
-            enc_dev = self._put(enc)
-            idx_dev = self._put(idx)
-            _jax().block_until_ready((vals_dev, enc_dev, idx_dev))
-            nbytes = vals.nbytes + enc.nbytes + idx.nbytes
+            blocks_dev = [self._put(b) for b in blocks]
+            acc = self._put(np.zeros((), dtype=np.uint32))
+            _jax().block_until_ready(blocks_dev)
             _linkmodel.link().observe_transfer(
                 nbytes, _time.perf_counter() - t0)
             _trace.add_bytes(tx=nbytes)
-            got = int(np.asarray(EK._device_checksum((vals_dev, enc_dev))))
-            count_trip(programs=1, waits=2)
+            # functional: the table is adopted only once every block's
+            # checksum has come back right
+            dev = self._build()
+            for block in blocks_dev:
+                *dev, acc = delta(*dev, block, acc)
+            got = int(np.asarray(acc))
+            count_trip(programs=len(blocks), waits=2)
             if got == expected:
                 break
             self.counters["checksum_retries"] += 1
@@ -400,14 +424,12 @@ class KeyTable:
                 raise RuntimeError(
                     "validator-table delta upload corrupted twice; "
                     "refusing to cache a poisoned row")
-        self._dev = tuple(scatter(*dev, idx_dev, vals_dev, enc_dev))
-        count_trip(programs=1)
+        self._dev = tuple(dev)
         for i, key in enumerate(missing):
             self._rows[key] = rows[i]
             self._ok[key] = bool(ok[i])
         self.counters["delta_updates"] += 1
-        self.counters["delta_rows"] += len(missing)
-        nbytes = vals.nbytes + enc.nbytes + idx.nbytes
+        self.counters["delta_rows"] += n
         record_send(path, nbytes)
         return nbytes
 
@@ -554,7 +576,14 @@ def announce_validator_set(vals) -> None:
     try:
         if getattr(vals, "_wire_announced", False):
             return
-        h = vals.hash()
+        from cometbft_tpu.libs import trace as _trace
+
+        # stage `header` (Merkle roots on the host), not `transfer`,
+        # which stays the wire's time: the root of a set object not seen
+        # yet is host hashing, though the reduced-send protocol asks for it
+        with _trace.span("residency.announce", cat="header",
+                         validators=len(vals.validators)):
+            h = vals.hash()
         if h == _last_announced_hash:
             # another object of the set announced last (a copy of it a
             # height): stamp this one too, or every call hashes it again

@@ -18,7 +18,7 @@ Semantics preserved exactly:
 from __future__ import annotations
 
 from dataclasses import dataclass
-from itertools import compress, islice
+from itertools import compress, islice, repeat
 
 import numpy as np
 
@@ -112,13 +112,15 @@ def _commit_rows(
     below threshold.
 
     One selection, two ways to run it, chosen from what is at hand: a
-    commit of ROW_BLOCK_MIN rows or more whose validators are looked up
-    by index and whose sign-rows the array pass built is selected, tallied
-    and cut with index vectors (_select_block: no object a lane); every
-    other one walks its signatures (_select_lanes: small commits, the
-    trusting check's address lookups, stamps past int64) and turns its
-    lists into the same block with one lane loop. The span says which
-    ran (`path`) and how many rows it handed over (`rows`)."""
+    commit of ROW_BLOCK_MIN rows or more whose sign-rows the array pass
+    built is selected, tallied and cut with index vectors (_select_block:
+    no object a lane; where the validators are looked up by address, the
+    trusting check, the commit's addresses are first joined to the set's
+    indices); every other one walks its signatures (_select_lanes: small
+    commits, stamps past int64) and turns its lists into the same block
+    with one lane loop. The span says which ran (`path`: `block` or
+    `lane`), how many rows it handed over (`rows`) and, on the trusting
+    check, that the set was looked up by `address`."""
     with trace.span("commit.rows", cat="collect") as sp:
         sign_rows = commit.vote_sign_bytes_all(chain_id)
         # epoch-keyed device residency (reduced-send protocol): announce the
@@ -130,18 +132,22 @@ def _commit_rows(
             _residency.announce_validator_set(vals)
         except Exception:  # noqa: BLE001 - residency is an optimization layer
             pass
-        if (lookup_by_index and sign_rows.block is not None
+        if (sign_rows.block is not None
                 and len(commit.signatures) >= ROW_BLOCK_MIN):
             path = "block"
             block, idxs = _select_block(
                 vals, commit, sign_rows, voting_power_needed, commit_only,
-                count_all_signatures)
+                count_all_signatures, lookup_by_index)
         else:
             path = "lane"
             block, idxs = _select_lanes(
                 vals, commit, sign_rows, voting_power_needed, commit_only,
                 count_all_signatures, lookup_by_index)
         sp.set(path=path, rows=len(block))
+        if not lookup_by_index:
+            sp.set(lookup="address")
+            trace.count("trusting_rows",
+                        "joined" if path == "block" else "scanned", len(block))
         return block, idxs
 
 
@@ -183,14 +189,20 @@ def _select_lanes(vals, commit, sign_rows, voting_power_needed,
 
 
 def _select_block(vals, commit, sign_rows, voting_power_needed,
-                  commit_only, count_all_signatures):
+                  commit_only, count_all_signatures, lookup_by_index=True):
     """_commit_rows over columns: the flags and the signatures are read
     from the commit in one pass each (fresh every call: a commit's
     signatures may be set after its sign-rows were built), the keys, key
     types and powers come from the set's cached columns, and selection,
     tally, threshold and the split by key type are index arithmetic. The
-    same rows, the same tally and the same errors as _select_lanes with
-    lookup_by_index."""
+    same rows, the same tally and the same errors as _select_lanes.
+
+    Looked up by index, signature i is validator i's. Looked up by address
+    (the trusting check: the commit is another set's), the commit's
+    addresses are joined to the set's indices through its address map
+    (ValidatorSet.address_index), signatures of validators the set does
+    not have are dropped, and a validator met twice before the tally
+    passes the threshold is refused as the loop refuses it."""
     signatures = commit.signatures
     n = len(signatures)
     cols = vals.columns()
@@ -199,15 +211,21 @@ def _select_block(vals, commit, sign_rows, voting_power_needed,
         flags = np.frombuffer(bytes(flags), dtype=np.uint8)
     except ValueError:  # a flag no commit should carry; the tests below
         flags = np.fromiter(flags, np.int64, n)  # treat it as the loop does
-    if commit_only:
-        taken = flags == BlockIDFlag.COMMIT
-        idxs = np.flatnonzero(taken)
-        power = cols.powers[idxs]
+    taken = (flags == BlockIDFlag.COMMIT if commit_only
+             else flags != BlockIDFlag.ABSENT)
+    if lookup_by_index:
+        idxs = val_idxs = np.flatnonzero(taken)
     else:
-        taken = flags != BlockIDFlag.ABSENT
+        joined = np.fromiter(
+            map(vals.address_index().get,
+                [cs.validator_address for cs in signatures], repeat(-1)),
+            np.intp, n)
+        taken &= joined >= 0
         idxs = np.flatnonzero(taken)
-        power = np.where(flags[idxs] == BlockIDFlag.COMMIT,
-                         cols.powers[idxs], 0)
+        val_idxs = joined[idxs]
+    power = cols.powers[val_idxs]
+    if not commit_only:
+        power = np.where(flags[idxs] == BlockIDFlag.COMMIT, power, 0)
     if count_all_signatures:
         tallied = int(power.sum())
     else:
@@ -217,15 +235,39 @@ def _select_block(vals, commit, sign_rows, voting_power_needed,
         stop = int(np.searchsorted(running, voting_power_needed,
                                    side="right"))
         if stop < len(idxs):
-            idxs = idxs[:stop + 1]
+            idxs, val_idxs = idxs[:stop + 1], val_idxs[:stop + 1]
         tallied = int(running[min(stop, len(running) - 1)]) if len(
             running) else 0
+    if not lookup_by_index:
+        _refuse_double_vote(vals, idxs, val_idxs)
     if tallied <= voting_power_needed:
         raise ErrNotEnoughVotingPowerSigned(got=tallied, needed=voting_power_needed)
     sigs = [cs.signature for cs in signatures]
     if len(idxs) < n:
         sigs = list(islice(compress(sigs, taken.tolist()), len(idxs)))
-    return RowBlock.from_set(cols, idxs, sign_rows.block, sigs), idxs
+    return RowBlock.from_set(cols, val_idxs, sign_rows.block, sigs,
+                             msg_idxs=idxs), idxs
+
+
+def _refuse_double_vote(vals, idxs, val_idxs) -> None:
+    """The loop's double-vote refusal over the rows taken (signatures
+    `idxs` of validators `val_idxs`, cut at the row where the tally
+    passed the threshold): the loop meets a validator's second signature
+    only if it comes no later than that row, and refuses it before it
+    tallies it, whatever the tally would have said. The rows before a
+    second signature hold each validator once, so the cut, taken over a
+    tally that counted the second one too, is the loop's wherever it
+    falls before it."""
+    of_vals = val_idxs.tolist()
+    if len(set(of_vals)) == len(of_vals):
+        return
+    seen: dict[int, int] = {}
+    for idx, val_idx in zip(idxs.tolist(), of_vals):
+        if val_idx in seen:
+            raise ValueError(
+                f"double vote from {vals.validators[val_idx].address.hex()} "
+                f"({seen[val_idx]} and {idx})")
+        seen[val_idx] = idx
 
 
 def _bls_aggregate_ok(pubs, msgs, sigs) -> bool | None:

@@ -17,6 +17,7 @@ import numpy as np
 
 from cometbft_tpu import crypto
 from cometbft_tpu.crypto import merkle
+from cometbft_tpu.libs import trace
 from cometbft_tpu.utils import protobuf as pb
 
 INT64_MAX = (1 << 63) - 1
@@ -217,6 +218,9 @@ class ValidatorSet:
     # the set's keys and powers as columns (columns()); a class default so
     # that a set made with __new__ (copy, from_proto, the stores) has it
     _columns: SetColumns | None = None
+    # (the list it was read from, its length, {address: index}): see
+    # address_index()
+    _addr_index: tuple | None = None
 
     def __init__(self, validators: list[Validator]):
         self.validators: list[Validator] = sorted(
@@ -243,6 +247,10 @@ class ValidatorSet:
         new._total_voting_power = self._total_voting_power
         if self._columns is not None and self._columns.src is self.validators:
             new._columns = self._columns.rebound(new.validators)
+        if self._addr_index is not None:
+            # the copies have the addresses and the order of the originals
+            new._addr_index = (new.validators, len(new.validators),
+                               self.address_index())
         return new
 
     def columns(self) -> SetColumns:
@@ -271,14 +279,30 @@ class ValidatorSet:
             self._update_total_voting_power()
         return self._total_voting_power
 
+    def address_index(self) -> dict[bytes, int]:
+        """{address: index of the first validator that has it}, made once
+        a set and kept as columns() are: until the list is replaced or
+        changes in length (update_with_change_set drops it; copy() hands
+        it on). What get_by_address looks up, and what the trusting
+        check joins a commit's addresses to (types/validation.py)."""
+        kept = self._addr_index
+        if (kept is None or kept[0] is not self.validators
+                or kept[1] != len(self.validators)):
+            index: dict[bytes, int] = {}
+            for i, v in enumerate(self.validators):
+                index.setdefault(v.address, i)
+            kept = self._addr_index = (
+                self.validators, len(self.validators), index)
+        return kept[2]
+
     def has_address(self, address: bytes) -> bool:
-        return any(v.address == address for v in self.validators)
+        return address in self.address_index()
 
     def get_by_address(self, address: bytes) -> tuple[int, Validator | None]:
-        for i, v in enumerate(self.validators):
-            if v.address == address:
-                return i, v.copy()
-        return -1, None
+        i = self.address_index().get(address)
+        if i is None:
+            return -1, None
+        return i, self.validators[i].copy()
 
     def get_by_index(self, index: int) -> tuple[bytes, Validator | None]:
         if index < 0 or index >= len(self.validators):
@@ -354,6 +378,7 @@ class ValidatorSet:
 
     def hash(self) -> bytes:
         """Merkle root of SimpleValidator leaves (validator_set.go:347-353)."""
+        trace.count("valset", "hashes")
         return merkle.hash_from_byte_slices([v.bytes_() for v in self.validators])
 
     # -------------------------------------------------------------- updates
@@ -398,6 +423,7 @@ class ValidatorSet:
             raise ValueError("total voting power would exceed maximum")
 
         self._columns = None  # keys and powers change in place from here
+        self._addr_index = None
         for u in updates:
             existing = by_addr.get(u.address)
             if existing is not None:

@@ -152,6 +152,23 @@ def test_trip_verify_program_compiles_for_v5e_commit_shape(
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_key_table_delta_compiles_for_v5e(one_chip, no_persistent_cache):
+    """The key table's one delta program (ops/residency.py) at the
+    default 16,384-row table: a block of DELTA_ROWS columns scattered,
+    its checksum chained."""
+    from cometbft_tpu.ops import residency
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = 16384
+    args = [*[arg((20, rows), jnp.int32)] * 4, arg((8, rows), jnp.uint32),
+            arg((residency._DELTA_PLANES, residency.DELTA_ROWS), jnp.int32),
+            arg((), jnp.uint32)]
+    compiled = residency._delta_fn().lower(*args).compile()
+    assert compiled.memory_analysis() is not None
+
+
 def test_pallas_verify_interpret_matches_host_oracle():
     """pallas_verify.verify_pallas_ok in interpret mode at one 128-lane
     block: every lane agrees with the exact ZIP-215 host oracle, the one

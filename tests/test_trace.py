@@ -60,9 +60,9 @@ def _arm(clock=None, capacity=1024, slow_ms=-1.0, slow_captures=4):
 
 def _model_part(attr: dict) -> dict:
     """What attribution_of() replays from spans: the live reading less
-    the switch and the collector's plain counts."""
+    the switch, the collector's plain counts and those of trace.count()."""
     return {k: v for k, v in attr.items()
-            if k not in ("enabled", "gc_collections")}
+            if k not in ("enabled", "gc_collections", *trace.COUNTS)}
 
 
 # ----------------------------------------------------------------- spans
@@ -292,7 +292,8 @@ class TestAttribution:
         assert set(attr) == {
             "stage_us", "stage_share", "total_us", "rows", "wire_tx_bytes",
             "wire_rx_bytes", "bytes_per_sig_tx", "bytes_per_sig_rx",
-            "sign_rows", "commit_rows", "gc_collections", "enabled"}
+            "sign_rows", "commit_rows", "gc_collections", "enabled",
+            *trace.COUNTS}
         assert tuple(attr["stage_us"]) == trace.STAGES
         assert {"node", "signbytes", "collect", "gc"} < set(trace.STAGES)
         us = attr["stage_us"]

@@ -198,14 +198,47 @@ def test_capacity_overflow_serves_from_full_key_path():
     assert s["full"]["sigs"] == 100
 
 
+@pytest.mark.parametrize("new_keys", [5, 128, 200])
+def test_a_delta_of_any_size_runs_one_compiled_geometry(new_keys):
+    """New keys reach the table in blocks of DELTA_ROWS columns through
+    ONE program, whatever their number (a light client's drifting sets
+    bring 8 to 500 a hop: no compile per key count), one awaited checksum
+    for all the blocks; the rows gather as the host decompressed them."""
+    delta = residency._delta_fn()
+    residency.reset_send_stats()
+    tbl = residency.table_for(K._default_cache)
+    tbl.stage(_sign_n(3)[0], 8)            # the table's first delta
+    compiled = delta._cache_size()
+    trips = residency.trip_stats()
+    pubs = _sign_n(new_keys)[0]
+    ok_a, coords, _nbytes = tbl.stage(pubs, 256)
+    blocks = -(-new_keys // residency.DELTA_ROWS)
+    assert delta._cache_size() == compiled == 1
+    now = residency.trip_stats()
+    assert now["device_programs"] - trips["device_programs"] == blocks + 1
+    assert now["blocking_waits"] - trips["blocking_waits"] == 2
+    assert residency.send_stats()["delta"]["bytes"] == (
+        (1 + blocks) * residency._DELTA_PLANES * residency.DELTA_ROWS * 4)
+    assert ok_a.all() and tbl.stats()["delta_rows"] == 3 + new_keys
+    _ok, want = K._default_cache.lookup_or_decompress(pubs)
+    got = np.stack([np.asarray(c) for c in coords])[:, :, :new_keys]
+    assert (got == want.transpose(1, 2, 0)).all()
+    # padding lanes gather the identity row, untouched by any block
+    pad = np.stack([np.asarray(c) for c in coords])[:, :, new_keys:]
+    assert (pad[[0, 3]] == 0).all() and (pad[[1, 2], 0] == 1).all()
+
+
 def test_poisoned_delta_upload_degrades_not_wrong(monkeypatch):
     """A delta upload whose device checksum fails twice must abandon the
     indexed path for that batch (full-key fallback), never cache the
     poisoned row."""
-    import numpy as _np
+    delta = residency._delta_fn()
 
-    monkeypatch.setattr(K, "_device_checksum",
-                        lambda dev: _np.uint32(1))
+    def poisoned(*args):
+        *table, acc = delta(*args)
+        return (*table, acc + 1)
+
+    monkeypatch.setattr(residency, "_delta_fn", lambda: poisoned)
     pubs, msgs, sigs = _sign_n(8)
     ok, mask = K.verify_batch(pubs, msgs, sigs)
     assert ok and all(mask)  # served correctly by the fallback ladder
