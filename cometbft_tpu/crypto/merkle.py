@@ -39,13 +39,21 @@ def get_split_point(n: int) -> int:
 
 
 def hash_from_byte_slices(items: list[bytes]) -> bytes:
-    n = len(items)
-    if n == 0:
+    """The root, level by level over the leaf hashes: pair left to right
+    and carry an odd last node up. That is the tree the reference splits
+    at get_split_point (tree.go:11-40): a left subtree of a power of two
+    is filled by the pairs before any of the right one is."""
+    if not items:
         return empty_hash()
-    if n == 1:
-        return leaf_hash(items[0])
-    k = get_split_point(n)
-    return inner_hash(hash_from_byte_slices(items[:k]), hash_from_byte_slices(items[k:]))
+    sha256 = hashlib.sha256
+    level = [sha256(_LEAF_PREFIX + item).digest() for item in items]
+    while len(level) > 1:
+        paired = [sha256(_INNER_PREFIX + level[i] + level[i + 1]).digest()
+                  for i in range(0, len(level) - 1, 2)]
+        if len(level) & 1:
+            paired.append(level[-1])
+        level = paired
+    return level[0]
 
 
 @dataclass

@@ -125,11 +125,11 @@ _ROW_PATHS = {"commit.sign_bytes": ("sign_rows", SIGN_ROW_PATHS),
 # client's hops and those of them answered "cannot be trusted"
 # (light/verifier.verify; the span's `answer` has the rest), the rows the
 # trusting check selected by the address join or by get_by_address a
-# signature (types/validation._commit_rows), the Merkle roots of
-# validator sets computed (ValidatorSet.hash).
+# signature (types/validation._commit_rows), the Merkle roots of validator
+# sets computed and the calls the kept root answered (ValidatorSet.hash).
 COUNTS = {"light": ("hops", "hops_untrusted"),
           "trusting_rows": ("joined", "scanned"),
-          "valset": ("hashes",)}
+          "valset": ("hashes", "kept")}
 
 
 def _no_rows_by_path() -> dict:

@@ -568,10 +568,10 @@ def announce_validator_set(vals) -> None:
     """Register the active validator set for epoch-keyed residency
     (validation.py calls this on every commit verification). Never
     raises — residency is an optimization layer. A per-object stamp
-    makes repeat announcements of the same ValidatorSet object free
-    (ValidatorSet.hash() is an uncached O(N) merkle root); a set
-    mutated after stamping just pins one epoch late, which costs delta
-    bytes, never correctness (rows are content-keyed)."""
+    makes repeat announcements of the same ValidatorSet object free; one
+    without it is asked for its root, which a set keeps once computed
+    (ValidatorSet.hash()). A set mutated after stamping just pins one epoch
+    late, which costs delta bytes, never correctness (content-keyed rows)."""
     global _last_announced_hash
     try:
         if getattr(vals, "_wire_announced", False):

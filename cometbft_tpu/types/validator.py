@@ -122,11 +122,15 @@ class Validator:
         )
 
 
+# crypto.PublicKey oneof (proto/tendermint/crypto/keys.proto): key type ->
+# field number
+_KEY_FIELDS = {"ed25519": 1, "secp256k1": 2, "sr25519": 3, "bls12381": 4}
+
+
 def pub_key_to_proto(pub_key: crypto.PubKey) -> bytes:
     """crypto.PublicKey oneof: ed25519=1 bytes, secp256k1=2 bytes
     (proto/tendermint/crypto/keys.proto)."""
-    field_num = {"ed25519": 1, "secp256k1": 2, "sr25519": 3,
-                 "bls12381": 4}.get(pub_key.type_())
+    field_num = _KEY_FIELDS.get(pub_key.type_())
     if field_num is None:
         raise ValueError(f"unsupported pubkey type {pub_key.type_()}")
     return pb.Writer().bytes(field_num, pub_key.bytes_(), always=True).output()
@@ -154,6 +158,40 @@ def pub_key_from_proto(data: bytes) -> crypto.PubKey:
             return bls12381.PubKey(r.read_bytes())
         r.skip(w)
     raise ValueError("empty/unsupported PublicKey proto")
+
+
+def _leaf_head(field_num: int, key_size: int) -> bytes:
+    """What a SimpleValidator leaf has before its key's bytes: tag and
+    length of pub_key = 1, then tag and length of the oneof's field."""
+    inner = pb.encode_uvarint(field_num << 3 | 2) + pb.encode_uvarint(key_size)
+    return b"\x0a" + pb.encode_uvarint(len(inner) + key_size) + inner
+
+
+def _leaves(validators: list[Validator]) -> list[bytes]:
+    """Validator.bytes_() of every validator, byte for byte, in one loop:
+    a key type of the PublicKey oneof has a fixed head for its key size,
+    then come the key and, unless the power is 0, 0x10 and its varint.
+    Any other key type goes through bytes_() (which refuses it)."""
+    heads: dict[tuple[str, int], bytes] = {}
+    tails: dict[int, bytes] = {0: b""}
+    leaves = []
+    for v in validators:
+        pub_key = v.pub_key
+        key = pub_key.bytes_()
+        kind = (pub_key.type_(), len(key))
+        head = heads.get(kind)
+        if head is None:
+            field_num = _KEY_FIELDS.get(kind[0])
+            if field_num is None:
+                leaves.append(v.bytes_())
+                continue
+            head = heads[kind] = _leaf_head(field_num, kind[1])
+        power = v.voting_power
+        tail = tails.get(power)
+        if tail is None:
+            tail = tails[power] = b"\x10" + pb.encode_varint_i64(power)
+        leaves.append(head + key + tail)
+    return leaves
 
 
 class SetColumns:
@@ -221,6 +259,9 @@ class ValidatorSet:
     # (the list it was read from, its length, {address: index}): see
     # address_index()
     _addr_index: tuple | None = None
+    # (the list it was computed from, its length, the Merkle root): see
+    # hash(). Written by hash() and copy() alone, never from the wire
+    _merkle_root: tuple | None = None
 
     def __init__(self, validators: list[Validator]):
         self.validators: list[Validator] = sorted(
@@ -251,13 +292,20 @@ class ValidatorSet:
             # the copies have the addresses and the order of the originals
             new._addr_index = (new.validators, len(new.validators),
                                self.address_index())
+        root = self._kept_root()
+        if root is not None:
+            # the copies have the keys and the powers of the originals
+            new._merkle_root = (new.validators, len(new.validators), root)
         return new
 
     def columns(self) -> SetColumns:
         """Keys, key types and powers as columns, read once from the set
         and kept until update_with_change_set changes a key or a power
         (priority moves touch neither; copy() carries them over). Nothing
-        else in the repo writes a Validator's key or power in place."""
+        else in the repo writes a Validator's key or power in place, or
+        puts another Validator into the list of a set: the Merkle root
+        (hash()) is kept on the same terms, and a writer that does not
+        drop it would leave a root of validators the set no longer has."""
         cols = self._columns
         if (cols is None or cols.src is not self.validators
                 or cols.n != len(self.validators)):
@@ -377,9 +425,27 @@ class ValidatorSet:
     # ---------------------------------------------------------------- hash
 
     def hash(self) -> bytes:
-        """Merkle root of SimpleValidator leaves (validator_set.go:347-353)."""
+        """Merkle root of SimpleValidator leaves (validator_set.go:347-353),
+        computed once a set and kept as columns() are: until the list is
+        replaced or changes in length (update_with_change_set drops it;
+        copy() hands it on). Proposer priority is not in a leaf, so the
+        rotation keeps it."""
+        root = self._kept_root()
+        if root is not None:
+            trace.count("valset", "kept")
+            return root
         trace.count("valset", "hashes")
-        return merkle.hash_from_byte_slices([v.bytes_() for v in self.validators])
+        validators = self.validators
+        root = merkle.hash_from_byte_slices(_leaves(validators))
+        self._merkle_root = (validators, len(validators), root)
+        return root
+
+    def _kept_root(self) -> bytes | None:
+        kept = self._merkle_root
+        if (kept is None or kept[0] is not self.validators
+                or kept[1] != len(self.validators)):
+            return None
+        return kept[2]
 
     # -------------------------------------------------------------- updates
 
@@ -424,6 +490,7 @@ class ValidatorSet:
 
         self._columns = None  # keys and powers change in place from here
         self._addr_index = None
+        self._merkle_root = None
         for u in updates:
             existing = by_addr.get(u.address)
             if existing is not None:

@@ -506,14 +506,16 @@ def test_a_hops_spans_and_counters():
     under = [s["name"] for s in spans if s["parent_id"] == hops[0]["id"]]
     assert under.count("commit.stage_verify") == 2
     assert under.count("commit.prefetch") == 1
-    # Merkle roots of validator sets: the header check's, and one where a
-    # set object is announced that carries no stamp (the trusted set once,
-    # the new set of every hop that reaches its own check)
+    # Merkle roots of validator sets: a set object is announced where it
+    # carries no stamp (the trusted set once, the new set of every hop that
+    # reaches its own check). Computed: the five header checks' and the
+    # trusted set's one announce; the new sets' three announces find the
+    # root their header check left on the set
     announced = [s for s in spans if s["name"] == "residency.announce"]
     assert all(s["cat"] == "header" and s["attrs"]["validators"] == 37
                for s in announced)
     assert len(announced) == 1 + 3
-    assert att["valset"] == {"hashes": 5 + len(announced)}
+    assert att["valset"] == {"hashes": 5 + 1, "kept": 3}
     # the trusting check at 37 rows walks the lanes (under ROW_BLOCK_MIN)
     assert att["trusting_rows"]["joined"] == 0
     assert att["trusting_rows"]["scanned"] == 2 * len(
@@ -530,6 +532,10 @@ def test_nothing_is_counted_while_the_tracer_is_off():
     trace.count("light", "hops")
     trace.configure(enabled=True)
     att = trace.attribution()
-    assert att["valset"] == {"hashes": 0} and att["light"]["hops"] == 0
+    assert att["valset"] == {"hashes": 0, "kept": 0}
+    assert att["light"]["hops"] == 0
+    # a set object of its own: `trusted` keeps the root it computed above,
+    # and a copy() of it would too
+    ValidatorSet(trusted.validators).hash()
     trusted.hash()
-    assert trace.attribution()["valset"] == {"hashes": 1}
+    assert trace.attribution()["valset"] == {"hashes": 1, "kept": 1}
