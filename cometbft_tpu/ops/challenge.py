@@ -20,8 +20,13 @@ host challenge pipeline in ops/hashvec.py:
   prefix/tail table         a 256-row device-resident table of
                             prefix||tail byte rows, content-keyed, so a
                             vote lane's message descriptor is a 2-byte
-                            (flag|prefix-id) plus only the ~10-24
-                            variable suffix bytes. A new row (a new
+                            (flag|width|prefix-id) plus only the ~10-24
+                            variable suffix bytes. One row serves every
+                            stamp width of a height: its byte 0 is the
+                            front's first byte less the suffix width
+                            (for a vote, the length varint less what
+                            the stamp varies), and the derive adds the
+                            lane's width back. A new row (a new
                             height) rides the batch's own upload: the
                             wire block carries up to CARRY_ROWS dirty
                             rows at its tail, the derive program sets
@@ -44,9 +49,12 @@ Wire layout (one flat uint32 block, ed25519_kernel stages it):
   words[0      : 8b ]   R encoding words, (8, b) word-major
   words[8b     : 16b]   s scalar words, (8, b) word-major
   words[16b    : W  ]   descriptor stream: 2*b bytes of per-lane uint16
-                        LE descriptors (bit15 = device-derive flag, low
-                        15 bits = prefix-table row), then b lanes of
-                        `var` variable suffix bytes, lane-contiguous
+                        LE descriptors (bit15 = device-derive flag, bits
+                        8-10 = how many bytes the lane's variable suffix
+                        is narrower than `var`, low 8 bits = prefix-table
+                        row), then b lanes of `var` variable suffix
+                        bytes, lane-contiguous, a narrower lane's
+                        zero-padded
   words[W      : W+C]   C = CARRY_WORDS: the prefix table's carried rows,
                         CARRY_ROWS row indices, then as many rows of
                         PREFIX_CAP bytes as LE words
@@ -85,6 +93,12 @@ CARRY_ROWS = 8    # dirty table rows ONE derive call carries: a catch-up
 CARRY_WORDS = CARRY_ROWS * (1 + PREFIX_CAP // 4)  # indices, then rows
 CARRY_BYTES = 4 * CARRY_WORDS
 MIN_ELIGIBLE_FRAC = 0.5  # mostly-fallback batches take the classic path
+WIDTH_SPAN = 7    # a lane's variable suffix may be up to this many bytes
+                  # narrower than the plan's widest (a vote's stamp: the
+                  # nanos field omitted against a 5-byte varint is 6); the
+                  # shortfall rides the descriptor's bits 8-10, and the
+                  # derive program selects among the span's layouts
+assert TABLE_ROWS <= 256  # a row index is the descriptor's low 8 bits
 
 # ------------------------------------------------------------------ config
 
@@ -178,6 +192,13 @@ def _compress_pairs(whi, wlo):
     arrays [h0hi, h0lo, ..., h7hi, h7lo] — FIPS 180-4 compression, all N
     lanes through each round together (the device twin of
     hashvec._sha512_blocks_numpy)."""
+    return _compress_blocks(whi, wlo)[-1]
+
+
+def _compress_blocks(whi, wlo):
+    """_compress_pairs, with the state after EVERY block: a list of nb
+    16-tuples, so lanes whose padded messages end in different blocks
+    read their own digest off one compression."""
     import jax
     import jax.numpy as jnp
 
@@ -185,6 +206,7 @@ def _compress_pairs(whi, wlo):
     khi = jnp.asarray(_K_HI_NP)
     klo = jnp.asarray(_K_LO_NP)
     state = []
+    states = []
     for i in range(8):
         state.append(jnp.full((n,), _H0_HI[i], dtype=jnp.uint32))
         state.append(jnp.full((n,), _H0_LO[i], dtype=jnp.uint32))
@@ -236,7 +258,8 @@ def _compress_pairs(whi, wlo):
             nxt.append(sh)
             nxt.append(sl)
         state = nxt
-    return tuple(state)
+        states.append(tuple(state))
+    return states
 
 
 # ----------------------------------------- Barrett reduction mod L (device)
@@ -648,32 +671,36 @@ def reset() -> None:
 
 class Plan:
     """One batch's device-challenge shape, frozen at plan time: the
-    static message geometry the derive program compiles against, the
-    per-lane descriptor assignment, and the table the in-flight batch
-    gathers from: the immutable confirmed snapshot `dev_tab` with the
-    plan's own copy of the dirty rows (`carry`, the block's tail;
-    `n_carried` of the CARRY_ROWS are real, the rest padding) set over it
-    in-program."""
+    static message geometry the derive program compiles against (`var`
+    the WIDEST admitted variable suffix), the per-lane descriptor
+    assignment (row, and `short`: how many bytes narrower than `var` the
+    lane's suffix is), and the table the in-flight batch gathers from:
+    the immutable confirmed snapshot `dev_tab` with the plan's own copy
+    of the dirty rows (`carry`, the block's tail; `n_carried` of the
+    CARRY_ROWS are real, the rest padding) set over it in-program."""
 
-    __slots__ = ("plen", "tlen", "var", "slen", "pids", "eligible",
+    __slots__ = ("plen", "tlen", "var", "slen", "pids", "short", "eligible",
                  "vbytes", "dev_tab", "carry", "n_carried", "n",
-                 "n_eligible", "n_fallback", "put_key", "_table", "_gens")
+                 "n_eligible", "n_fallback", "n_joined", "put_key",
+                 "_table", "_gens")
 
-    def __init__(self, *, plen, tlen, var, slen, pids, eligible, vbytes,
-                 table, carried, n, n_eligible, n_fallback, put_key):
+    def __init__(self, *, plen, tlen, var, pids, short, eligible, vbytes,
+                 table, carried, n, n_fallback, put_key):
         self.plen = plen
         self.tlen = tlen
         self.var = var
-        self.slen = slen
+        self.slen = var + tlen
         self.pids = pids
+        self.short = short
         self.eligible = eligible
         self.vbytes = vbytes
         self._table = table
         self.dev_tab, self.carry, self._gens = carried
         self.n_carried = len(self._gens)
         self.n = n
-        self.n_eligible = n_eligible
+        self.n_eligible = int(eligible.sum())
         self.n_fallback = n_fallback
+        self.n_joined = int((eligible & (short > 0)).sum())
         self.put_key = put_key
 
     def adopt(self, new_tab) -> bool:
@@ -685,14 +712,56 @@ class Plan:
         return self._table.adopt(self.dev_tab, new_tab, self._gens)
 
 
+def _common_tail(mats, slen: int) -> int:
+    """Bytes at the end of every row of the (k, slen) suffix matrices
+    `mats` that are the same in every row: a vote flush's chain-id
+    trailer."""
+    ref = mats[0][0]
+    eq = np.ones(slen, dtype=bool)
+    for rows in mats:
+        eq &= (rows == ref).all(axis=0)
+    tlen = 0
+    while tlen < slen and eq[slen - 1 - tlen]:
+        tlen += 1
+    return tlen
+
+
+def _front_matches(msgs, live, plen: int):
+    """The live lanes whose front is `plen` bytes long: [(front, lanes,
+    the lanes' (k, w) suffix matrix)], a class at a time. Whole rows with
+    no front (a commit's off-length stamps where the scalar builder, or a
+    lane loop, left them as exception bytes) are cut at `plen` and join
+    where their front is such a class's but for byte 0 (the length
+    varint, which follows the width); a NIL vote's shorter head and a
+    foreign message do not."""
+    fits, plain = [], []
+    for c, lanes in live:
+        front, body = msgs.fronts[c], msgs.bodies[c]
+        if len(front) == plen:
+            fits.append((front, lanes, body[msgs.pos[lanes]]))
+        elif not front and plen and body.shape[1] > plen:
+            plain.append((c, lanes))
+    heads = {f[1:] for f, _lanes, _sfx in fits}
+    for c, lanes in plain:
+        rows = msgs.bodies[c][msgs.pos[lanes]]
+        fronts, group = np.unique(rows[:, :plen], axis=0,
+                                  return_inverse=True)
+        for g, f in enumerate(fronts):
+            if f[1:].tobytes() in heads:
+                at = group.reshape(-1) == g
+                fits.append((f.tobytes(), lanes[at], rows[at, plen:]))
+    return fits
+
+
 def plan_batch(msgs, pre_ok, put_key: str = "", device=None) -> Plan | None:
     """Decide the degradation rung for one batch: a Plan when device
-    challenge derivation wins (dominant (prefix-len, suffix-len) combo
-    covers most live lanes, messages fit the static compile ladder, the
-    challenge breaker admits, the table has a snapshot), else None — the
-    caller stays on the bit-identical host-challenge path. Lanes outside the
-    dominant combo or missing a table row become per-lane host
-    fallbacks inside the Plan, never verdict changes."""
+    challenge derivation wins (the dominant (prefix-len, suffix-len)
+    combo, with the classes that differ from it only in the suffix's
+    width, covers most live lanes, messages fit the static compile
+    ladder, the challenge breaker admits, the table has a snapshot), else
+    None — the caller stays on the bit-identical host-challenge path.
+    Lanes outside those classes or missing a table row become per-lane
+    host fallbacks inside the Plan, never verdict changes."""
     n = len(msgs)
     if not _cfg["enabled"]:
         count("plan_disabled")
@@ -713,7 +782,8 @@ def plan_batch(msgs, pre_ok, put_key: str = "", device=None) -> Plan | None:
     # a commit's rows arrive so): what follows reads index vectors and
     # matrices, and walks classes, never lanes
     msgs = MsgBlock.of(msgs)
-    live = msgs.take(np.flatnonzero(pre_ok)).present()
+    ok_idx = np.flatnonzero(pre_ok)
+    live = [(c, ok_idx[lanes]) for c, lanes in msgs.take(ok_idx).present()]
     combos: dict[tuple[int, int], int] = {}
     for c, lanes in live:
         key = (len(msgs.fronts[c]), msgs.bodies[c].shape[1])
@@ -721,55 +791,65 @@ def plan_batch(msgs, pre_ok, put_key: str = "", device=None) -> Plan | None:
     if not combos:
         count("plan_no_ok_lanes")
         return None
-    (plen, slen), nc = max(combos.items(), key=lambda kv: kv[1])
-    n_ok = int(pre_ok.sum())
+    (plen, slen), _ = max(combos.items(), key=lambda kv: kv[1])
+    n_ok = len(ok_idx)
     if plen + slen > MAX_MLEN or plen > PREFIX_CAP:
         count("plan_oversize")
         return None
+    # the batch-common trailing run (vote rows: the chain-id trailer
+    # after the per-lane timestamp) rides the table row, not the wire
+    cands = _front_matches(msgs, live, plen)
+    dom = [sfx for _f, _lanes, sfx in cands if sfx.shape[1] == slen]
+    tlen = min(_common_tail(dom, slen), PREFIX_CAP - plen)
+    if slen - tlen > MAX_VAR:
+        count("plan_oversize_var")
+        return None
+    tail = dom[0][0, slen - tlen:]
+    # the classes that differ from the dominant geometry only in the
+    # suffix's width (a vote's stamp, and with it the length varint in
+    # the front's byte 0) join it, lane by lane where the suffix ends in
+    # the tail; the widest sets the plan's `var`, the narrowest lie
+    # within WIDTH_SPAN of it
+    fits = []
+    for front, lanes, sfx in cands:
+        w = sfx.shape[1]
+        if (abs(w - slen) > WIDTH_SPAN or not tlen <= w <= tlen + MAX_VAR
+                or plen + w > MAX_MLEN):
+            continue
+        if tlen and w != slen:  # the dominant width's lanes end in it
+            ends = (sfx[:, w - tlen:] == tail).all(axis=1)
+            if not ends.all():
+                lanes, sfx = lanes[ends], sfx[ends]
+        if len(lanes):
+            fits.append((front, lanes, sfx))
+    top = max(sfx.shape[1] for _f, _lanes, sfx in fits)
+    fits = [f for f in fits if f[2].shape[1] >= top - WIDTH_SPAN]
+    nc = sum(len(lanes) for _f, lanes, _sfx in fits)
     if nc < MIN_LANES or nc < MIN_ELIGIBLE_FRAC * n_ok:
         count("plan_low_eligibility")
         return None
-    # the classes of the dominant geometry, and their live lanes
-    conforming = [c for c, _ in live
-                  if (len(msgs.fronts[c]), msgs.bodies[c].shape[1])
-                  == (plen, slen)]
-    conf = pre_ok & np.isin(msgs.cls, conforming)
-    cidx = np.flatnonzero(conf)
-    sfx = np.empty((len(cidx), slen), dtype=np.uint8)
-    ccls = msgs.cls[cidx]
-    for c in conforming:
-        at = np.flatnonzero(ccls == c)
-        sfx[at] = msgs.bodies[c][msgs.pos[cidx[at]]]
-    if slen:
-        # the batch-common trailing run (vote rows: the chain-id trailer
-        # after the per-lane timestamp) rides the table row, not the wire
-        eqcols = (sfx == sfx[0]).all(axis=0)
-        tlen = 0
-        for j in range(slen - 1, -1, -1):
-            if not eqcols[j]:
-                break
-            tlen += 1
-    else:
-        tlen = 0
-    tlen = min(tlen, PREFIX_CAP - plen)
-    var = slen - tlen
-    if var > MAX_VAR:
-        count("plan_oversize_var")
-        return None
-    tail = sfx[0, slen - tlen:].tobytes() if tlen else b""
+    var = top - tlen
+    tail = tail.tobytes()
     tab = table(put_key, device=device)
     pids = np.full(n, -1, dtype=np.int32)
+    short = np.zeros(n, dtype=np.uint8)
+    vbytes = np.zeros((n, var), dtype=np.uint8)
     protect: set[int] = set()
     misses = 0
-    for c in conforming:  # in the order of each class's first live lane
-        at = cidx[ccls == c]
-        pid = tab.ensure(msgs.fronts[c], tail, protect=protect,
-                         lanes=len(at))
+    for front, lanes, sfx in fits:
+        w = sfx.shape[1]
+        if plen:
+            # what every width of the row shares: byte 0 less the width
+            # (the derive adds the lane's back)
+            front = bytes([(front[0] - w) & 0xFF]) + front[1:]
+        pid = tab.ensure(front, tail, protect=protect, lanes=len(lanes))
         if pid is None:
-            misses += len(at)
+            misses += len(lanes)
             continue
         protect.add(pid)
-        pids[at] = pid
+        pids[lanes] = pid
+        short[lanes] = top - w
+        vbytes[lanes, :w - tlen] = sfx[:, :w - tlen]
     if misses:
         count("lane_table_miss", misses)
     eligible = pids >= 0
@@ -782,15 +862,15 @@ def plan_batch(msgs, pre_ok, put_key: str = "", device=None) -> Plan | None:
     if carried is None:
         count("plan_upload_failed")
         return None
-    vbytes = np.zeros((n, var), dtype=np.uint8)
-    if var:
-        vbytes[cidx] = sfx[:, :var]
+    plan = Plan(plen=plen, tlen=tlen, var=var, pids=pids, short=short,
+                eligible=eligible, vbytes=vbytes, table=tab, carried=carried,
+                n=n, n_fallback=n_ok - ne, put_key=put_key)
     count("plans")
     count("lanes_device", ne)
     count("lanes_host_fallback", n_ok - ne)
-    return Plan(plen=plen, tlen=tlen, var=var, slen=slen, pids=pids,
-                eligible=eligible, vbytes=vbytes, table=tab, carried=carried,
-                n=n, n_eligible=ne, n_fallback=n_ok - ne, put_key=put_key)
+    if plan.n_joined:
+        count("lanes_width_joined", plan.n_joined)
+    return plan
 
 
 # ------------------------------------------------------------- wire packing
@@ -810,10 +890,10 @@ def block_words(bucket: int, var: int) -> int:
 
 def fill_stream(block: np.ndarray, bucket: int, plan: Plan) -> None:
     """Pack the descriptor stream of a leased flat block in place:
-    per-lane uint16 LE descriptors (bit15 = derive-on-device, low 15
-    bits = prefix-table row; 0 for padding/fallback lanes), then the
-    lane-contiguous variable suffix bytes; and behind it the plan's
-    carried table rows."""
+    per-lane uint16 LE descriptors (bit15 = derive-on-device, bits 8-10
+    = the lane's width shortfall, low 8 bits = prefix-table row; 0 for
+    padding/fallback lanes), then the lane-contiguous variable suffix
+    bytes; and behind it the plan's carried table rows."""
     sw = stream_words(bucket, plan.var)
     block[16 * bucket + sw:] = plan.carry
     sb = block[16 * bucket:16 * bucket + sw].view(np.uint8)
@@ -822,7 +902,8 @@ def fill_stream(block: np.ndarray, bucket: int, plan: Plan) -> None:
     desc = sb[:2 * bucket].view("<u2")
     vals = np.zeros(n, dtype=np.uint16)
     el = plan.eligible
-    vals[el] = (0x8000 | plan.pids[el]).astype(np.uint16)
+    vals[el] = (0x8000 | (plan.short[el].astype(np.int32) << 8)
+                | plan.pids[el]).astype(np.uint16)
     desc[:n] = vals
     if plan.var:
         v = sb[2 * bucket:2 * bucket + bucket * plan.var]
@@ -848,6 +929,19 @@ def _words_to_bytes(w):
     wt = jnp.transpose(w)  # (B, 8)
     parts = jnp.stack([(wt >> (8 * k)) & 0xFF for k in range(4)], axis=-1)
     return parts.reshape(wt.shape[0], 32).astype(jnp.uint8)
+
+
+def _pad_tail(tot: int, width: int) -> np.ndarray:
+    """SHA-512's padding of a `tot`-byte message (0x80, zeros, the 128-bit
+    bit length at the end of its last block), zero-filled to `width` bytes
+    from `tot`: the blocks past a shorter message's last are never read."""
+    nb = (tot + 17 + 127) // 128
+    pad = np.zeros(width - tot, dtype=np.uint8)
+    pad[0] = 0x80
+    end = nb * 128 - tot
+    pad[end - 16:end] = np.frombuffer((tot * 8).to_bytes(16, "big"),
+                                      dtype=np.uint8)
+    return pad
 
 
 @functools.lru_cache(maxsize=32)
@@ -881,6 +975,16 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int):
            repeated real index — the scatter is idempotent). fb == 0
            omits both.
 
+    A lane's message is R || A || the row's front (byte 0 plus the
+    lane's suffix width) || its own variable bytes || the row's tail,
+    `var` - short bytes of its own where short (descriptor bits 8-10,
+    at most WIDTH_SPAN) says how much narrower than `var` the lane is.
+    The bytes from the narrowest lane's first difference on are one of
+    the span's static layouts, selected a lane; a lane whose message
+    ends a block earlier reads the state after that block. So the
+    program follows the geometry (the widest `var`), never the widths a
+    batch holds.
+
     Everything whose shape follows the geometry ends HERE, so that the
     verify program behind it (the ladder: a minute of tracing and
     compiling a shape) is built per bucket and not per geometry: rw, sw
@@ -899,13 +1003,11 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int):
     from cometbft_tpu.ops import ed25519_kernel as EK
 
     count("derive_programs")  # lru miss: a program for a new geometry
-    tot = 64 + plen + var + tlen
-    nb = (tot + 17 + 127) // 128
-    padlen = nb * 128 - tot
-    pad_np = np.zeros(padlen, dtype=np.uint8)
-    pad_np[0] = 0x80
-    pad_np[-16:] = np.frombuffer((tot * 8).to_bytes(16, "big"),
-                                 dtype=np.uint8)
+    span = min(WIDTH_SPAN, var)
+    tots = [64 + plen + var - d + tlen for d in range(span + 1)]
+    width = (tots[0] + 17 + 127) // 128 * 128
+    pads = [_pad_tail(tot, width) for tot in tots]
+    nbs = [(tot + 17 + 127) // 128 for tot in tots]
     sw = stream_words(bucket, var)
 
     def derive_challenge(flat, idx, tx, ty, tz, tt, te, ptab, *fk):
@@ -922,25 +1024,45 @@ def derive_fn(bucket: int, var: int, plen: int, tlen: int, fb: int):
         dhi = sb[1:2 * bucket:2].astype(jnp.uint32)
         desc = dlo | (dhi << 8)
         use_dev = (desc >> 15).astype(jnp.uint32)
-        pid = (desc & 0x7FFF).astype(jnp.int32)
+        short = ((desc >> 8) & 0x7).astype(jnp.int32)
+        pid = (desc & 0xFF).astype(jnp.int32)
         rw = flat[:8 * bucket].reshape(8, bucket)
         parts = [_words_to_bytes(rw), _words_to_bytes(aw)]
         if plen or tlen:
             row = ptab[pid]  # (bucket, PREFIX_CAP) gather off the snapshot
         if plen:
-            parts.append(row[:, :plen])
+            # the row's byte 0 is the front's less the suffix width
+            # (plan_batch): the lane's own width restores it
+            b0 = (row[:, 0].astype(jnp.int32) + (var + tlen) - short) & 0xFF
+            parts += [b0.astype(jnp.uint8)[:, None], row[:, 1:plen]]
         if var:
             offs = (2 * bucket
                     + jnp.arange(bucket, dtype=jnp.int32)[:, None] * var
                     + jnp.arange(var, dtype=jnp.int32)[None, :])
-            parts.append(sb[offs])
-        if tlen:
-            parts.append(row[:, plen:plen + tlen])
-        if padlen:
-            parts.append(jnp.broadcast_to(jnp.asarray(pad_np),
-                                          (bucket, padlen)))
+            own = sb[offs]  # (bucket, var), a narrower lane's zero-padded
+            if var > span:
+                parts.append(own[:, :var - span])
+        layouts = []
+        for d in range(span + 1):  # the lane's bytes end `d` early
+            lay = [own[:, var - span:var - d]] if d < span else []
+            if tlen:
+                lay.append(row[:, plen:plen + tlen])
+            lay.append(jnp.broadcast_to(jnp.asarray(pads[d]),
+                                        (bucket, pads[d].size)))
+            layouts.append(jnp.concatenate(lay, axis=1))
+        if span:
+            with jax.named_scope("width_select"):
+                layouts[0] = jnp.select(
+                    [short[:, None] == d for d in range(1, span + 1)],
+                    layouts[1:], layouts[0])
+        parts.append(layouts[0])
         msg = jnp.concatenate(parts, axis=1)  # (bucket, nb*128)
-        st = _compress_pairs(*_pairs_from_be_bytes(msg))
+        states = _compress_blocks(*_pairs_from_be_bytes(msg))
+        st = states[-1]
+        if len(set(nbs)) > 1:  # a narrower lane's message ends a block early
+            nb_lane = jnp.asarray(np.array(nbs, dtype=np.int32))[short]
+            st = tuple(jnp.where(nb_lane == nbs[-1], w, st_w)
+                       for w, st_w in zip(states[nbs[-1] - 1], st))
         with jax.named_scope("barrett_mod_l"):
             kw = _limbs_to_words(_barrett_mod_l(_state_to_limbs(st)))
         kw = kw * use_dev

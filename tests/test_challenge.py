@@ -392,7 +392,11 @@ def test_derive_with_carried_rows_matches_host_challenges(case):
     assert chk == host_chk  # the carried rows are under the checksum
     for i in range(n):
         assert kw[:, i].tobytes() == want[i].tobytes(), i
-    body = (msgs[0].prefix + msgs[0].suffix[plan.var:])
+    # the row: the front, its byte 0 less the suffix width (what every
+    # stamp width of a height shares), then the common tail
+    prefix, suffix = msgs[0].prefix, msgs[0].suffix
+    body = (bytes([(prefix[0] - len(suffix)) & 0xFF]) + prefix[1:]
+            + suffix[plan.var:])
     row = int(plan.pids[0])
     assert np.asarray(ntab)[row, :len(body)].tobytes() == body
     if case == "hit":
@@ -507,3 +511,213 @@ def test_table_stays_true_under_concurrent_plans_and_adoptions():
     assert errors == []
     assert clean_rows_are_true() >= challenge.TABLE_ROWS - rows_n - 16
     assert tab.counters["adoptions"] > 0 and tab.counters["evictions"] > 0
+
+
+# ------------------------------------------ every stamp width on the chip
+
+
+def _commit_rows(n: int, nanos, chain_id: str = "cosmoshub-4",
+                 height: int = 1_000_000, nil=(), lane_loop: bool = False):
+    """A commit's sign-rows as the verify path gets them (an index-vector
+    take of Commit.vote_sign_bytes_all: columns from 36 rows on, the
+    lane loop's exception bytes below, or with `lane_loop` at any size),
+    lane i stamped nanos[i] inside the height's second; the lanes in
+    `nil` voted NIL."""
+    from cometbft_tpu.libs.prefixrows import MsgBlock
+    from cometbft_tpu.types.basic import BlockID, BlockIDFlag, PartSetHeader
+    from cometbft_tpu.types.commit import Commit, CommitSig
+    from cometbft_tpu.utils import cmttime
+
+    rng = np.random.default_rng(height)
+    bid = BlockID(hash=rng.bytes(32),
+                  part_set_header=PartSetHeader(total=1, hash=rng.bytes(32)))
+    sigs = [CommitSig(
+        BlockIDFlag.NIL if i in nil else BlockIDFlag.COMMIT, bytes(20),
+        cmttime.Timestamp(1_700_000_000 + 6 * height, int(ns)), bytes(64))
+        for i, ns in enumerate(nanos)]
+    commit = Commit(height=height, round_=0, block_id=bid, signatures=sigs)
+    rows = commit.vote_sign_bytes_all(chain_id)
+    if lane_loop:
+        return MsgBlock.from_list(rows.rows_for(range(n)))
+    return rows.take(np.arange(n))
+
+
+def _ms_spread(n: int, seed: int = 7) -> list[int]:
+    """The benchmark's stamps (benchmarks/datagen.make_ring): n
+    milliseconds spread evenly over the second, in a seeded order."""
+    spread = [j * 1000 // n * 1_000_000 for j in range(n)]
+    np.random.default_rng(seed).shuffle(spread)
+    return spread
+
+
+# nanos 0 (the field omitted) and every width of its varint, 1 to 5 bytes
+EVERY_WIDTH = [0, 7, 300, 1_000_000, 2_000_000, 100_000_000, 268_435_455,
+               268_435_456, 999_000_000]
+
+
+def _nanos_widths(nanos) -> set[int]:
+    return {0 if ns == 0 else (int(ns).bit_length() + 6) // 7 for ns in nanos}
+
+
+def _derive_against_host(plan, msgs, n: int, bucket: int, seed: int):
+    """Run the plan's derive program on a hand-staged block -> (its k
+    words (bucket, 8), the host's _challenge_words (n, 8))."""
+    import jax.numpy as jnp
+
+    from cometbft_tpu.ops import ed25519_kernel as EK
+
+    rng = np.random.default_rng(seed)
+    sigs = rng.integers(0, 256, size=(n, 32), dtype=np.uint8)
+    pubs = rng.integers(0, 256, size=(n, 32), dtype=np.uint8)
+    block = np.zeros(challenge.block_words(bucket, plan.var),
+                     dtype=np.uint32)
+    block[:8 * bucket].reshape(8, bucket)[:, :n] = (
+        _limbs.bytes_to_words(sigs).T)
+    challenge.fill_stream(block, bucket, plan)
+    aw = np.zeros((8, bucket), dtype=np.uint32)
+    aw[:, :n] = _limbs.bytes_to_words(pubs).T
+    coords = (jnp.zeros((20, bucket), jnp.int32),) * 4
+    run = challenge.derive_fn(bucket, plan.var, plan.plen, plan.tlen, 0)
+    kw = run(block, np.arange(bucket, dtype=np.uint16), *coords,
+             jnp.asarray(aw), plan.dev_tab)[2]
+    want = EK._challenge_words(sigs, pubs, msgs, msgs.lengths(),
+                               np.ones(n, dtype=bool))
+    return np.asarray(kw).T, want
+
+
+def _chain_across_a_block() -> str:
+    """A chain id whose vote rows' widest and narrowest stamps pad to
+    different SHA-512 block counts (64 + message + 17 bytes either side
+    of 256)."""
+    for k in range(1, 120):
+        lens = _commit_rows(48, EVERY_WIDTH * 6, "c" * k).lengths()
+        if ((64 + int(lens.max()) + 17 + 127) // 128
+                != (64 + int(lens.min()) + 17 + 127) // 128):
+            return "c" * k
+    raise AssertionError("no chain id crosses a block")
+
+
+@pytest.mark.parametrize("blocks", ["one_block_count", "across_a_block"])
+def test_derive_is_bit_identical_for_every_stamp_width(blocks):
+    """A full commit whose stamps take every width of the timestamp
+    (nanos omitted, its varint 1 to 5 bytes, and the benchmark's ms
+    0-999), 48 rows in a 64-lane bucket: every live lane derives on the
+    chip, and k is _challenge_words' bit for bit, padding lanes zero;
+    where the widths straddle a SHA-512 block, each lane reads the state
+    after its own last block."""
+    challenge.reset()
+    n, bucket = 48, 64
+    nanos = EVERY_WIDTH + _ms_spread(n - len(EVERY_WIDTH))
+    assert _nanos_widths(nanos) == {0, 1, 2, 3, 4, 5}
+    chain = "cosmoshub-4" if blocks == "one_block_count" else (
+        _chain_across_a_block())
+    msgs = _commit_rows(n, nanos, chain)
+    assert len(set(msgs.lengths().tolist())) == 6
+    plan = challenge.plan_batch(msgs, np.ones(n, dtype=bool),
+                                put_key="widths-" + blocks)
+    assert plan is not None
+    assert (plan.n_eligible, plan.n_fallback) == (n, 0)
+    assert plan.n_joined == int((plan.short > 0).sum()) > 0
+    assert int(plan.short.max()) == 6  # nanos omitted: 6 bytes short
+    assert challenge.stats()["lanes_width_joined"] == plan.n_joined
+    kw, want = _derive_against_host(plan, msgs, n, bucket, 0x51A)
+    assert kw[:n].tobytes() == want.tobytes()
+    assert not kw[n:].any()
+
+
+@pytest.mark.parametrize("n", [24, 150, 2048])
+def test_a_commits_plan_derives_every_lane_from_one_row(n):
+    """A benchmark-shaped commit (ms spread over the second: ~27% of
+    its stamps shorter than the modal one) plans every lane onto the
+    chip, from ONE prefix-table row for the height, whichever builder
+    made its rows (24: the lane loop's exception bytes; 150 and 2,048:
+    columns)."""
+    challenge.reset()
+    msgs = _commit_rows(n, _ms_spread(n))
+    plan = challenge.plan_batch(msgs, np.ones(n, dtype=bool), put_key="one")
+    assert plan is not None
+    assert (plan.n_eligible, plan.n_fallback) == (n, 0)
+    assert len(set(plan.pids.tolist())) == 1
+    assert challenge.table("one").stats()["rows"] == 1
+    modal = np.bincount(msgs.lengths()).max()
+    assert plan.n_joined == n - modal
+    if n >= 150:
+        assert 0.2 < plan.n_joined / n < 0.35
+    counters = challenge.stats()
+    assert counters["lanes_device"] == n
+    assert counters["lanes_host_fallback"] == 0
+    assert counters["lanes_width_joined"] == plan.n_joined
+
+
+def test_a_catchup_window_carries_one_row_a_height():
+    """Eight heights of 150 votes in one batch (blocksync's window):
+    eight new rows, at most CARRY_ROWS, ride the derive call; the table's
+    awaited scatter runs only at its first use."""
+    challenge.reset()
+    from cometbft_tpu.libs.prefixrows import MsgBlock
+
+    first = _commit_rows(150, _ms_spread(150), height=999)
+    assert challenge.plan_batch(first, np.ones(150, bool),
+                                put_key="window") is not None
+    assert challenge.stats()["table_rows_awaited"] == 1  # first use
+    window = MsgBlock.concat([_commit_rows(150, _ms_spread(150, h), height=h)
+                              for h in range(1000, 1008)])
+    n = len(window)
+    plan = challenge.plan_batch(window, np.ones(n, bool), put_key="window")
+    assert plan is not None
+    assert (plan.n_eligible, plan.n_fallback) == (n, 0)
+    assert plan.n_joined > 0
+    assert plan.n_carried == 8 <= challenge.CARRY_ROWS
+    assert len(set(plan.pids.tolist())) == 8
+    assert challenge.stats()["table_rows_awaited"] == 1  # no second sync
+    assert challenge.table("window").stats()["syncs"] == 1
+
+
+def test_two_width_mixes_build_one_derive_program():
+    """Batches whose stamps hold different widths in different
+    shares plan the same geometry: the derive family does not grow with
+    the widths a batch holds (the `derive_programs` counter), and both
+    derive k bit for bit."""
+    challenge.reset()
+    n, bucket = 40, 64
+    chain = "width-mix-chain"
+    every = EVERY_WIDTH + _ms_spread(n - len(EVERY_WIDTH))
+    # another mix: mostly 5-byte varints, a few ms-0 and 4-byte stamps
+    other = [(300 + 17 * i) * 1_000_000 for i in range(n - 6)]
+    other += [0, 0, 0, 50_000_000, 100_000_000, 200_000_000]
+    assert _nanos_widths(other) == {0, 4, 5} != _nanos_widths(every)
+    geometries = set()
+    for i, nanos in enumerate((every, other)):
+        msgs = _commit_rows(n, nanos, chain, height=500 + i)
+        plan = challenge.plan_batch(msgs, np.ones(n, bool), put_key="mix")
+        assert plan is not None and plan.n_fallback == 0
+        geometries.add((plan.var, plan.plen, plan.tlen))
+        kw, want = _derive_against_host(plan, msgs, n, bucket, i)
+        assert kw[:n].tobytes() == want.tobytes()
+    assert len(geometries) == 1
+    assert challenge.stats()["derive_programs"] == 1
+
+
+@pytest.mark.parametrize("rows", ["columns", "lane_loop"])
+def test_nil_and_foreign_lanes_still_fall_back_per_lane(rows):
+    """A NIL vote's shorter head and a foreign message stay with the
+    per-lane host rung, whether the commit's rows come as columns or as
+    the lane loop's PrefixedMsg and exception bytes; every other width
+    joins the chip."""
+    from cometbft_tpu.libs.prefixrows import MsgBlock
+
+    challenge.reset()
+    n = 48
+    nanos = EVERY_WIDTH + _ms_spread(n - len(EVERY_WIDTH))
+    commit = _commit_rows(n, nanos, nil=(3, 11),
+                          lane_loop=rows == "lane_loop")
+    if rows == "lane_loop":  # off-length and NIL rows as whole bytes
+        assert sum(not f for f in commit.fronts) > 1
+    foreign = MsgBlock.from_list([b"\x70" + b"a foreign message" * 6])
+    msgs = MsgBlock.concat([commit, foreign])
+    plan = challenge.plan_batch(msgs, np.ones(n + 1, bool), put_key="nil")
+    assert plan is not None
+    assert plan.n_fallback == 3
+    assert np.flatnonzero(~plan.eligible).tolist() == [3, 11, n]
+    assert plan.n_joined > 0
+    assert len(set(plan.pids[plan.eligible].tolist())) == 1

@@ -94,7 +94,8 @@ def test_challenge_derive_compiles_for_v5e_vote_shape(one_chip,
     from the 16,384-row key table in front and the block's checksum
     behind) at the geometry a 150-validator commit
     plans: bucket 256, the votes' shared prefix/tail, ms-grained
-    timestamps with their off-length lanes as host fallbacks."""
+    timestamps, every width of them derived here (the span's layouts
+    selected a lane), so no fallback arrays."""
     import chip_smoke
     from cometbft_tpu.ops import challenge
     from cometbft_tpu.ops import ed25519_kernel as EK
@@ -106,6 +107,7 @@ def test_challenge_derive_compiles_for_v5e_vote_shape(one_chip,
     finally:
         challenge.reset()
     assert plan is not None and plan.n_eligible > 0
+    assert plan.n_joined > 0 and plan.n_fallback == 0
     b = EK.bucket_size(len(msgs))
     assert b == 256
     fb = EK.bucket_size(plan.n_fallback) if plan.n_fallback else 0

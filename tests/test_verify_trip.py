@@ -677,3 +677,153 @@ def test_benchmark_reads_the_share_of_prefix_rows_carried(keys, rows):
     parents = {k: v for k, v in counters.items()
                if ".table_rows_" not in k}
     assert parents and read(parents) is None
+
+
+# ----------------------------------- every stamp width derived on the chip
+
+
+def _stamped_commit(keys):
+    """A signed 150-validator commit with the benchmark's stamps (ms
+    spread over the height's second, seeded order): ~27% of its rows are
+    shorter than the modal sign-bytes. -> (vals, block id, commit, the
+    nanos of each row)."""
+    import random
+
+    from cometbft_tpu.types.basic import BlockID, BlockIDFlag, PartSetHeader
+    from cometbft_tpu.types.commit import Commit, CommitSig
+    from cometbft_tpu.types.validator import Validator, ValidatorSet
+    from cometbft_tpu.utils import cmttime
+
+    vals = ValidatorSet([Validator.new(k.pub_key(), 10) for k in keys])
+    by_addr = {k.pub_key().address(): k for k in keys}
+    nanos = [j * 1000 // N * 1_000_000 for j in range(N)]
+    random.Random(40).shuffle(nanos)
+    bid = BlockID(hash=b"\x33" * 32,
+                  part_set_header=PartSetHeader(total=1, hash=b"\x44" * 32))
+    sigs = [CommitSig(BlockIDFlag.COMMIT, v.address,
+                      cmttime.Timestamp(1_790_000_000, ns), b"")
+            for v, ns in zip(vals.validators, nanos)]
+    commit = Commit(height=STAMP_HEIGHT, round_=0, block_id=bid,
+                    signatures=sigs)
+    for i, v in enumerate(vals.validators):
+        sigs[i].signature = by_addr[v.address].sign(
+            commit.vote_sign_bytes(STAMP_CHAIN, i))
+    return vals, bid, commit, nanos
+
+
+STAMP_CHAIN = "stamp-width-chain"
+STAMP_HEIGHT = 4242
+
+
+@pytest.mark.parametrize("flip", ["none", "four_byte_stamp", "ms_zero",
+                                  "both"])
+def test_odd_width_lanes_answer_as_the_host_oracle(keys, flip):
+    """verify_commit on the device path (one chip, every stamp width's k
+    derived there: no lane on the host rung) against the host oracle: a
+    flipped signature in a lane with a 4-byte stamp (3-268 ms) or in the
+    ms-0 lane (nanos omitted) is named by the same index in the same
+    error, and a clean commit passes."""
+    from cometbft_tpu import sched
+    from cometbft_tpu.crypto import batch as crypto_batch
+    from cometbft_tpu.parallel import mesh as M
+    from cometbft_tpu.types import validation
+    from cometbft_tpu.types.commit import Commit, CommitSig
+
+    vals, bid, commit, nanos = _stamped_commit(keys)
+    four = next(i for i, ns in enumerate(nanos)
+                if 3_000_000 <= ns <= 268_000_000)
+    zero = nanos.index(0)
+    lanes = {"none": [], "four_byte_stamp": [four], "ms_zero": [zero],
+             "both": [four, zero]}[flip]
+    sigs = [CommitSig(cs.block_id_flag, cs.validator_address, cs.timestamp,
+                      cs.signature) for cs in commit.signatures]
+    for i in lanes:
+        sigs[i].signature = bytes([sigs[i].signature[0] ^ 1]) + (
+            sigs[i].signature[1:])
+
+    def answer(backend: str):
+        prev = crypto_batch.get_backend()
+        crypto_batch.set_backend(backend)
+        sched.reset()
+        try:
+            validation.verify_commit(STAMP_CHAIN, vals, bid, STAMP_HEIGHT,
+                                     Commit(STAMP_HEIGHT, 0, bid, list(sigs)))
+            return "ok", ""
+        except Exception as exc:  # noqa: BLE001 - the verdict compared
+            return type(exc).__name__, str(exc)
+        finally:
+            sched.reset()
+            crypto_batch.set_backend(prev)
+
+    M.configure(enabled=False)
+    try:
+        on_chip = answer("tpu")
+    finally:
+        M.configure(enabled=True)
+    counters = challenge.stats()
+    assert answer("cpu") == on_chip
+    if lanes:
+        assert on_chip[0] == "ErrInvalidCommitSignature"
+        assert on_chip[1].startswith(f"wrong signature (#{min(lanes)}): ")
+    else:
+        assert on_chip == ("ok", "")
+    widths = {len(commit.vote_sign_bytes(STAMP_CHAIN, i)) for i in range(N)}
+    assert len(widths) == 3  # nanos omitted, its 4- and 5-byte varints
+    assert counters["lanes_device"] == N
+    assert counters["lanes_host_fallback"] == 0
+    assert 0.2 * N < counters["lanes_width_joined"] < 0.35 * N
+
+
+def test_benchmark_reads_the_share_of_lanes_derived_on_the_chip(keys, rows):
+    """benchmarks/metrics/device_challenge_lanes_pct.json over the
+    flattened crypto_health snapshot, differenced as a run differences
+    it: 100 where every live lane's k was derived on the chip, less by
+    the share a foreign message keeps on the host rung, nothing (and no
+    error) on a program without the counters."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from benchmarks import program, readers
+
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        per_layer = json.load(fh)["per_layer"]
+    for family, moves, cell in (
+            ("commit", "commit_verify_ms", "hub-150.commit"),
+            ("catchup", "catchup_blocks_per_s", "hub-150.catchup")):
+        entry = dict(next(m for m in per_layer if m["name"]
+                          == f"device_challenge_lanes_pct.{family}"))
+        assert entry.pop("workloads")[0] == cell  # written for it first
+        assert entry == {
+            "name": f"device_challenge_lanes_pct.{family}", "unit": "%",
+            "better": "higher", "source": "program_counter",
+            "layer": "residency and wire", "moves": moves}
+    metrics_dir = os.path.join(root, "benchmarks", "metrics")
+
+    def flat() -> dict:
+        out: dict = {}
+        program._flatten(D.health_snapshot(), "", out)
+        return out
+
+    def read(counters):
+        return readers.read_metric(metrics_dir,
+                                   "device_challenge_lanes_pct.commit",
+                                   {"counters": counters})
+
+    pubs, msgs, sigs, _bad = rows
+    assert K.verify_batch(pubs, msgs, sigs)[0]
+    before = flat()
+    assert K.verify_batch(pubs, msgs, sigs)[0]
+    assert read(program.Counters.diff(before, flat())) == {
+        "value": 100.0, "unit": "%"}
+    foreign = b"a foreign message, not a vote"
+    msgs, sigs = list(msgs), list(sigs)
+    msgs[5], sigs[5] = foreign, keys[5].sign(foreign)
+    assert K.verify_batch(pubs, msgs, sigs)[0]
+    counters = program.Counters.diff(before, flat())
+    assert read(counters) == {"value": 100 * (2 * N - 1) / (2 * N),
+                              "unit": "%"}
+    parents = {k: v for k, v in counters.items() if ".lanes_" not in k}
+    assert parents and read(parents) is None

@@ -205,6 +205,9 @@ def test_a_delta_of_any_size_runs_one_compiled_geometry(new_keys):
     bring 8 to 500 a hop: no compile per key count), one awaited checksum
     for all the blocks; the rows gather as the host decompressed them."""
     delta = residency._delta_fn()
+    # the program is process-global: a table of another size (the default
+    # 16,384 rows, where another file's tests ran first) is another entry
+    delta.clear_cache()
     residency.reset_send_stats()
     tbl = residency.table_for(K._default_cache)
     tbl.stage(_sign_n(3)[0], 8)            # the table's first delta
